@@ -35,7 +35,6 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.bitplane import PackedJ
@@ -347,9 +346,9 @@ def batched_anneal_step_lowering(
 #     floor division (local h·m + m·field may be odd; int32 addition is
 #     exact and order-free, so sharded H is bit-identical to unsharded).
 #
-# `check_rep=False`: jax 0.4.x cannot statically infer that an all-gathered
-# value is replicated; replication of best_H is instead guaranteed by the
-# psum and asserted (bit-identity vs the unsharded backends) in tests.
+# `check_vma=False`: replication of best_H is guaranteed by the psum, not
+# inferred by shard_map's varying-axes check, and asserted (bit-identity vs
+# the unsharded backends) in tests.
 # ---------------------------------------------------------------------------
 
 
@@ -601,10 +600,10 @@ class BatchedSpinShardedBackend(BatchedBackend):
                 st = self._pack_local(st)
             return st
 
-        return shard_map(
+        return jax.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(self._problem_specs(), sspec), out_specs=sspec,
-            check_rep=False,
+            check_vma=False,
         )
 
     def run_plateau(self, problem, state, i0, *, length, eligible, jperp=0):
@@ -634,11 +633,11 @@ class BatchedSpinShardedBackend(BatchedBackend):
                 return st, trace
             return st, (jnp.zeros((0,)), jnp.zeros((0,)))
 
-        return shard_map(
+        return jax.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(self._problem_specs(), sspec),
             out_specs=(sspec, (P(None), P(None))),
-            check_rep=False,
+            check_vma=False,
         )(problem, state)
 
     def run_shots(self, problem, state, plateaus, n_shots):

@@ -94,7 +94,10 @@ __all__ = [
     "BACKENDS",
     "make_backend",
     "resolve_backend",
+    "route_backend",
     "resolve_field_mode",
+    "pallas_vmem_shortfall",
+    "resident_kernel",
     "resolve_j_mode",
     "resolve_noise_mode",
     "resolve_partition",
@@ -777,14 +780,80 @@ def resolve_field_mode(field_mode: str, j_bits: int) -> str:
     return field_mode
 
 
-def resolve_backend(backend: str, n: int) -> str:
-    """'auto' dispatches the resident Pallas kernel only at or above
-    MIN_RESIDENT_N spins; below it the launch overhead loses to the scan
-    backends (the measured 32-spin smoke regression), so 'auto' never does.
-    Non-'auto' names pass through untouched."""
-    if backend == "auto":
-        return "pallas" if int(n) >= MIN_RESIDENT_N else "dense"
-    return backend
+def resident_kernel(field_mode: str, noise_mode: str) -> str:
+    """The resident kernel family a pallas backend launches: 'popcount'
+    (XNOR-popcount chain), 'streamed' (in-kernel xorshift, f32 J) or
+    'pregen' (per-plateau noise buffer, f32 J)."""
+    if field_mode == "popcount":
+        return "popcount"
+    return "streamed" if noise_mode == "streamed" else "pregen"
+
+
+def pallas_vmem_shortfall(n: int, *, noise: str = "xorshift",
+                          noise_mode: str = "auto", field_mode: str = "dense",
+                          j_bits: int = 1, block_r: int = 8,
+                          n_replicas: int = 0, j_dtype=jnp.float32,
+                          n_cycles: int = 1, **_other) -> Optional[str]:
+    """Why the resident kernel cannot run at ``n`` spins, or None if it can.
+
+    The kernel's VMEM need comes from its block shapes
+    (:func:`repro.kernels.ssa_update.plateau_vmem_bytes`) and is checked
+    against the chip's budget before anything is built or dispatched.
+    ``n_cycles`` (the plateau length) only sizes the pregen noise buffer.
+    Options the pallas backend does not take are ignored, so callers may
+    pass a backend-opts union.
+    """
+    from repro.kernels import ssa_update as kssa  # lazy: keeps core light
+
+    kernel = resident_kernel(resolve_field_mode(field_mode, j_bits),
+                             resolve_noise_mode(noise_mode, noise))
+    br = int(n_replicas) if n_replicas else int(block_r)
+    need = kssa.plateau_vmem_bytes(
+        kernel, int(n), block_r=br, n_cycles=int(n_cycles), j_dtype=j_dtype,
+        j_bits=int(j_bits), n_replicas=int(n_replicas),
+    )
+    budget = kssa.vmem_budget_bytes()
+    if need <= budget:
+        return None
+    return (f"the {kernel} resident kernel needs ~{need / 2**20:.1f} MiB of "
+            f"VMEM at {int(n)} spins, over the {budget / 2**20:.1f} MiB budget")
+
+
+def route_backend(backend: str, n: int,
+                  **kernel_opts) -> Tuple[str, Optional[str]]:
+    """``(backend, why)`` for an ``n``-spin program.
+
+    'auto' dispatches the resident Pallas kernel only at or above
+    MIN_RESIDENT_N spins (below it the launch overhead loses to the scan
+    backends, the measured 32-spin smoke regression) and only where that
+    kernel fits the chip's VMEM budget (:func:`pallas_vmem_shortfall`, fed
+    ``kernel_opts``); otherwise it picks XLA dense, and ``why`` is the
+    budget shortfall when that is the reason.  Non-'auto' names pass
+    through untouched.
+    """
+    if backend != "auto":
+        return backend, None
+    if int(n) < MIN_RESIDENT_N:
+        return "dense", None
+    why = pallas_vmem_shortfall(n, **kernel_opts)
+    return ("dense" if why else "pallas"), why
+
+
+def resolve_backend(backend: str, n: int, **kernel_opts) -> str:
+    """The backend name :func:`route_backend` picks."""
+    return route_backend(backend, n, **kernel_opts)[0]
+
+
+def _require_vmem_fit(n: int, **kernel_opts) -> None:
+    """Refuse an explicit pallas backend whose kernel cannot fit the chip."""
+    why = pallas_vmem_shortfall(n, **kernel_opts)
+    if why is not None:
+        from repro.kernels.ssa_update import VmemBudgetError
+
+        raise VmemBudgetError(
+            f"backend='pallas': {why}; use backend='auto' (routes this size "
+            "to XLA dense) or 'dense'/'sparse'"
+        )
 
 
 # Spin-sharded execution (DESIGN.md §11). partition='auto' splits the spin
@@ -969,13 +1038,20 @@ class PallasBackend(PlateauBackend):
             field_mode,
             model_weight_bits(model) if field_mode == "auto" else 1,
         )
+        if self.field_mode == "popcount" and self.noise_mode != "streamed":
+            raise ValueError(
+                "field_mode='popcount' on the pallas backend requires "
+                "noise_mode='streamed' (noise='xorshift'): the bit-"
+                "parallel chain kernel generates its noise in-kernel"
+            )
+        _require_vmem_fit(
+            model.n, noise=self.noise, noise_mode=self.noise_mode,
+            field_mode=self.field_mode, block_r=self.block_r,
+            n_replicas=self.n_replicas, j_dtype=j_dtype,
+            j_bits=(model_weight_bits(model)
+                    if self.field_mode == "popcount" else 1),
+        )
         if self.field_mode == "popcount":
-            if self.noise_mode != "streamed":
-                raise ValueError(
-                    "field_mode='popcount' on the pallas backend requires "
-                    "noise_mode='streamed' (noise='xorshift'): the bit-"
-                    "parallel chain kernel generates its noise in-kernel"
-                )
             self.packed_j = pack_couplings_from_adjacency(
                 model.n, model.nbr_idx, model.nbr_w
             )
@@ -1210,8 +1286,10 @@ def make_backend(
     if isinstance(backend, type) and issubclass(backend, PlateauBackend):
         cls = backend
     else:
-        if isinstance(backend, str):
-            backend = resolve_backend(backend, model.n)
+        if backend == "auto":
+            backend = resolve_backend(backend, model.n, **{
+                "noise": noise, "j_bits": model_weight_bits(model), **opts,
+            })
         try:
             cls = BACKENDS[backend]
         except (KeyError, TypeError):
@@ -1775,6 +1853,11 @@ class BatchedPallasBackend(BatchedBackend):
                 "requires noise_mode='streamed' (noise='xorshift'); the "
                 "pregen kernel has no replica-coupling path"
             )
+        _require_vmem_fit(
+            self.n_bucket, noise=self.noise, noise_mode=self.noise_mode,
+            field_mode=self.field_mode, j_bits=self.j_bits,
+            block_r=self.block_r, n_replicas=self.n_replicas, j_dtype=j_dtype,
+        )
 
     def stack(self, models):
         if self.field_mode == "popcount":
@@ -1977,7 +2060,7 @@ def make_batched_backend(
             **opts,
         )
     if isinstance(backend, str):
-        backend = resolve_backend(backend, n_bucket)
+        backend = resolve_backend(backend, n_bucket, noise=noise, **opts)
     try:
         cls = BATCHED_BACKENDS[backend]
     except (KeyError, TypeError):

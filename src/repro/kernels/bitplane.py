@@ -12,9 +12,10 @@ is used
 * for the trajectory planes of ``record='traj'`` (the Eq. 5/6 witness);
 * inside the streamed-noise resident kernel
   (`repro.kernels.ssa_update.ssa_plateau_packed_batched`), whose HBM-facing
-  spin refs are these words — `_unpack_pm1_f32` / `_pack_pm1` are the
+  spin refs are these words — `_unpack_pm1_f32` / `_pack_bits` are the
   kernel-side halves of the codec, operating on lane-aligned (N % 128 == 0)
-  tiles in VMEM.
+  tiles in VMEM (packing there runs as exact MXU contractions, because
+  Mosaic does not lower the lane-splitting reshape used here).
 
 Everything here is pure `jnp` on uint32 (no Pallas imports), so the codec
 is usable from `repro.core` without pulling in the kernel toolchain, and

@@ -44,11 +44,17 @@ kernels:
   dispatch instead of one per plateau — the small-N launch-overhead fix.
 
 All are validated against :mod:`.ref` oracles / the scan engine in
-interpret mode (CPU) over a shape/dtype sweep; TPU is the compile target.
+interpret mode (CPU) over a shape/dtype sweep, and compiled for a described
+TPU v5e at the service buckets (tests/test_tpu_compile.py).  Every
+`pallas_call` carries explicit Mosaic params: ``dimension_semantics`` and a
+``vmem_limit_bytes`` derived from its block shapes
+(:func:`plateau_vmem_bytes`); a kernel over the chip's budget raises
+:class:`VmemBudgetError` before dispatch.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -65,12 +71,142 @@ __all__ = [
     "ssa_plateau_popcount",
     "ssa_plateau_popcount_batched",
     "pad_to",
-    "DEFAULT_INTERPRET",
+    "default_interpret",
+    "plateau_vmem_bytes",
+    "vmem_budget_bytes",
+    "VmemBudgetError",
 ]
 
-# interpret=True executes the kernel body in Python on CPU — the validation
-# mode for this container; on TPU hosts the same code lowers to Mosaic.
-DEFAULT_INTERPRET = jax.default_backend() == "cpu"
+def default_interpret() -> bool:
+    """Whether kernels run in interpret mode when the caller does not say.
+
+    Decided at call (trace) time from the platform JAX runs on, never at
+    import: off the TPU the kernel body executes as Python (the validation
+    mode of CPU test runs); on the TPU it always lowers to Mosaic.
+    """
+    return jax.default_backend() != "tpu"
+
+
+# Whole-array scalar operands (I0, J⊥, schedules) live in SMEM: the kernels
+# index them with the traced cycle counter, which VMEM loads cannot take.
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+LANE = 128
+# VMEM per TensorCore of TPU v5e, the chip the kernels are sized for when no
+# TPU backs JAX (`pltpu.get_tpu_info` gives the real figure on the chip), so
+# routing decisions are the same in interpret mode as on the chip.
+TARGET_VMEM_BYTES = 128 * 2**20
+# Share of VMEM a resident kernel may claim; the rest stays with Mosaic for
+# its internal scratch and relayout buffers.
+VMEM_BUDGET_FRACTION = 0.75
+# Added to a kernel's own estimate for its vmem_limit_bytes.  The estimate
+# already sat above the smallest limit each kernel compiled under (v5e,
+# 1024-4096 spins), so this is slack, not a correction.
+_VMEM_HEADROOM = 4 * 2**20
+
+
+class VmemBudgetError(ValueError):
+    """A resident kernel's blocks do not fit the chip's VMEM budget."""
+
+
+def vmem_budget_bytes() -> int:
+    """VMEM bytes one resident kernel may claim on the current chip."""
+    if jax.default_backend() == "tpu":
+        cap = pltpu.get_tpu_info().vmem_capacity_bytes
+    else:
+        cap = TARGET_VMEM_BYTES
+    return int(cap * VMEM_BUDGET_FRACTION)
+
+
+def _buf_bytes(shape, dtype) -> int:
+    """VMEM bytes of one buffer in Mosaic's (8·packing, 128) tiled layout."""
+    item = jnp.dtype(dtype).itemsize
+    *lead, rows, cols = (1, 1, *shape)[-max(2, len(shape)):]
+    sub = 8 * max(1, 4 // item)
+    return (math.prod(lead) * (-(-rows // sub) * sub)
+            * (-(-cols // LANE) * LANE) * item)
+
+
+def _vmem_bytes(blocks, scratch=(), temps=()) -> int:
+    """Pipelined in/out blocks count twice (double-buffered); scratch and
+    the body's large temporaries once."""
+    return (2 * sum(_buf_bytes(*b) for b in blocks)
+            + sum(_buf_bytes(*b) for b in (*scratch, *temps)))
+
+
+def _compiler_params(kernel: str, need: int, grid_dims: int,
+                     semantics: Optional[Tuple[str, ...]] = None):
+    """Mosaic params for one `pallas_call`, refusing what cannot fit.
+
+    ``vmem_limit_bytes`` is the kernel's own estimate plus the headroom
+    Mosaic keeps for itself, capped at the budget; a kernel whose estimate
+    exceeds :func:`vmem_budget_bytes` raises :class:`VmemBudgetError` here,
+    before anything is dispatched.
+    """
+    budget = vmem_budget_bytes()
+    if need > budget:
+        raise VmemBudgetError(
+            f"{kernel} needs ~{need / 2**20:.1f} MiB of VMEM for its resident "
+            f"blocks, over the {budget / 2**20:.1f} MiB budget "
+            f"({VMEM_BUDGET_FRACTION:.0%} of the chip's VMEM)"
+        )
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics or ("parallel",) * grid_dims,
+        vmem_limit_bytes=min(budget, need + _VMEM_HEADROOM),
+    )
+
+
+def plateau_vmem_bytes(kernel: str, n: int, *, block_r: int = 8,
+                       n_cycles: int = 1, j_dtype=jnp.float32,
+                       j_bits: int = 1, n_replicas: int = 0,
+                       field_tile: int = LANE) -> int:
+    """VMEM estimate of one resident plateau kernel at ``n`` spins.
+
+    ``kernel`` is 'pregen' (:func:`ssa_plateau_batched`), 'streamed'
+    (:func:`ssa_plateau_packed_batched`) or 'popcount'
+    (:func:`ssa_plateau_popcount_batched`).  The figure comes from the block
+    shapes the wrapper hands `pallas_call` — the same function the wrapper
+    derives its ``vmem_limit_bytes`` from — so the engine's resolvers can
+    check it against :func:`vmem_budget_bytes` before choosing a backend.
+    """
+    np_ = n + (-n) % LANE
+    nwp = np_ // 32
+    br = block_r
+    i32, u32, f32 = jnp.int32, jnp.uint32, jnp.float32
+    plane = ((br, np_), i32)          # one (bR, Np) 32-bit state plane
+    words = ((br, nwp), u32)          # packed spins of one R-tile
+    best = ((br, 1), i32)
+    vec = ((1, np_), i32)
+    lanes = ((4, br, np_), u32)       # xorshift128 state
+    # The body's per-cycle field/update values spill whole planes; the
+    # codecs hold the bf16 pack weights and the (bR, Nw, 32) unpack buffer.
+    temps = [plane] * 4
+    codec = [((np_, nwp), jnp.bfloat16)] * 2 + [((br, nwp, 32), u32)]
+    if kernel == "pregen":
+        spins8 = ((br, np_), jnp.int8)
+        blocks = [plane, plane, ((np_, np_), j_dtype), vec,
+                  ((n_cycles, br, np_), jnp.int8), best, spins8,   # in
+                  plane, plane, best, spins8]                      # out
+        scratch = [plane, plane, best, plane]
+    elif kernel == "streamed":
+        blocks = [words, plane, ((np_, np_), j_dtype), vec, lanes, best,
+                  words,                                           # in
+                  words, plane, lanes, best, words]                # out
+        scratch = [plane, plane, lanes, best, plane]
+        temps += codec
+    elif kernel == "popcount":
+        planes = ((np_, nwp), u32)
+        blocks = [words, plane, planes, ((j_bits, np_, nwp), u32), vec,
+                  vec, lanes, best, words,                         # in
+                  words, plane, lanes, best, words]                # out
+        scratch = [words, plane, plane, lanes, best, words, plane]
+        if n_replicas:
+            scratch.append(((2, br, np_), i32))
+        # XNOR words, masked words and their popcounts for one row tile.
+        temps += codec + [((br, field_tile, nwp), u32)] * 3
+    else:
+        raise ValueError(f"unknown resident kernel {kernel!r}")
+    return _vmem_bytes(blocks, scratch, temps)
 
 
 def pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -119,7 +255,7 @@ def local_field(
     interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """field = h + m @ J, int32 exact, via the tiled Pallas kernel."""
-    interpret = DEFAULT_INTERPRET if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     R, N = m.shape
     mf = pad_to(pad_to(m.astype(J.dtype), 1, block_k), 0, block_r)
     Jp = pad_to(pad_to(J, 0, block_k), 1, block_n)
@@ -139,6 +275,15 @@ def local_field(
         out_specs=pl.BlockSpec((block_r, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Rp, Np), jnp.int32),
         scratch_shapes=[pltpu.VMEM((block_r, block_n), jnp.float32)],
+        compiler_params=_compiler_params(
+            "local_field",
+            _vmem_bytes(
+                [((block_r, block_k), mf.dtype), ((block_k, block_n), Jp.dtype),
+                 ((1, block_n), jnp.int32), ((block_r, block_n), jnp.int32)],
+                [((block_r, block_n), jnp.float32)],
+            ),
+            3, ("parallel", "parallel", "arbitrary"),
+        ),
         interpret=interpret,
     )(mf, Jp, hp)
     return out[:R, :N]
@@ -245,10 +390,9 @@ def ssa_plateau_batched(
     batched hot path).  Per-problem semantics are identical to the B=1
     kernel; :func:`ssa_plateau` is exactly this with B=1.
     """
-    interpret = DEFAULT_INTERPRET if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     B, R, N = m.shape
     C = noise.shape[1]
-    LANE = 128
     mf = pad_to(pad_to(m.astype(jnp.float32), 2, LANE), 1, block_r)
     itp = pad_to(pad_to(itanh, 2, LANE), 1, block_r)
     Jp = pad_to(pad_to(J, 1, LANE), 2, LANE)
@@ -267,7 +411,7 @@ def ssa_plateau_batched(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (0, 0)),
+            _SMEM,
             pl.BlockSpec((1, block_r, Np), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_r, Np), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Np, Np), lambda b, i: (b, 0, 0)),
@@ -294,6 +438,12 @@ def ssa_plateau_batched(
             pltpu.VMEM((block_r, 1), jnp.float32),
             pltpu.VMEM((block_r, Np), jnp.float32),
         ],
+        compiler_params=_compiler_params(
+            "ssa_plateau_batched",
+            plateau_vmem_bytes("pregen", Np, block_r=block_r, n_cycles=C,
+                               j_dtype=J.dtype),
+            2,
+        ),
         interpret=interpret,
     )(i0a, mf, itp, Jp.astype(J.dtype), hp, np_, bhp, bmp)
     return (
@@ -319,11 +469,38 @@ def _unpack_pm1_f32(words: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(flat == 1, 1.0, -1.0).astype(jnp.float32)
 
 
-def _pack_pm1(m: jnp.ndarray) -> jnp.ndarray:
-    """Kernel-side codec: (bR, N) ±1 f32 → (bR, N/32) u32 words (N % 32 == 0)."""
-    bits = (m > 0).astype(jnp.uint32).reshape(m.shape[0], -1, 32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(bits << shifts, axis=-1, dtype=jnp.uint32)
+def _pack_matrices(n: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(n, n/32) bf16 weights that pack sign bits by two MXU contractions.
+
+    ``lo[i, w] = 2^(i%32)`` where spin i sits in the low half of word w (bit
+    ``i%32 < 16``), ``hi[i, w] = 2^(i%32 - 16)`` in the high half, else 0.
+    Powers of two up to 2^15 and 0/1 bits are exact in bf16, and each
+    half-word sum stays below 2^16, so the f32-accumulated products are
+    exact.  Built from 2-D iotas (Mosaic has no 1-D iota).
+    """
+    shape = (n, n // 32)
+    i = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    own = (i >> 5) == jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    k = i & 31
+    val = (jnp.int32(1) << (k & 15)).astype(jnp.float32)
+    lo = jnp.where(own & (k < 16), val, 0.0).astype(jnp.bfloat16)
+    hi = jnp.where(own & (k >= 16), val, 0.0).astype(jnp.bfloat16)
+    return lo, hi
+
+
+def _pack_bits(bits: jnp.ndarray, mats) -> jnp.ndarray:
+    """Kernel-side codec: (bR, N) bool sign bits → (bR, N/32) u32 words.
+
+    Bit k of word w is spin 32·w + k (the `repro.kernels.bitplane` layout).
+    Grouping 32 lanes into one word is a lane-splitting reshape that Mosaic
+    does not lower, so the two 16-bit halves of every word come out of exact
+    bf16 contractions against :func:`_pack_matrices` instead.
+    """
+    lo_m, hi_m = mats
+    b = bits.astype(jnp.bfloat16)
+    lo = jnp.dot(b, lo_m, preferred_element_type=jnp.float32).astype(jnp.int32)
+    hi = jnp.dot(b, hi_m, preferred_element_type=jnp.float32).astype(jnp.int32)
+    return jax.lax.bitcast_convert_type(lo | (hi << 16), jnp.uint32)
 
 
 def _plateau_streamed_kernel(
@@ -439,11 +616,12 @@ def _plateau_streamed_kernel(
     field = jnp.dot(m_s[...], jm, preferred_element_type=jnp.float32) + hf
     track_best(m_s[...], field)
 
-    mp_out[...] = _pack_pm1(m_s[...])[None]
+    mats = _pack_matrices(m_s.shape[-1])
+    mp_out[...] = _pack_bits(m_s[...] > 0, mats)[None]
     it_out[...] = it_s[...][None]
     rng_out[...] = rng_s[...][None]
     bh_out[...] = bh_s[...].astype(jnp.int32)[None]
-    bmp_out[...] = _pack_pm1(bm_s[...])[None]
+    bmp_out[...] = _pack_bits(bm_s[...] > 0, mats)[None]
 
 
 @functools.partial(
@@ -480,7 +658,7 @@ def ssa_plateau_packed_batched(
 
     Returns (m_packed, itanh, rng, best_H, best_m_packed) after the plateau.
     """
-    interpret = DEFAULT_INTERPRET if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     B, R, N = itanh.shape
     if n_replicas:
         if block_r != n_replicas:
@@ -492,7 +670,6 @@ def ssa_plateau_packed_batched(
             raise ValueError(
                 f"n_trials={R} not divisible by n_replicas={n_replicas}"
             )
-    LANE = 128
     Np = N + (-N) % LANE
     Nwp = Np // 32
     # Pad packed words up to the padded lane count; zero words decode to -1
@@ -515,13 +692,13 @@ def ssa_plateau_packed_batched(
     )
     jperp_specs, jperp_args = [], []
     if n_replicas:
-        jperp_specs = [pl.BlockSpec((1, 1), lambda b, i: (0, 0))]
+        jperp_specs = [_SMEM]
         jperp_args = [jnp.asarray(jperp, jnp.int32).reshape(1, 1)]
     mp_o, it_o, rng_o, bh_o, bmp_o = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, i: (0, 0)),
+            _SMEM,
             *jperp_specs,
             pl.BlockSpec((1, block_r, Nwp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_r, Np), lambda b, i: (b, i, 0)),
@@ -552,6 +729,12 @@ def ssa_plateau_packed_batched(
             pltpu.VMEM((block_r, 1), jnp.float32),
             pltpu.VMEM((block_r, Np), jnp.float32),
         ],
+        compiler_params=_compiler_params(
+            "ssa_plateau_packed_batched",
+            plateau_vmem_bytes("streamed", Np, block_r=block_r,
+                               j_dtype=J.dtype),
+            2,
+        ),
         interpret=interpret,
     )(i0a, *jperp_args, mp, itp, Jp.astype(J.dtype), hp, rngp, bhp, bmp)
     nw = (N + 31) // 32
@@ -658,13 +841,6 @@ def _unpack_pm1_i32(words: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(flat == 1, 1, -1).astype(jnp.int32)
 
 
-def _pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
-    """Kernel-side codec: (bR, N) bool sign bits → (bR, N/32) u32 words."""
-    b = bits.astype(jnp.uint32).reshape(bits.shape[0], -1, 32)
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    return jnp.sum(b << shifts, axis=-1, dtype=jnp.uint32)
-
-
 def _plateau_popcount_kernel(
     *refs,
     # i0_ref,    # (1, C)   int32   per-cycle I0 schedule (whole chain)
@@ -690,6 +866,7 @@ def _plateau_popcount_kernel(
     # rng_s,     # scratch (4, bR, Np) uint32
     # bh_s,      # scratch (bR, 1) int32
     # bmw_s,     # scratch (bR, Nwp) uint32  packed best spins
+    # f_s,       # scratch (bR, Np) int32    field accumulator (row tiles)
     # [ring_s]   # scratch (2, bR, Np) int32 — ONLY when n_replicas > 0
     n_cycles: int,
     n_rnd: int,
@@ -724,12 +901,12 @@ def _plateau_popcount_kernel(
         (i0_ref, jperp_ref, fold_ref, mp_ref, it_ref, sign_ref, mags_ref,
          base_ref, h_ref, rng_ref, bh_ref, bmp_ref,
          mp_out, it_out, rng_out, bh_out, bmp_out,
-         mw_s, m_s, it_s, rng_s, bh_s, bmw_s, ring_s) = refs
+         mw_s, m_s, it_s, rng_s, bh_s, bmw_s, f_s, ring_s) = refs
     else:
         (i0_ref, fold_ref, mp_ref, it_ref, sign_ref, mags_ref,
          base_ref, h_ref, rng_ref, bh_ref, bmp_ref,
          mp_out, it_out, rng_out, bh_out, bmp_out,
-         mw_s, m_s, it_s, rng_s, bh_s, bmw_s) = refs
+         mw_s, m_s, it_s, rng_s, bh_s, bmw_s, f_s) = refs
     mw_s[...] = mp_ref[0]
     m_s[...] = _unpack_pm1_i32(mp_ref[0])
     it_s[...] = it_ref[0]
@@ -739,40 +916,38 @@ def _plateau_popcount_kernel(
     if n_replicas:
         ring_s[0] = m_s[...]
         ring_s[1] = m_s[...]
-    sg = sign_ref[0]          # (Np, Nwp)
-    mg = mags_ref[0]          # (nb, Np, Nwp)
+    nb = mags_ref.shape[1]
+    n_pad = sign_ref.shape[1]
     hf = h_ref[0]             # (1, Np) int32
     hb = hf + base_ref[0]     # field constant: h + base
-    nsg = ~sg                 # XNOR(a, b) = a ^ ~b
-    nb = mg.shape[0]
-    n_pad = sg.shape[0]
-    br = mw_s.shape[0]
     nt = n_pad // field_tile
     one = jnp.uint32(1)
+    mats = _pack_matrices(n_pad)
 
     def field_of(mw):
-        """(bR, Nwp) packed spins → (bR, Np) int32 fields, row-tiled."""
+        """(bR, Nwp) packed spins → (bR, Np) int32 fields, row-tiled.
 
-        def tile_body(t, acc):
-            off = t * field_tile
-            st = jax.lax.dynamic_slice_in_dim(nsg, off, field_tile, axis=0)
-            xs = mw[:, None, :] ^ st[None]       # (bR, tile, Nwp) XNOR words
-            f = jnp.zeros((br, field_tile), jnp.int32)
+        Each tile of J rows is read from the resident planes by ref slice
+        (no whole-plane value is loaded) and its fields land in ``f_s``.
+        """
+
+        def tile_body(t, carry):
+            off = pl.multiple_of(t * field_tile, field_tile)
+            nst = ~sign_ref[0, pl.ds(off, field_tile), :]   # XNOR(a,b)=a^~b
+            xs = mw[:, None, :] ^ nst[None]      # (bR, tile, Nwp) XNOR words
+            f = jnp.zeros(xs.shape[:2], jnp.int32)
             for b in range(nb):
-                mt = jax.lax.dynamic_slice_in_dim(
-                    mg[b], off, field_tile, axis=0
-                )
+                mt = mags_ref[0, b, pl.ds(off, field_tile), :]
                 pc = jnp.sum(
                     jax.lax.population_count(xs & mt[None]).astype(jnp.int32),
                     axis=-1,
                 )
                 f = f + (pc << (b + 1))
-            return jax.lax.dynamic_update_slice_in_dim(acc, f, off, axis=1)
+            f_s[:, pl.ds(off, field_tile)] = f
+            return carry
 
-        acc = jax.lax.fori_loop(
-            0, nt, tile_body, jnp.zeros((br, n_pad), jnp.int32)
-        )
-        return acc + hb
+        jax.lax.fori_loop(0, nt, tile_body, 0)
+        return f_s[...] + hb
 
     def track_best(fold, field):
         # H = -(h·m + m·field)/2, exact int32 (the sum is always even).
@@ -813,7 +988,7 @@ def _plateau_popcount_kernel(
         bits = it_new >= 0
         m_new = jnp.where(bits, 1, -1).astype(jnp.int32)
         m_s[...] = m_new
-        mw_s[...] = _pack_bits(bits)
+        mw_s[...] = _pack_bits(bits, mats)
         if n_replicas:
 
             @pl.when(even)
@@ -876,7 +1051,7 @@ def ssa_plateau_popcount_batched(
 
     Returns (m_packed, itanh, rng, best_H, best_m_packed) after the chain.
     """
-    interpret = DEFAULT_INTERPRET if interpret is None else interpret
+    interpret = default_interpret() if interpret is None else interpret
     B, R, N = itanh.shape
     C = i0_sched.shape[0]
     if jperp_sched is None:
@@ -896,7 +1071,6 @@ def ssa_plateau_popcount_batched(
     else:
         raise ValueError("jperp_sched given but n_replicas == 0")
     nb = mags.shape[1]
-    LANE = 128
     Np = N + (-N) % LANE
     Nwp = Np // 32
     if Np % field_tile:
@@ -925,16 +1099,16 @@ def ssa_plateau_popcount_batched(
     )
     jperp_specs, jperp_args, ring_scratch = [], [], []
     if n_replicas:
-        jperp_specs = [pl.BlockSpec((1, C), lambda b, i: (0, 0))]
+        jperp_specs = [_SMEM]
         jperp_args = [jnp.asarray(jperp_sched, jnp.int32).reshape(1, C)]
         ring_scratch = [pltpu.VMEM((2, block_r, Np), jnp.int32)]
     mp_o, it_o, rng_o, bh_o, bmp_o = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, C), lambda b, i: (0, 0)),
+            _SMEM,
             *jperp_specs,
-            pl.BlockSpec((1, C + 1), lambda b, i: (0, 0)),
+            _SMEM,
             pl.BlockSpec((1, block_r, Nwp), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_r, Np), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, Np, Nwp), lambda b, i: (b, 0, 0)),
@@ -966,8 +1140,15 @@ def ssa_plateau_popcount_batched(
             pltpu.VMEM((4, block_r, Np), jnp.uint32),
             pltpu.VMEM((block_r, 1), jnp.int32),
             pltpu.VMEM((block_r, Nwp), jnp.uint32),
+            pltpu.VMEM((block_r, Np), jnp.int32),
             *ring_scratch,
         ],
+        compiler_params=_compiler_params(
+            "ssa_plateau_popcount_batched",
+            plateau_vmem_bytes("popcount", Np, block_r=block_r, j_bits=nb,
+                               n_replicas=n_replicas, field_tile=field_tile),
+            2,
+        ),
         interpret=interpret,
     )(i0a, *jperp_args, folda, mp, itp, signp, magsp, basep, hp, rngp, bhp, bmp)
     nw = (N + 31) // 32

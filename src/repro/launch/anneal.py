@@ -46,6 +46,8 @@ from repro.core import (
     gset,
     memory,
 )
+from repro.core.engine import model_weight_bits, route_backend
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _resilience_policy(args):
@@ -60,6 +62,18 @@ def _backend_opts(args):
     if args.field_mode != "dense" and args.backend != "sparse":
         return {"field_mode": args.field_mode}
     return {}
+
+
+def _backend_choice(args, model, hp) -> str:
+    """What ``--backend`` resolves to for one instance, and why if 'auto'
+    was kept off the resident kernel by its VMEM budget."""
+    if args.backend != "auto":
+        return args.backend
+    chosen, why = route_backend(
+        "auto", model.n, noise=args.noise, field_mode=args.field_mode,
+        j_bits=model_weight_bits(model), n_cycles=hp.tau,
+    )
+    return f"auto→{chosen}" + (f" ({why})" if why else "")
 
 
 def _partition_mesh(args):
@@ -124,7 +138,7 @@ def _run_service(problem_names, hp, args):
         degraded = "" if r.status == "ok" else f" status={r.status}"
         print(f"{p.name}: best cut {r.result.overall_best_cut} "
               f"avg {r.result.mean_best_cut:.1f} "
-              f"[bucket={r.bucket} batch={r.batch} "
+              f"[backend={r.backend} bucket={r.bucket} batch={r.batch} "
               f"chunks={r.chunks_run}/{r.chunks_total}]{tuned}{degraded}")
         for ev in r.events:
             print(f"  event[{ev.t:.2f}s] {ev.kind}: {ev.detail}")
@@ -187,7 +201,7 @@ def _run_stream(problem_names, hp, args):
             if r.status == "deadline":
                 deadline += 1
             print(f"{p.name}: best cut {r.result.overall_best_cut} "
-                  f"[chunks={r.chunks_run}/{r.chunks_total} "
+                  f"[backend={r.backend} chunks={r.chunks_run}/{r.chunks_total} "
                   f"queued {r.queued_s:.2f}s lane {r.lane_wall_s:.2f}s] "
                   f"status={r.status}"
                   + (" (best-so-far at deadline)"
@@ -239,7 +253,8 @@ def _run_problem_kind(hp, args):
         degraded = "" if r.status == "ok" else f" status={r.status}"
         print(f"{enc.model.name}: objective={r.objective} "
               f"feasible={r.feasible} energy={int(r.result.best_energy.min())} "
-              f"[bucket={r.bucket} batch={r.batch}]{tuned}{degraded}")
+              f"[backend={r.backend} bucket={r.bucket} "
+              f"batch={r.batch}]{tuned}{degraded}")
     info = svc.cache_info()
     print(f"{len(encs)} × {args.problem_kind} in {dt:.1f}s "
           f"({info['programs']} compiled program(s))")
@@ -314,8 +329,9 @@ def main():
                          "bitplanes (DESIGN.md §4; bit-identical results)")
     ap.add_argument("--backend", choices=("sparse", "dense", "pallas", "auto"),
                     default="sparse",
-                    help="'auto' picks pallas at/above MIN_RESIDENT_N spins, "
-                         "dense below (the small-N launch-overhead rule)")
+                    help="'auto' picks pallas at/above MIN_RESIDENT_N spins "
+                         "where the resident kernel fits the chip's VMEM "
+                         "budget, dense otherwise (printed with the reason)")
     ap.add_argument("--field-mode", choices=("dense", "popcount", "auto"),
                     default="dense",
                     help="field contraction arithmetic (dense/pallas "
@@ -339,6 +355,7 @@ def main():
     ap.add_argument("--noise", choices=("xorshift", "threefry"), default="xorshift")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.algo == "ssqa":
         hp = SSQAHyperParams(
@@ -371,7 +388,8 @@ def main():
     extra = (f"; R={hp.n_replicas} jperp_max={hp.jperp_max}"
              if args.algo == "ssqa" else "")
     print(f"{p.name}: N={p.n} |E|={len(p.edges)}; {hp.total_cycles} cycles "
-          f"× {hp.n_trials} trials; backend={args.backend}; "
+          f"× {hp.n_trials} trials; "
+          f"backend={_backend_choice(args, p.to_ising(), hp)}; "
           f"storage={args.storage} ({algo_name}){extra}")
     partition, mesh = _partition_mesh(args)
     cfg = SolverConfig(

@@ -176,8 +176,6 @@ class RooflineReport:
 def roofline(compiled, n_chips: int, hlo_text: Optional[str] = None) -> RooflineReport:
     """Build a RooflineReport from a jax compiled artifact."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # older jax returns [dict]
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     hbm = float(ca.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

@@ -59,7 +59,12 @@ def make_mesh(shape: Sequence[int], axes: Sequence[str]):
             "shrink --mesh-shape or force more host devices with "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N"
         )
-    return jax.make_mesh(shape, tuple(axes))
+    # Auto axes: the programs place data with `with_sharding_constraint` and
+    # NamedSharding, which refuse the Explicit axes jax.make_mesh defaults to.
+    return jax.make_mesh(
+        shape, tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(shape),
+    )
 
 
 def make_spin_mesh(spec: Optional[str] = None, *, axis: str = "model"):

@@ -113,9 +113,9 @@ from repro.core.engine import (
     model_weight_bits,
     next_pow2,
     normalize_problem,
-    resolve_backend,
     resolve_field_mode,
     resolve_partition,
+    route_backend,
     schedule_plateaus,
     validate_model,
 )
@@ -222,6 +222,9 @@ class AnnealResponse:
     # the wall time to ITS chunk-boundary stop, not the whole group's.
     lane_wall_s: Optional[float] = None  # group start → this lane's stop boundary
     queued_s: Optional[float] = None     # streaming only: submit → first seated
+    # The backend the request's plateau program ran on, after 'auto' routing
+    # and any fallback (None for SA, whose Metropolis core has no backend).
+    backend: Optional[str] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,6 +249,30 @@ def _largest_divisor_leq(n: int, k: int) -> int:
 def _opts_key(opts: dict) -> tuple:
     """Hashable projection of backend_opts for the executable-cache key."""
     return tuple(sorted((k, repr(v)) for k, v in opts.items()))
+
+
+class _Program:
+    """A jitted service program that records its one compile.
+
+    The first call lowers and compiles ahead of time — the same executable
+    jit's own dispatch then reuses — so the service can report the compile
+    seconds and show the compiled text (which kernels the program launches)
+    without a second compile.
+    """
+
+    def __init__(self, fn, key, backend):
+        self.key = key
+        self.backend = backend        # the BatchedBackend it was built from
+        self._jit = jax.jit(fn)
+        self.compiled = None
+        self.compile_s: Optional[float] = None
+
+    def __call__(self, *args):
+        if self.compiled is None:
+            t0 = time.perf_counter()
+            self.compiled = self._jit.lower(*args).compile()
+            self.compile_s = time.perf_counter() - t0
+        return self._jit(*args)
 
 
 class _LRUCache:
@@ -553,6 +580,13 @@ class AnnealService:
                 resp.solution, resp.objective, resp.feasible = sol, obj, feas
         return responses  # type: ignore[return-value]
 
+    def programs(self) -> List[_Program]:
+        """Every plateau program in the executable cache (SSA and SSQA
+        groups), each with its ``backend`` and, once it has run, its
+        ``compile_s`` and ``compiled`` executable."""
+        return [p for ent in self._programs.values() for p in ent
+                if isinstance(p, _Program)]
+
     def cache_info(self) -> dict:
         """Executable-cache observability (programs + trace counters)."""
         return {
@@ -682,13 +716,14 @@ class AnnealService:
             opts.pop("storage_layout", None)  # service-wide, passed apart
         else:
             backend, opts = self.backend, dict(self.backend_opts)
-        if backend == "auto":
-            # Resolve per bucket (MIN_RESIDENT_N rule) and drop any opts the
-            # chosen backend doesn't accept — 'auto' users pass a union.
-            backend = resolve_backend(backend, nb)
-            opts = filter_backend_opts(backend, opts,
-                                       partition=self.partition_for(kind, nb))
         carried_events: List[ServiceEvent] = []
+        if backend == "auto":
+            backend, opts, why = self.route_auto(kind, nb, items, opts)
+            if why is not None:
+                carried_events.append(ServiceEvent(
+                    "route", {"backend": backend, "reason": why},
+                    time.perf_counter() - solve_t0,
+                ))
         while True:
             ctx = _GroupCtx(self, kind, nb, items, backend, opts, solve_t0,
                             self._chunk_of(kind, items), events=carried_events)
@@ -728,8 +763,31 @@ class AnnealService:
                 resp = responses[idx]
                 resp.status = ctx.statuses.get(idx, default)
                 resp.events = list(ctx.events)
+                resp.backend = None if kind == "sa" else ctx.backend
             ctx.finish_success()
             return
+
+    def route_auto(self, kind, nb, items, opts):
+        """Resolve backend='auto' for one group: ``(backend, opts, why)``.
+
+        Resident pallas at or above ``engine.MIN_RESIDENT_N`` spins where its
+        kernel fits the chip's VMEM budget, XLA dense otherwise; ``why`` is
+        the budget shortfall when that is what sent the group to dense.  The
+        opts are then filtered to what the chosen backend accepts — 'auto'
+        users pass a union.
+        """
+        hp = items[0][1].hp
+        kernel_opts = dict(
+            opts, noise=self.noise, n_cycles=getattr(hp, "tau", 1),
+            j_bits=max(model_weight_bits(model) for *_, model in items),
+        )
+        if kind == "ssqa":
+            kernel_opts["n_replicas"] = hp.n_replicas
+            kernel_opts.setdefault("noise_mode", "streamed")
+        backend, why = route_backend("auto", nb, **kernel_opts)
+        opts = filter_backend_opts(backend, opts,
+                                   partition=self.partition_for(kind, nb))
+        return backend, opts, why
 
     def _chunk_of(self, kind, items) -> int:
         """The group's chunk width (part of its checkpoint fingerprint)."""
@@ -854,7 +912,8 @@ class AnnealService:
                 self.stats["traces_chunk"] += 1
                 return bk.run_shots(problem, state, plateaus, chunk)
 
-            ent = (bk, jax.jit(init_fn), jax.jit(chunk_fn))
+            ent = (bk, _Program(init_fn, cache_key + ("init",), bk),
+                   _Program(chunk_fn, cache_key + ("chunk",), bk))
             self._programs[cache_key] = ent
         else:
             self.stats["program_cache_hits"] += 1

@@ -174,8 +174,11 @@ def classify_fault(exc: BaseException, backend: str) -> Optional[str]:
     Returns 'oom', 'compile', or None (not recoverable by fallback — the
     exception propagates).  Injected kills and quarantine signals are never
     classified: a kill must escape like a real process death, and
-    quarantines have their own path.  For the pallas backend any unexpected
-    error during the group solve is treated as a compile/launch failure —
+    quarantines have their own path.  Nor is a resident kernel refused for
+    its VMEM budget: that is a configuration error, caught before dispatch,
+    which a silent rerun on another backend would hide.  For the pallas
+    backend any unexpected error during the group solve is treated as a
+    compile/launch failure —
     that backend failing while dense/sparse can still serve the batch is
     precisely the fault the chain exists for.
     """
@@ -184,9 +187,10 @@ def classify_fault(exc: BaseException, backend: str) -> Optional[str]:
         InjectedKill,
         InjectedOOM,
     )
+    from repro.kernels.ssa_update import VmemBudgetError
 
     if isinstance(exc, (InjectedKill, QuarantineFault, AdmissionError,
-                        KeyboardInterrupt)):
+                        VmemBudgetError, KeyboardInterrupt)):
         return None
     if isinstance(exc, (InjectedOOM, MemoryError)):
         return "oom"
@@ -195,7 +199,7 @@ def classify_fault(exc: BaseException, backend: str) -> Optional[str]:
     msg = str(exc)
     if "RESOURCE_EXHAUSTED" in msg or "out of memory" in msg.lower():
         return "oom"
-    if type(exc).__name__ == "XlaRuntimeError":
+    if type(exc).__name__ == "JaxRuntimeError":
         return "compile"
     if backend == "pallas":
         return "compile"

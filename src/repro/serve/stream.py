@@ -90,7 +90,6 @@ from .resilience import (
     ServiceEvent,
     classify_fault,
     fallback_step,
-    filter_backend_opts,
     group_fingerprint,
 )
 
@@ -457,9 +456,8 @@ class StreamingAnnealService:
             opts = dict(svc.backend_opts)
         part = svc.partition_for(kind, nb)
         if backend == "auto":
-            from repro.core.engine import resolve_backend
-            backend = resolve_backend(backend, nb)
-            opts = filter_backend_opts(backend, opts, partition=part)
+            backend, opts, _ = svc.route_auto(
+                kind, nb, [(ticket.seq, req, None, model)], opts)
         opts = svc._resolve_field_opts(backend, opts,
                                        [(ticket.seq, req, None, model)])
         nr = int(getattr(hp, "n_replicas", 0) or 0)
@@ -849,7 +847,7 @@ class StreamingAnnealService:
             chunks_run=s.chunks_done, chunks_total=s.budget,
             chunk_best_cut=np.asarray(s.trace),
             autotune=ticket.autotune, status=status,
-            events=list(ticket.events),
+            events=list(ticket.events), backend=table.backend,
             lane_wall_s=(now - ticket.t_seated
                          if ticket.t_seated is not None else None),
             queued_s=(ticket.t_seated - ticket.submit_t

@@ -101,18 +101,10 @@ TRAIN_FSDP_SP_RULES = DEFAULT_RULES.replace(
 
 
 def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """Version-portable `jax.sharding.AbstractMesh` constructor.
-
-    jax <= 0.4.x takes a tuple of (name, size) pairs; newer releases take
-    (axis_sizes, axis_names).  Spec-construction tests need only the shape,
-    so paper over the signature change here.
-    """
+    """A device-free `jax.sharding.AbstractMesh` (spec-construction tests)."""
     from jax.sharding import AbstractMesh
 
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
+    return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def spin_mesh(n_devices: Optional[int] = None, *, axis: str = "model") -> Mesh:
