@@ -90,6 +90,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import os
 import threading
 import time
@@ -132,6 +133,7 @@ from repro.problems import ProblemEncoding
 from repro.sharding import mesh_fingerprint
 
 from .registry import family_for, registered_algos
+from .spans import Spans
 
 from .resilience import (
     STATUS_DEADLINE,
@@ -257,21 +259,24 @@ class _Program:
     The first call lowers and compiles ahead of time — the same executable
     jit's own dispatch then reuses — so the service can report the compile
     seconds and show the compiled text (which kernels the program launches)
-    without a second compile.
+    without a second compile.  That compile runs inside the ``compile``
+    span, so a compile inside a measured window shows on the timeline.
     """
 
-    def __init__(self, fn, key, backend):
+    def __init__(self, fn, key, backend, spans: Spans):
         self.key = key
         self.backend = backend        # the BatchedBackend it was built from
         self._jit = jax.jit(fn)
+        self._spans = spans
         self.compiled = None
         self.compile_s: Optional[float] = None
 
     def __call__(self, *args):
         if self.compiled is None:
-            t0 = time.perf_counter()
-            self.compiled = self._jit.lower(*args).compile()
-            self.compile_s = time.perf_counter() - t0
+            with self._spans("compile", program=self.key[-1]):
+                t0 = time.perf_counter()
+                self.compiled = self._jit.lower(*args).compile()
+                self.compile_s = time.perf_counter() - t0
         return self._jit(*args)
 
 
@@ -500,6 +505,9 @@ class AnnealService:
         self.partition = partition
         self.mesh = mesh
         self.stats = collections.Counter()
+        # Host spans (repro.serve.spans) count into the same stats.
+        self.spans = Spans(self.stats)
+        self._solve_seq = itertools.count()
         # LRU-bounded: diverse streaming traffic would otherwise grow one
         # live XLA executable per unique group key forever.
         self._programs = _LRUCache(max_cached_executables, self.stats)
@@ -538,25 +546,32 @@ class AnnealService:
         """
         if not requests:
             return []
+        with self.spans("solve", solve=next(self._solve_seq)):
+            return self._solve(requests, progress)
+
+    def _solve(self, requests, progress) -> List[AnnealResponse]:
         t_solve0 = time.perf_counter()
+        spans = self.spans
         self.stats["requests"] += len(requests)
         responses: List[Optional[AnnealResponse]] = [None] * len(requests)
         reports: dict = {}
         groups = collections.defaultdict(list)
         for idx, req in enumerate(requests):
-            try:
-                maxcut, model = normalize_problem(req.problem)
-            except TypeError as e:
-                raise AdmissionError(f"request {idx}: {e}") from e
+            with spans("normalize"):
+                try:
+                    maxcut, model = normalize_problem(req.problem)
+                except TypeError as e:
+                    raise AdmissionError(f"request {idx}: {e}") from e
             if self.policy.validate_admission:
-                self._admit(idx, req, model)
+                with spans("admit"):
+                    self._admit(idx, req, model)
             if isinstance(req.hp, str):
-                hp, reports[idx] = resolve_hyperparams(
-                    req.hp, model, base=req.auto_base, seed=self.autotune_seed,
-                    algo=req.algo,
-                )
+                with spans("autotune"):
+                    hp, reports[idx] = resolve_hyperparams(
+                        req.hp, model, base=req.auto_base,
+                        seed=self.autotune_seed, algo=req.algo,
+                    )
                 req = dataclasses.replace(req, hp=hp)
-                self.stats["autotuned"] += 1
             fam = family_for(req.hp, algo=req.algo)  # raises AdmissionError
             if fam.validate is not None:
                 # Family-owned admission rules are correctness (a backend
@@ -570,14 +585,15 @@ class AnnealService:
             kind, nb = key[0], key[1]
             self._solve_group_resilient(kind, nb, items, responses, progress,
                                         t_solve0)
-        for idx, resp in enumerate(responses):
-            resp.autotune = reports.get(idx)
-            if resp.result is None:
-                continue
-            enc = resp.request.problem
-            if isinstance(enc, ProblemEncoding):
-                sol, obj, feas = enc.best_feasible(resp.result.best_m)
-                resp.solution, resp.objective, resp.feasible = sol, obj, feas
+        with spans("decode"):
+            for idx, resp in enumerate(responses):
+                resp.autotune = reports.get(idx)
+                if resp.result is None:
+                    continue
+                enc = resp.request.problem
+                if isinstance(enc, ProblemEncoding):
+                    sol, obj, feas = enc.best_feasible(resp.result.best_m)
+                    resp.solution, resp.objective, resp.feasible = sol, obj, feas
         return responses  # type: ignore[return-value]
 
     def programs(self) -> List[_Program]:
@@ -718,7 +734,8 @@ class AnnealService:
             backend, opts = self.backend, dict(self.backend_opts)
         carried_events: List[ServiceEvent] = []
         if backend == "auto":
-            backend, opts, why = self.route_auto(kind, nb, items, opts)
+            with self.spans("weight_bits"):  # routing scans the weight bits
+                backend, opts, why = self.route_auto(kind, nb, items, opts)
             if why is not None:
                 carried_events.append(ServiceEvent(
                     "route", {"backend": backend, "reason": why},
@@ -728,7 +745,11 @@ class AnnealService:
             ctx = _GroupCtx(self, kind, nb, items, backend, opts, solve_t0,
                             self._chunk_of(kind, items), events=carried_events)
             try:
-                solver(nb, items, responses, progress, ctx)
+                # One span per attempt; a quarantine's re-runs and solo
+                # retries open their own after this one has closed.
+                with self.spans("group", kind=kind, bucket=nb,
+                                batch=len(items)):
+                    solver(nb, items, responses, progress, ctx)
             except QuarantineFault as qf:
                 if not requeue_quarantine:
                     raise
@@ -912,8 +933,8 @@ class AnnealService:
                 self.stats["traces_chunk"] += 1
                 return bk.run_shots(problem, state, plateaus, chunk)
 
-            ent = (bk, _Program(init_fn, cache_key + ("init",), bk),
-                   _Program(chunk_fn, cache_key + ("chunk",), bk))
+            ent = (bk, _Program(init_fn, cache_key + ("init",), bk, self.spans),
+                   _Program(chunk_fn, cache_key + ("chunk",), bk, self.spans))
             self._programs[cache_key] = ent
         else:
             self.stats["program_cache_hits"] += 1
@@ -921,6 +942,7 @@ class AnnealService:
 
     def _solve_ssa_group(self, nb, items, responses, progress, ctx):
         t0 = time.perf_counter()
+        spans = self.spans
         _, req0, _, _ = items[0]
         hp: SSAHyperParams = req0.hp
         chunk = _largest_divisor_leq(hp.m_shot, self.chunk_shots)
@@ -928,7 +950,8 @@ class AnnealService:
 
         padded, b_live, b_bucket = self._pad_group(items)
         backend, opts = ctx.backend, ctx.backend_opts
-        opts = self._resolve_field_opts(backend, opts, items)
+        with spans("weight_bits"):
+            opts = self._resolve_field_opts(backend, opts, items)
         nr = int(getattr(hp, "n_replicas", 0) or 0)
         if nr:
             # SSQA: the Trotter depth is program-structural (ring width per
@@ -939,21 +962,24 @@ class AnnealService:
             opts["n_replicas"] = nr
             if backend == "pallas":
                 opts.setdefault("noise_mode", "streamed")
-        bk, init_fn, chunk_fn, plateaus = self._ssa_programs(
-            nb=nb, b_bucket=b_bucket, hp=hp, storage=req0.storage,
-            schedule_kind=req0.schedule_kind, backend=backend, opts=opts,
-            chunk=chunk, fire=ctx.fire, kind=ctx.kind,
-        )
+        with spans("program"):
+            bk, init_fn, chunk_fn, plateaus = self._ssa_programs(
+                nb=nb, b_bucket=b_bucket, hp=hp, storage=req0.storage,
+                schedule_kind=req0.schedule_kind, backend=backend, opts=opts,
+                chunk=chunk, fire=ctx.fire, kind=ctx.kind,
+            )
         stored_per_iter = sum(p.length for p in plateaus if p.eligible)
 
-        stacked = bk.stack([model for _, _, _, model in padded])
+        with spans("stack"):
+            stacked = bk.stack([model for _, _, _, model in padded])
         ctx.fire("oom", backend=backend, kind="ssa", bucket=nb, batch=b_bucket,
                  j_mode=getattr(bk, "j_mode", None))
-        ns0 = bk.init_noise(
-            [req.seed for _, req, _, _ in padded],
-            [model.n for _, _, _, model in padded],
-        )
-        state = init_fn(stacked, ns0)
+        with spans("init"):
+            ns0 = bk.init_noise(
+                [req.seed for _, req, _, _ in padded],
+                [model.n for _, _, _, model in padded],
+            )
+            state = init_fn(stacked, ns0)
 
         state, chunk_traces, stops = self._chunk_loop(
             ctx.kind, nb, items, n_chunks, progress,
@@ -961,32 +987,46 @@ class AnnealService:
             lambda st: st.best_H, ctx, width=b_bucket,
             snap=lambda st: bk.finalize(st),
         )
-        bh_dev, bm_dev = bk.finalize(state)  # layout-agnostic (unpacks bitplanes)
-        best_H = np.asarray(bh_dev)
-        best_m = np.asarray(bm_dev)
-        wall = time.perf_counter() - t0
+        with spans("finalize"):
+            bh_dev, bm_dev = bk.finalize(state)  # unpacks bitplanes too
+            self._group_responses(
+                items, responses, np.asarray(bh_dev), np.asarray(bm_dev),
+                chunk_traces, stops, nb=nb, b_live=b_live, n_chunks=n_chunks,
+                t0=t0,
+                result=lambda req, model, **f: AnnealResult(
+                    **f, traj=None,
+                    stored_bits_per_iter=model.n * stored_per_iter,
+                    hp=req.hp),
+            )
 
+    def _group_responses(self, items, responses, best_H, best_m, traces,
+                         stops, *, nb, b_live, n_chunks, t0, result):
+        """One response per request of a finished group.
+
+        Each lane reports its bests frozen at its own stop boundary, or the
+        group's final ``best_H``/``best_m`` when it ran to the end.
+        ``result(req, model, **fields)`` builds the family's result object.
+        """
+        wall = time.perf_counter() - t0
         for slot, (idx, req, maxcut, model) in enumerate(items):
             stop = stops[slot]
             if stop is not None and stop.get("best_H") is not None:
                 bh, bm_full = stop["best_H"], stop["best_m"]
             else:
                 bh, bm_full = best_H[slot], best_m[slot]
-            result = AnnealResult(
+            res = result(
+                req, model,
                 best_cut=np.asarray(finalize_cut(bh, maxcut)),
                 best_energy=bh,
                 best_m=bm_full[:, : model.n],
                 energy_mean=None,
                 energy_min=None,
-                traj=None,
-                stored_bits_per_iter=model.n * stored_per_iter,
-                hp=req.hp,
             )
             responses[idx] = AnnealResponse(
-                request=req, result=result, wall_s=wall, bucket=nb,
-                batch=b_live, chunks_run=len(chunk_traces[slot]),
+                request=req, result=res, wall_s=wall, bucket=nb,
+                batch=b_live, chunks_run=len(traces[slot]),
                 chunks_total=n_chunks,
-                chunk_best_cut=np.asarray(chunk_traces[slot]),
+                chunk_best_cut=np.asarray(traces[slot]),
                 lane_wall_s=(stop["t_abs"] - t0 if stop is not None else wall),
             )
 
@@ -1001,6 +1041,49 @@ class AnnealService:
         chunk_cycles = hp.n_cycles // n_chunks
 
         padded, b_live, b_bucket = self._pad_group(items)
+        with self.spans("program"):
+            init_fn, chunk_fn = self._sa_programs(nb, b_bucket, hp,
+                                                  chunk_cycles, ctx)
+
+        # SA reuses the sparse stacking (gather-based ΔH).
+        with self.spans("stack"):
+            stacker = make_batched_backend(
+                "sparse", n_bucket=nb, n_trials=hp.n_trials, noise="xorshift"
+            )
+            stacked = stacker.stack([model for _, _, _, model in padded])
+        with self.spans("init"):
+            keys = jnp.stack(
+                [jax.random.PRNGKey(req.seed) for _, req, _, _ in padded]
+            )
+            n_lives = jnp.asarray([model.n for _, _, _, model in padded],
+                                  jnp.int32)
+            temps = np.asarray(
+                sa_temperature_ladder(hp.t_start, hp.t_end, hp.n_cycles),
+                np.float32,
+            )
+            carry = init_fn(stacked, keys)
+            chunk_arrays = [
+                jnp.asarray(temps[c * chunk_cycles : (c + 1) * chunk_cycles])
+                for c in range(n_chunks)
+            ]
+
+        carry, chunk_traces, stops = self._chunk_loop(
+            "sa", nb, items, n_chunks, progress,
+            lambda ca, c: chunk_fn(stacked, ca, chunk_arrays[c], n_lives),
+            carry, lambda ca: ca[3], ctx, width=b_bucket,
+            snap=lambda ca: (ca[3], ca[4]),
+        )
+        with self.spans("finalize"):
+            _, _, _, best_H, best_m = carry
+            self._group_responses(
+                items, responses, np.asarray(best_H), np.asarray(best_m),
+                chunk_traces, stops, nb=nb, b_live=b_live, n_chunks=n_chunks,
+                t0=t0,
+                result=lambda req, model, **f: SAResult(**f, hp=req.hp),
+            )
+
+    def _sa_programs(self, nb, b_bucket, hp, chunk_cycles, ctx):
+        """Jitted ``(init_fn, chunk_fn)`` of an SA group shape, cached."""
         cache_key = ("sa", nb, b_bucket, hp.n_trials, chunk_cycles)
         ent = self._programs.get(cache_key)
         if ent is None:
@@ -1030,59 +1113,7 @@ class AnnealService:
             self._programs[cache_key] = ent
         else:
             self.stats["program_cache_hits"] += 1
-        init_fn, chunk_fn = ent
-
-        # SA reuses the sparse stacking (gather-based ΔH).
-        stacker = make_batched_backend(
-            "sparse", n_bucket=nb, n_trials=hp.n_trials, noise="xorshift"
-        )
-        stacked = stacker.stack([model for _, _, _, model in padded])
-        keys = jnp.stack(
-            [jax.random.PRNGKey(req.seed) for _, req, _, _ in padded]
-        )
-        n_lives = jnp.asarray([model.n for _, _, _, model in padded], jnp.int32)
-        temps = np.asarray(
-            sa_temperature_ladder(hp.t_start, hp.t_end, hp.n_cycles), np.float32
-        )
-        carry = init_fn(stacked, keys)
-
-        chunk_arrays = [
-            jnp.asarray(temps[c * chunk_cycles : (c + 1) * chunk_cycles])
-            for c in range(n_chunks)
-        ]
-
-        carry, chunk_traces, stops = self._chunk_loop(
-            "sa", nb, items, n_chunks, progress,
-            lambda ca, c: chunk_fn(stacked, ca, chunk_arrays[c], n_lives),
-            carry, lambda ca: ca[3], ctx, width=b_bucket,
-            snap=lambda ca: (ca[3], ca[4]),
-        )
-        _, _, _, best_H, best_m = carry
-        best_H = np.asarray(best_H)
-        best_m = np.asarray(best_m)
-        wall = time.perf_counter() - t0
-
-        for slot, (idx, req, maxcut, model) in enumerate(items):
-            stop = stops[slot]
-            if stop is not None and stop.get("best_H") is not None:
-                bh, bm_full = stop["best_H"], stop["best_m"]
-            else:
-                bh, bm_full = best_H[slot], best_m[slot]
-            result = SAResult(
-                best_cut=np.asarray(finalize_cut(bh, maxcut)),
-                best_energy=bh,
-                best_m=bm_full[:, : model.n],
-                energy_mean=None,
-                energy_min=None,
-                hp=req.hp,
-            )
-            responses[idx] = AnnealResponse(
-                request=req, result=result, wall_s=wall, bucket=nb,
-                batch=b_live, chunks_run=len(chunk_traces[slot]),
-                chunks_total=n_chunks,
-                chunk_best_cut=np.asarray(chunk_traces[slot]),
-                lane_wall_s=(stop["t_abs"] - t0 if stop is not None else wall),
-            )
+        return ent
 
     # ------------------------------------------------------------------
     # PT-SSA groups
@@ -1101,7 +1132,52 @@ class AnnealService:
         n_chunks = hp.n_rounds // chunk
 
         padded, b_live, b_bucket = self._pad_group(items)
-        opts = self._resolve_field_opts(backend, opts, items)
+        with self.spans("weight_bits"):
+            opts = self._resolve_field_opts(backend, opts, items)
+        with self.spans("program"):
+            bk, init_fn, chunk_fn = self._ptssa_programs(
+                nb, b_bucket, hp, backend, opts, chunk, ctx)
+
+        with self.spans("stack"):
+            stacked = bk.stack([model for _, _, _, model in padded])
+        ctx.fire("oom", backend=backend, kind="ptssa", bucket=nb,
+                 batch=b_bucket, j_mode=getattr(bk, "j_mode", None))
+        with self.spans("init"):
+            ns0 = bk.init_noise(
+                [req.seed for _, req, _, _ in padded],
+                [model.n for _, _, _, model in padded],
+            )
+            state = init_fn(stacked, ns0)
+
+            # Same swap-key derivation as anneal_pt_ssa, split once over all
+            # rounds then sliced per chunk — chunked == unchunked, bitwise.
+            all_keys = jnp.stack([
+                jax.random.split(
+                    jax.random.PRNGKey(req.seed ^ 0x5CA1AB1E), hp.n_rounds
+                )
+                for _, req, _, _ in padded
+            ])  # (B, n_rounds, 2)
+            parities = jnp.arange(hp.n_rounds, dtype=jnp.int32) % 2
+
+        def step(st, c):
+            sl = slice(c * chunk, (c + 1) * chunk)
+            return chunk_fn(stacked, st, all_keys[:, sl], parities[sl])
+
+        state, chunk_traces, stops = self._chunk_loop(
+            "ptssa", nb, items, n_chunks, progress, step, state,
+            lambda st: st.best_H, ctx, width=b_bucket,
+            snap=lambda st: (st.best_H, st.best_m),
+        )
+        with self.spans("finalize"):
+            self._group_responses(
+                items, responses, np.asarray(state.best_H),
+                np.asarray(state.best_m), chunk_traces, stops, nb=nb,
+                b_live=b_live, n_chunks=n_chunks, t0=t0,
+                result=lambda req, model, **f: PTSSAResult(**f, hp=req.hp),
+            )
+
+    def _ptssa_programs(self, nb, b_bucket, hp, backend, opts, chunk, ctx):
+        """``(bk, init_fn, chunk_fn)`` of a PT-SSA group shape, cached."""
         cache_key = ("ptssa", backend, _opts_key(opts), nb, b_bucket, hp,
                      self.noise, chunk)
         ent = self._programs.get(cache_key)
@@ -1133,61 +1209,7 @@ class AnnealService:
             self._programs[cache_key] = ent
         else:
             self.stats["program_cache_hits"] += 1
-        bk, init_fn, chunk_fn = ent
-
-        stacked = bk.stack([model for _, _, _, model in padded])
-        ctx.fire("oom", backend=backend, kind="ptssa", bucket=nb,
-                 batch=b_bucket, j_mode=getattr(bk, "j_mode", None))
-        ns0 = bk.init_noise(
-            [req.seed for _, req, _, _ in padded],
-            [model.n for _, _, _, model in padded],
-        )
-        state = init_fn(stacked, ns0)
-
-        # Same swap-key derivation as anneal_pt_ssa, split once over all
-        # rounds then sliced per chunk — chunked == unchunked, bitwise.
-        all_keys = jnp.stack([
-            jax.random.split(
-                jax.random.PRNGKey(req.seed ^ 0x5CA1AB1E), hp.n_rounds
-            )
-            for _, req, _, _ in padded
-        ])  # (B, n_rounds, 2)
-        parities = jnp.arange(hp.n_rounds, dtype=jnp.int32) % 2
-
-        def step(st, c):
-            sl = slice(c * chunk, (c + 1) * chunk)
-            return chunk_fn(stacked, st, all_keys[:, sl], parities[sl])
-
-        state, chunk_traces, stops = self._chunk_loop(
-            "ptssa", nb, items, n_chunks, progress, step, state,
-            lambda st: st.best_H, ctx, width=b_bucket,
-            snap=lambda st: (st.best_H, st.best_m),
-        )
-        best_H = np.asarray(state.best_H)
-        best_m = np.asarray(state.best_m)
-        wall = time.perf_counter() - t0
-
-        for slot, (idx, req, maxcut, model) in enumerate(items):
-            stop = stops[slot]
-            if stop is not None and stop.get("best_H") is not None:
-                bh, bm_full = stop["best_H"], stop["best_m"]
-            else:
-                bh, bm_full = best_H[slot], best_m[slot]
-            result = PTSSAResult(
-                best_cut=np.asarray(finalize_cut(bh, maxcut)),
-                best_energy=bh,
-                best_m=bm_full[:, : model.n],
-                energy_mean=None,
-                energy_min=None,
-                hp=req.hp,
-            )
-            responses[idx] = AnnealResponse(
-                request=req, result=result, wall_s=wall, bucket=nb,
-                batch=b_live, chunks_run=len(chunk_traces[slot]),
-                chunks_total=n_chunks,
-                chunk_best_cut=np.asarray(chunk_traces[slot]),
-                lane_wall_s=(stop["t_abs"] - t0 if stop is not None else wall),
-            )
+        return ent
 
     # ------------------------------------------------------------------
     # Shared chunk loop: streaming best_H reports, early stop, checkpoints,
@@ -1212,6 +1234,7 @@ class AnnealService:
         from the final state).  ``width`` is the padded batch width, feeding
         the slot/live-lane occupancy counters the streaming benchmark reads.
         """
+        spans = self.spans
         traces = [[] for _ in items]
         start = 0
         if ctx is not None and ctx.ckpt is not None:
@@ -1222,82 +1245,92 @@ class AnnealService:
         frozen = [False] * len(items)
         stops: List[Optional[dict]] = [None] * len(items)
         for c in range(start, n_chunks):
-            self.stats["slot_chunks"] += width if width is not None else len(items)
-            self.stats["live_lane_chunks"] += sum(
-                1 for s in range(len(items)) if not done[s]
-            )
-            state = step(state, c)
-            best_H = np.asarray(best_of(state))  # device sync: the report
-            # Non-finite watchdog.  The 'nan' hook corrupts the detector's
-            # float view of the readings (slots it names), emulating a
-            # numeric blow-up; detection itself is the production check.
-            readings = best_H.astype(np.float64)
-            spec = ctx.fire("nan", kind=kind, chunk=c) if ctx else None
-            if spec is not None:
-                slots = [s for s in (spec.slots or range(len(items)))
-                         if s < len(items)]
-                for s in slots:
-                    readings[s] = np.nan
-            bad = tuple(
-                s for s in range(len(items))
-                if not np.all(np.isfinite(readings[s]))
-            )
-            if bad:
-                self.stats["nonfinite_detected"] += 1
-                raise QuarantineFault(bad)
-            bests = []
-            for slot, (idx, req, maxcut, model) in enumerate(items):
-                obj = np.asarray(finalize_cut(best_H[slot], maxcut))
-                best = int(np.max(obj))
-                if not frozen[slot]:
-                    traces[slot].append(best)
-                bests.append(best)
-            self.stats["chunks_run"] += 1
-            if progress is not None:
-                progress(AnnealProgress(
-                    kind=kind, bucket=nb, chunk=c, chunks_total=n_chunks,
-                    request_indices=tuple(idx for idx, *_ in items),
-                    best_cut=tuple(bests),
-                ))
-            now = time.perf_counter()
-            newly: List[int] = []
-            if ctx is not None:
-                ctx.save(c + 1, state, traces)
-                ctx.fire("kill", kind=kind, chunk=c)
-                for slot, (idx, req, _, _) in enumerate(items):
-                    if done[slot]:
-                        continue
-                    if req.target_cut is not None and bests[slot] >= req.target_cut:
-                        done[slot] = frozen[slot] = True
-                        stops[slot] = {"chunk": c + 1, "t_abs": now}
-                        newly.append(slot)
-                    elif (req.deadline_s is not None
-                          and now - ctx.solve_t0 >= req.deadline_s):
-                        done[slot] = frozen[slot] = True
-                        stops[slot] = {"chunk": c + 1, "t_abs": now}
-                        newly.append(slot)
-                        ctx.statuses[idx] = STATUS_DEADLINE
-                        ctx._event("deadline", request=idx, chunk=c,
-                                   best=bests[slot])
-                        self.stats["deadline_expirations"] += 1
-            else:
-                for slot, (idx, req, _, _) in enumerate(items):
-                    if (not done[slot] and req.target_cut is not None
-                            and bests[slot] >= req.target_cut):
-                        done[slot] = frozen[slot] = True
-                        stops[slot] = {"chunk": c + 1, "t_abs": now}
-                        newly.append(slot)
-            group_ends = (c + 1 == n_chunks) or (bool(done) and all(done))
-            if newly and not group_ends and snap is not None:
-                # The group continues past these lanes' stop boundary:
-                # freeze their result here so later chunks (which they no
-                # longer participate in, logically) can't change it.
-                bh_s, bm_s = snap(state)
-                bh_s, bm_s = np.asarray(bh_s), np.asarray(bm_s)
-                for slot in newly:
-                    stops[slot]["best_H"] = bh_s[slot].copy()
-                    stops[slot]["best_m"] = bm_s[slot].copy()
+            with spans("chunk", chunk=c):
+                with spans("chunk.launch"):
+                    self.stats["slot_chunks"] += (width if width is not None
+                                                  else len(items))
+                    self.stats["live_lane_chunks"] += sum(
+                        1 for s in range(len(items)) if not done[s]
+                    )
+                    state = step(state, c)
+                with spans("chunk.sync"):
+                    best_H = np.asarray(best_of(state))  # the report
+                with spans("chunk.book"):
+                    newly = self._chunk_book(kind, nb, items, n_chunks, c,
+                                             progress, state, best_H, traces,
+                                             done, frozen, stops, ctx)
+                group_ends = (c + 1 == n_chunks) or (bool(done) and all(done))
+                if newly and not group_ends and snap is not None:
+                    # The group continues past these lanes' stop boundary:
+                    # freeze their result here so later chunks (which they
+                    # no longer participate in, logically) can't change it.
+                    with spans("chunk.snap"):
+                        bh_s, bm_s = snap(state)
+                        bh_s, bm_s = np.asarray(bh_s), np.asarray(bm_s)
+                        for slot in newly:
+                            stops[slot]["best_H"] = bh_s[slot].copy()
+                            stops[slot]["best_m"] = bm_s[slot].copy()
             if done and all(done) and c + 1 < n_chunks:
                 self.stats["early_stops"] += 1
                 break
         return state, traces, stops
+
+    def _chunk_book(self, kind, nb, items, n_chunks, c, progress, state,
+                    best_H, traces, done, frozen, stops, ctx) -> List[int]:
+        """Host bookkeeping at one chunk boundary: the non-finite watchdog,
+        per-lane bests and traces, progress, checkpoint and kill hook, then
+        the target and deadline stop checks.  Returns the lanes that stopped
+        at this boundary."""
+        # Non-finite watchdog.  The 'nan' hook corrupts the detector's
+        # float view of the readings (slots it names), emulating a
+        # numeric blow-up; detection itself is the production check.
+        readings = best_H.astype(np.float64)
+        spec = ctx.fire("nan", kind=kind, chunk=c) if ctx else None
+        if spec is not None:
+            slots = [s for s in (spec.slots or range(len(items)))
+                     if s < len(items)]
+            for s in slots:
+                readings[s] = np.nan
+        bad = tuple(
+            s for s in range(len(items))
+            if not np.all(np.isfinite(readings[s]))
+        )
+        if bad:
+            self.stats["nonfinite_detected"] += 1
+            raise QuarantineFault(bad)
+        bests = []
+        for slot, (idx, req, maxcut, model) in enumerate(items):
+            obj = np.asarray(finalize_cut(best_H[slot], maxcut))
+            best = int(np.max(obj))
+            if not frozen[slot]:
+                traces[slot].append(best)
+            bests.append(best)
+        self.stats["chunks_run"] += 1
+        if progress is not None:
+            progress(AnnealProgress(
+                kind=kind, bucket=nb, chunk=c, chunks_total=n_chunks,
+                request_indices=tuple(idx for idx, *_ in items),
+                best_cut=tuple(bests),
+            ))
+        now = time.perf_counter()
+        newly: List[int] = []
+        if ctx is not None:
+            ctx.save(c + 1, state, traces)
+            ctx.fire("kill", kind=kind, chunk=c)
+        for slot, (idx, req, _, _) in enumerate(items):
+            if done[slot]:
+                continue
+            if req.target_cut is not None and bests[slot] >= req.target_cut:
+                done[slot] = frozen[slot] = True
+                stops[slot] = {"chunk": c + 1, "t_abs": now}
+                newly.append(slot)
+            elif (ctx is not None and req.deadline_s is not None
+                  and now - ctx.solve_t0 >= req.deadline_s):
+                done[slot] = frozen[slot] = True
+                stops[slot] = {"chunk": c + 1, "t_abs": now}
+                newly.append(slot)
+                ctx.statuses[idx] = STATUS_DEADLINE
+                ctx._event("deadline", request=idx, chunk=c,
+                           best=bests[slot])
+                self.stats["deadline_expirations"] += 1
+        return newly

@@ -78,6 +78,7 @@ from .anneal_service import (
     _opts_key,
 )
 from .registry import family_for
+from .spans import QUANTUM
 from .resilience import (
     STATUS_DEADLINE,
     STATUS_FAILED,
@@ -286,7 +287,6 @@ class StreamingAnnealService:
                 seed=svc.autotune_seed, algo=request.algo,
             )
             request = dataclasses.replace(request, hp=hp)
-            self.stats["autotuned"] += 1
         fam = family_for(request.hp, algo=request.algo)
         if fam.solver != "_solve_ssa_group":
             raise AdmissionError(
@@ -327,15 +327,25 @@ class StreamingAnnealService:
         of one table (round-robin), retire + backfill at its boundary.
 
         Returns False when the stream is idle (empty queue, no live slots).
-        Call from a single driver thread (or use ``start()``).
+        Call from one scheduling thread (or use ``start()``).  A quantum's
+        host time is spanned (:mod:`repro.serve.spans`): ``quantum`` around
+        ``quantum.seat`` (seating and splicing queued work) and the chunk's
+        ``quantum.launch``, ``quantum.sync`` and ``quantum.retire``.
         """
+        spans = self.service.spans
         with self._lock:
             self._shed_expired()
-            self._seat_queued()
-            table = self._pick_table()
+            if not self._queue and not any(
+                    t.n_live for t in self._tables.values()):
+                return False
+        with spans("quantum"):
+            with self._lock:
+                with spans("quantum.seat"):
+                    self._seat_queued()
+                table = self._pick_table()
             if table is None:
                 return False
-        self._run_quantum(table, progress)
+            self._run_quantum(table, progress)
         return True
 
     def run_until_idle(
@@ -369,18 +379,23 @@ class StreamingAnnealService:
                 self._stop.wait(poll_s)
 
     def stream_stats(self) -> dict:
-        """Scheduler observability: queue depth, occupancy, counters."""
+        """Scheduler observability: queue depth, occupancy, counters, and
+        the host ms per quantum of each of its activities."""
         with self._lock:
             live = sum(t.n_live for t in self._tables.values())
             width = sum(len(t.slots) for t in self._tables.values())
             slot_chunks = self.stats["stream_slot_chunks"]
             live_chunks = self.stats["stream_live_lane_chunks"]
+            per_quantum = self.service.spans.ms_per(
+                QUANTUM, self.stats["span_n.quantum"])
         return {
             "queued": len(self._queue),
             "tables": len(self._tables),
             "live_slots": live,
             "table_width": width,
             "occupancy": (live_chunks / slot_chunks) if slot_chunks else 0.0,
+            "quantum_host_ms": {k.split(".", 1)[1]: v
+                                for k, v in per_quantum.items()},
             **{k: v for k, v in self.stats.items()
                if k.startswith("stream_")},
         }
@@ -670,11 +685,21 @@ class StreamingAnnealService:
     def _run_quantum(self, table: _SlotTable, progress):
         svc = self.service
         try:
-            new_state = table.chunk_fn(table.stacked, table.state)
-            best_H = np.asarray(new_state.best_H)
+            with svc.spans("quantum.launch"):
+                new_state = table.chunk_fn(table.stacked, table.state)
+            with svc.spans("quantum.sync"):
+                best_H = np.asarray(new_state.best_H)
         except Exception as exc:  # noqa: BLE001 — classified below
             self._table_fault(table, exc)
             return
+        with svc.spans("quantum.retire"):
+            self._retire(table, new_state, best_H, progress)
+
+    def _retire(self, table: _SlotTable, new_state, best_H, progress):
+        """Boundary processing after one chunk: per-slot bests, the
+        non-finite detector, progress, checkpoints, then retire finished
+        lanes (target, budget, deadline, quarantine)."""
+        svc = self.service
         table.state = new_state
         table.quanta += 1
         now = time.monotonic()
