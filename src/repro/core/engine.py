@@ -161,6 +161,7 @@ POPCOUNT_AUTO_MAX_BITS = 4
 # ---------------------------------------------------------------------------
 from repro.kernels.bitplane import (  # noqa: E402
     PackedJ,
+    adjacency_planes,
     adjacency_weight_bits,
     pack_couplings_from_adjacency,
     pack_spins,
@@ -1656,22 +1657,42 @@ def pad_degree(model: IsingModel, d: int) -> IsingModel:
     )
 
 
+def _distinct(models):
+    """``(distinct, lanes)``: each distinct model object once, in first-seen
+    order, and for every lane the index of its model in ``distinct``.
+
+    Identity, not equality: equal but distinct objects stay apart.  The
+    caller's list holds every model, so no id is reused meanwhile.
+    """
+    index: dict = {}
+    distinct: list = []
+    lanes: list = []
+    for m in models:
+        k = index.get(id(m))
+        if k is None:
+            k = index[id(m)] = len(distinct)
+            distinct.append(m)
+        lanes.append(k)
+    return distinct, lanes
+
+
 def _stack_sparse_models(models, n_bucket: int) -> dict:
-    """Stacked, bucket-padded adjacency views {h, nbr_idx, nbr_w}."""
-    padded = [pad_model(m, n_bucket) for m in models]
+    """Stacked, bucket-padded adjacency views {h, nbr_idx, nbr_w}.
+
+    Each distinct model is padded once; lanes that carry the same model
+    object index its one copy.
+    """
+    distinct, lanes = _distinct(models)
+    padded = [pad_model(m, n_bucket) for m in distinct]
     d = max(m.max_degree for m in padded)
     padded = [pad_degree(m, d) for m in padded]
-    return {
-        "h": jnp.asarray(
-            np.stack([np.asarray(m.h, np.int32) for m in padded]), jnp.int32
-        ),
-        "nbr_idx": jnp.asarray(
-            np.stack([np.asarray(m.nbr_idx) for m in padded]), jnp.int32
-        ),
-        "nbr_w": jnp.asarray(
-            np.stack([np.asarray(m.nbr_w) for m in padded]), jnp.int32
-        ),
-    }
+
+    def stacked(field):
+        views = [np.asarray(getattr(m, field), np.int32) for m in padded]
+        return jnp.asarray(np.stack([views[k] for k in lanes]), jnp.int32)
+
+    return {"h": stacked("h"), "nbr_idx": stacked("nbr_idx"),
+            "nbr_w": stacked("nbr_w")}
 
 
 def extract_slot(tree, slot: int):
@@ -1716,16 +1737,22 @@ class BatchedSparseBackend(_VmapBatchedBackend):
 
 
 def _stack_dense_models(models, n_bucket: int, j_dtype) -> dict:
-    """Stacked, bucket-padded dense views {h (B,N), J (B,N,N)}."""
+    """Stacked, bucket-padded dense views {h (B,N), J (B,N,N)}.
+
+    Each distinct model's J is built once; lanes that carry the same model
+    object stack its one copy.
+    """
     from repro.kernels.ssa_update import pad_to  # lazy: keeps core light
 
+    distinct, lanes = _distinct(models)
     Js, hs = [], []
-    for m in models:
+    for m in distinct:
         Js.append(
             pad_to(pad_to(jnp.asarray(m.dense_J(), j_dtype), 0, n_bucket), 1, n_bucket)
         )
         hs.append(pad_to(jnp.asarray(m.h, jnp.int32), 0, n_bucket))
-    return {"h": jnp.stack(hs), "J": jnp.stack(Js)}
+    return {"h": jnp.stack([hs[k] for k in lanes]),
+            "J": jnp.stack([Js[k] for k in lanes])}
 
 
 def _stack_packed_models(models, n_bucket: int, j_bits: int) -> dict:
@@ -1735,23 +1762,19 @@ def _stack_packed_models(models, n_bucket: int, j_bits: int) -> dict:
     stacked ``mags`` tensor has one uniform shape (a program-structural
     parameter — the executable cache keys on it); callers pass the group
     maximum from :func:`repro.kernels.bitplane.adjacency_weight_bits`.
+    Each distinct model is packed once on the host; the (B, ...) arrays
+    index those packings by lane and move to the device in one transfer
+    each.
     """
-    hs, signs, magss, bases = [], [], [], []
-    for m in models:
+    distinct, lanes = _distinct(models)
+    planes = []   # per distinct model: (h, sign, mags, base) host arrays
+    for m in distinct:
         p = pad_model(m, n_bucket)
-        pj = pack_couplings_from_adjacency(
-            p.n, p.nbr_idx, p.nbr_w, n_bits=j_bits
-        )
-        hs.append(jnp.asarray(p.h, jnp.int32))
-        signs.append(pj.sign)
-        magss.append(pj.mags)
-        bases.append(pj.base)
-    return {
-        "h": jnp.stack(hs),
-        "sign": jnp.stack(signs),
-        "mags": jnp.stack(magss),
-        "base": jnp.stack(bases),
-    }
+        planes.append((np.asarray(p.h, np.int32),
+                       *adjacency_planes(p.n, p.nbr_idx, p.nbr_w,
+                                         n_bits=j_bits)))
+    return {key: jnp.asarray(np.stack([planes[k][i] for k in lanes]))
+            for i, key in enumerate(("h", "sign", "mags", "base"))}
 
 
 class BatchedDenseBackend(_VmapBatchedBackend):

@@ -54,6 +54,7 @@ __all__ = [
     "PackedJ",
     "pack_couplings",
     "pack_couplings_from_adjacency",
+    "adjacency_planes",
     "adjacency_weight_bits",
     "packed_j_nbytes",
 ]
@@ -241,6 +242,16 @@ def pack_couplings_from_adjacency(
     first, matching ``IsingModel.dense_J``.  O(N·max_deg) host work — this
     is the constructor the 20k-spin instances use.
     """
+    return PackedJ(*(jnp.asarray(a) for a in
+                     adjacency_planes(n, nbr_idx, nbr_w, n_bits)))
+
+
+def adjacency_planes(n: int, nbr_idx: np.ndarray, nbr_w: np.ndarray,
+                     n_bits=None):
+    """The planes of :func:`pack_couplings_from_adjacency` as host arrays:
+    ``(sign uint32 (N, W), mags uint32 (n_bits, N, W), base int32 (N,))``,
+    for callers that stack many problems on the host before one transfer.
+    """
     n = int(n)
     nw = packed_words(n)
     r, c, wsum = _coalesced_adjacency(n, nbr_idx, nbr_w)
@@ -261,10 +272,7 @@ def pack_couplings_from_adjacency(
             mags[b], (r[sel], word[sel]), np.uint32(1) << bit[sel]
         )
         np.add.at(base, r[sel], -(np.int64(1) << b))
-    return PackedJ(
-        jnp.asarray(sign), jnp.asarray(mags),
-        jnp.asarray(base.astype(np.int32)),
-    )
+    return sign, mags, base.astype(np.int32)
 
 
 def packed_j_nbytes(n: int, n_bits: int = 1) -> int:

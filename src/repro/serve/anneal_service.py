@@ -253,6 +253,24 @@ def _opts_key(opts: dict) -> tuple:
     return tuple(sorted((k, repr(v)) for k, v in opts.items()))
 
 
+class _ByIdentity:
+    """``fn(obj)`` computed once per distinct object, for one ``solve()`` call.
+
+    Keyed by ``id(obj)``; each entry holds its object, so no id is reused
+    while the memo lives.  Equal but distinct objects are computed apart.
+    """
+
+    def __init__(self, fn):
+        self._fn = fn
+        self._memo: dict = {}
+
+    def __call__(self, obj):
+        ent = self._memo.get(id(obj))
+        if ent is None:
+            ent = self._memo[id(obj)] = (obj, self._fn(obj))
+        return ent[1]
+
+
 class _Program:
     """A jitted service program that records its one compile.
 
@@ -345,8 +363,10 @@ class _GroupCtx:
 
     def __init__(self, service: "AnnealService", kind: str, nb: int, items,
                  backend: str, backend_opts: dict, solve_t0: float,
-                 chunk: int, events: Optional[List[ServiceEvent]] = None):
+                 chunk: int, weight_bits,
+                 events: Optional[List[ServiceEvent]] = None):
         self.kind = kind
+        self.weight_bits = weight_bits
         self.backend = backend
         self.backend_opts = dict(backend_opts)
         self.solve_t0 = solve_t0
@@ -537,7 +557,12 @@ class AnnealService:
 
         ``solve([])`` returns ``[]``.  The same request object may appear
         multiple times in one batch (aliased requests): each occurrence gets
-        its own response.  ``hp='auto'`` requests are resolved *before*
+        its own response.  Requests that carry the same ``problem`` object
+        share its preparation within the call: it is normalized, validated,
+        scanned for weight bits and packed once, and its lanes index that
+        one result.  Equal but distinct objects are prepared apart.  So the
+        problems' arrays must not be mutated while a call runs.
+        ``hp='auto'`` requests are resolved *before*
         grouping — autotuned hyperparameters are ordinary call-time
         arguments by the time the bucketing and the compiled-executable
         cache see them.  Admission validation (non-finite weights, absurd
@@ -556,15 +581,26 @@ class AnnealService:
         responses: List[Optional[AnnealResponse]] = [None] * len(requests)
         reports: dict = {}
         groups = collections.defaultdict(list)
+        # Each distinct problem object is prepared once per call; the entry
+        # holds the object, so its id is not reused while the call runs.
+        prepared: dict = {}   # id(problem) -> (problem, maxcut, model)
+        weight_bits = _ByIdentity(model_weight_bits)
         for idx, req in enumerate(requests):
+            seen = prepared.get(id(req.problem))
             with spans("normalize"):
-                try:
-                    maxcut, model = normalize_problem(req.problem)
-                except TypeError as e:
-                    raise AdmissionError(f"request {idx}: {e}") from e
+                if seen is not None:
+                    _, maxcut, model = seen
+                else:
+                    try:
+                        maxcut, model = normalize_problem(req.problem)
+                    except TypeError as e:
+                        raise AdmissionError(f"request {idx}: {e}") from e
             if self.policy.validate_admission:
                 with spans("admit"):
-                    self._admit(idx, req, model)
+                    self._admit(idx, req, model, check_model=seen is None)
+            if seen is None:
+                prepared[id(req.problem)] = (req.problem, maxcut, model)
+                self.stats["prep_instances"] += 1
             if isinstance(req.hp, str):
                 with spans("autotune"):
                     hp, reports[idx] = resolve_hyperparams(
@@ -584,7 +620,7 @@ class AnnealService:
         for key, items in sorted(groups.items(), key=lambda kv: repr(kv[0])):
             kind, nb = key[0], key[1]
             self._solve_group_resilient(kind, nb, items, responses, progress,
-                                        t_solve0)
+                                        t_solve0, weight_bits=weight_bits)
         with spans("decode"):
             for idx, resp in enumerate(responses):
                 resp.autotune = reports.get(idx)
@@ -616,12 +652,16 @@ class AnnealService:
     # ------------------------------------------------------------------
     # Admission
     # ------------------------------------------------------------------
-    def _admit(self, idx: int, req: AnnealRequest, model: IsingModel):
-        try:
-            validate_model(model)
-        except ValueError as e:
-            self.stats["admission_rejects"] += 1
-            raise AdmissionError(f"request {idx}: {e}") from e
+    def _admit(self, idx: int, req: AnnealRequest, model: IsingModel, *,
+               check_model: bool = True):
+        """Admission checks of one request; ``check_model=False`` skips the
+        model's own validation (done for an earlier request of the call)."""
+        if check_model:
+            try:
+                validate_model(model)
+            except ValueError as e:
+                self.stats["admission_rejects"] += 1
+                raise AdmissionError(f"request {idx}: {e}") from e
         if req.deadline_s is not None and not float(req.deadline_s) > 0:
             self.stats["admission_rejects"] += 1
             raise AdmissionError(
@@ -675,7 +715,8 @@ class AnnealService:
         cfg_sig = req.config.signature() if req.config is not None else None
         return fam.group_key(req, req.hp, nb) + (cfg_sig,)
 
-    def _resolve_field_opts(self, backend: str, opts: dict, items) -> dict:
+    def _resolve_field_opts(self, backend: str, opts: dict, items,
+                            weight_bits=model_weight_bits) -> dict:
         """Resolve field_mode='auto' + group ``j_bits`` for one request group.
 
         The popcount contraction's magnitude-plane count is program-
@@ -683,12 +724,13 @@ class AnnealService:
         uniform across the group: every model packs to the group maximum.
         The resolved values land in the opts dict — and therefore in the
         executable-cache key via ``_opts_key`` — so a ±1 group and a 3-bit
-        group never collide on one compiled program.
+        group never collide on one compiled program.  ``weight_bits`` is
+        the call's per-model scan (memoized by :meth:`solve`).
         """
         if backend not in ("dense", "pallas") or "field_mode" not in opts:
             return dict(opts)
         opts = dict(opts)
-        jb = max(model_weight_bits(model) for _, _, _, model in items)
+        jb = max(weight_bits(model) for _, _, _, model in items)
         opts["field_mode"] = resolve_field_mode(opts["field_mode"], jb)
         if opts["field_mode"] == "popcount":
             opts["j_bits"] = max(jb, int(opts.get("j_bits", 1)))
@@ -710,7 +752,8 @@ class AnnealService:
     # Resilient group dispatch: fallback chain + quarantine + retry
     # ------------------------------------------------------------------
     def _solve_group_resilient(self, kind, nb, items, responses, progress,
-                               solve_t0, *, requeue_quarantine: bool = True):
+                               solve_t0, *, weight_bits,
+                               requeue_quarantine: bool = True):
         """Run one group with the resilience wrapper (DESIGN.md §10).
 
         A classified compile/OOM fault walks the fallback chain and re-runs
@@ -718,7 +761,8 @@ class AnnealService:
         preserved — the trajectory depends only on the noise stream, not the
         backend).  A quarantine signal splits the group: healthy requests
         re-run as a fresh group, offenders retry solo with backoff.  Kills
-        and unclassified errors propagate.
+        and unclassified errors propagate.  ``weight_bits`` is the call's
+        memoized weight-bit scan, shared by routing and the field options.
         """
         solver = getattr(self, registered_algos()[kind].solver)
         cfg = items[0][1].config
@@ -735,7 +779,8 @@ class AnnealService:
         carried_events: List[ServiceEvent] = []
         if backend == "auto":
             with self.spans("weight_bits"):  # routing scans the weight bits
-                backend, opts, why = self.route_auto(kind, nb, items, opts)
+                backend, opts, why = self.route_auto(kind, nb, items, opts,
+                                                     weight_bits)
             if why is not None:
                 carried_events.append(ServiceEvent(
                     "route", {"backend": backend, "reason": why},
@@ -743,7 +788,8 @@ class AnnealService:
                 ))
         while True:
             ctx = _GroupCtx(self, kind, nb, items, backend, opts, solve_t0,
-                            self._chunk_of(kind, items), events=carried_events)
+                            self._chunk_of(kind, items), weight_bits,
+                            events=carried_events)
             try:
                 # One span per attempt; a quarantine's re-runs and solo
                 # retries open their own after this one has closed.
@@ -788,19 +834,19 @@ class AnnealService:
             ctx.finish_success()
             return
 
-    def route_auto(self, kind, nb, items, opts):
+    def route_auto(self, kind, nb, items, opts, weight_bits=model_weight_bits):
         """Resolve backend='auto' for one group: ``(backend, opts, why)``.
 
         Resident pallas at or above ``engine.MIN_RESIDENT_N`` spins where its
         kernel fits the chip's VMEM budget, XLA dense otherwise; ``why`` is
         the budget shortfall when that is what sent the group to dense.  The
         opts are then filtered to what the chosen backend accepts — 'auto'
-        users pass a union.
+        users pass a union.  ``weight_bits`` is the call's per-model scan.
         """
         hp = items[0][1].hp
         kernel_opts = dict(
             opts, noise=self.noise, n_cycles=getattr(hp, "tau", 1),
-            j_bits=max(model_weight_bits(model) for *_, model in items),
+            j_bits=max(weight_bits(model) for *_, model in items),
         )
         if kind == "ssqa":
             kernel_opts["n_replicas"] = hp.n_replicas
@@ -832,11 +878,13 @@ class AnnealService:
         bad_items = [it for s, it in enumerate(items) if s in bad]
         if good:
             self._solve_group_resilient(kind, nb, good, responses, progress,
-                                        solve_t0)
+                                        solve_t0, weight_bits=ctx.weight_bits)
         for it in bad_items:
-            self._retry_solo(kind, nb, it, responses, progress, solve_t0)
+            self._retry_solo(kind, nb, it, responses, progress, solve_t0,
+                             ctx.weight_bits)
 
-    def _retry_solo(self, kind, nb, item, responses, progress, solve_t0):
+    def _retry_solo(self, kind, nb, item, responses, progress, solve_t0,
+                    weight_bits):
         """Quarantined request: exponential backoff + re-autotuned I0max.
 
         Each attempt re-derives the I0 clamp from the instance's local-field
@@ -871,6 +919,7 @@ class AnnealService:
                 self._solve_group_resilient(
                     kind, nb, [(idx, req_retry, maxcut, model)], responses,
                     progress, solve_t0, requeue_quarantine=False,
+                    weight_bits=weight_bits,
                 )
             except QuarantineFault:
                 self.stats["retry_requarantined"] += 1
@@ -951,7 +1000,8 @@ class AnnealService:
         padded, b_live, b_bucket = self._pad_group(items)
         backend, opts = ctx.backend, ctx.backend_opts
         with spans("weight_bits"):
-            opts = self._resolve_field_opts(backend, opts, items)
+            opts = self._resolve_field_opts(backend, opts, items,
+                                            ctx.weight_bits)
         nr = int(getattr(hp, "n_replicas", 0) or 0)
         if nr:
             # SSQA: the Trotter depth is program-structural (ring width per
@@ -1133,7 +1183,8 @@ class AnnealService:
 
         padded, b_live, b_bucket = self._pad_group(items)
         with self.spans("weight_bits"):
-            opts = self._resolve_field_opts(backend, opts, items)
+            opts = self._resolve_field_opts(backend, opts, items,
+                                            ctx.weight_bits)
         with self.spans("program"):
             bk, init_fn, chunk_fn = self._ptssa_programs(
                 nb, b_bucket, hp, backend, opts, chunk, ctx)
