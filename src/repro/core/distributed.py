@@ -412,6 +412,9 @@ class BatchedSpinShardedBackend(BatchedBackend):
         self._pc_tile = (
             None if self.n_shard <= TILED_J_THRESHOLD else self.tile_n
         )
+        self.row_tiled = (self.field_style == "tiled"
+                          or (self.field_style == "popcount"
+                              and self._pc_tile is not None))
         # Packed-layout spin words shard over devices only when each shard
         # is word-aligned; otherwise the (tiny) planes stay replicated and
         # each device slices its columns after the local unpack.

@@ -1485,6 +1485,9 @@ class BatchedBackend:
     """
 
     name = "abstract"
+    # Whether the field streams row slabs of the couplings through a scan
+    # (row-tiled popcount or j_mode='tiled') rather than one block.
+    row_tiled = False
 
     def __init__(
         self,
@@ -1805,6 +1808,9 @@ class BatchedDenseBackend(_VmapBatchedBackend):
         self._pc_tile = (
             None if self.n_bucket <= TILED_J_THRESHOLD else self.tile_n
         )
+        self.row_tiled = (self._pc_tile is not None
+                          if self.field_mode == "popcount"
+                          else self.j_mode == "tiled")
 
     def stack(self, models):
         if self.field_mode == "popcount":

@@ -375,6 +375,7 @@ class _GroupCtx:
         self.noise = service.noise
         self.events: List[ServiceEvent] = list(events or [])
         self.statuses: dict = {}
+        self.row_tiled = False   # set by AnnealService._stack
         self.ckpt: Optional[CheckpointManager] = None
         self._dir: Optional[str] = None
         if self.policy.checkpoint_dir:
@@ -748,6 +749,22 @@ class AnnealService:
         padded = list(items) + [items[0]] * (b_bucket - b_live)
         return padded, b_live, b_bucket
 
+    def _stack(self, bk, padded, ctx):
+        """``bk.stack`` of a padded group's models, in the ``stack`` span.
+
+        Adds the stacked arrays' bytes to ``stats["stack_bytes"]`` (shapes
+        only, no device sync) and notes on ``ctx`` whether the backend's
+        field streams row slabs, which the group counts in ``tiled_lanes``
+        once it succeeds.
+        """
+        with self.spans("stack"):
+            stacked = bk.stack([model for _, _, _, model in padded])
+        nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(stacked))
+        with self.spans.lock:
+            self.stats["stack_bytes"] += nbytes
+        ctx.row_tiled = bk.row_tiled
+        return stacked
+
     # ------------------------------------------------------------------
     # Resilient group dispatch: fallback chain + quarantine + retry
     # ------------------------------------------------------------------
@@ -831,6 +848,11 @@ class AnnealService:
                 resp.status = ctx.statuses.get(idx, default)
                 resp.events = list(ctx.events)
                 resp.backend = None if kind == "sa" else ctx.backend
+            if kind != "sa":
+                with self.spans.lock:  # concurrent solve() calls
+                    self.stats[f"route_lanes.{ctx.backend}"] += len(items)
+                    if ctx.row_tiled:
+                        self.stats["tiled_lanes"] += len(items)
             ctx.finish_success()
             return
 
@@ -1020,8 +1042,7 @@ class AnnealService:
             )
         stored_per_iter = sum(p.length for p in plateaus if p.eligible)
 
-        with spans("stack"):
-            stacked = bk.stack([model for _, _, _, model in padded])
+        stacked = self._stack(bk, padded, ctx)
         ctx.fire("oom", backend=backend, kind="ssa", bucket=nb, batch=b_bucket,
                  j_mode=getattr(bk, "j_mode", None))
         with spans("init"):
@@ -1096,11 +1117,10 @@ class AnnealService:
                                                   chunk_cycles, ctx)
 
         # SA reuses the sparse stacking (gather-based ΔH).
-        with self.spans("stack"):
-            stacker = make_batched_backend(
-                "sparse", n_bucket=nb, n_trials=hp.n_trials, noise="xorshift"
-            )
-            stacked = stacker.stack([model for _, _, _, model in padded])
+        stacker = make_batched_backend(
+            "sparse", n_bucket=nb, n_trials=hp.n_trials, noise="xorshift"
+        )
+        stacked = self._stack(stacker, padded, ctx)
         with self.spans("init"):
             keys = jnp.stack(
                 [jax.random.PRNGKey(req.seed) for _, req, _, _ in padded]
@@ -1189,8 +1209,7 @@ class AnnealService:
             bk, init_fn, chunk_fn = self._ptssa_programs(
                 nb, b_bucket, hp, backend, opts, chunk, ctx)
 
-        with self.spans("stack"):
-            stacked = bk.stack([model for _, _, _, model in padded])
+        stacked = self._stack(bk, padded, ctx)
         ctx.fire("oom", backend=backend, kind="ptssa", bucket=nb,
                  batch=b_bucket, j_mode=getattr(bk, "j_mode", None))
         with self.spans("init"):
