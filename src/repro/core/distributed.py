@@ -643,7 +643,7 @@ class BatchedSpinShardedBackend(BatchedBackend):
             check_vma=False,
         )(problem, state)
 
-    def run_shots(self, problem, state, plateaus, n_shots):
+    def run_shots(self, problem, state, plateaus, n_shots, live=None):
         return self._sharded_chain(tuple(plateaus), int(n_shots))(
             problem, state
         )

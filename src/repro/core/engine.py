@@ -1488,6 +1488,11 @@ class BatchedBackend:
     # Whether the field streams row slabs of the couplings through a scan
     # (row-tiled popcount or j_mode='tiled') rather than one block.
     row_tiled = False
+    # Whether run_shots' ``live`` mask saves work: a lane marked dead then
+    # costs no compute and keeps its spins, Itanh and bests.  Under vmap a
+    # per-lane branch becomes a select that computes both sides, so only
+    # the resident kernels skip.
+    skips_dead_lanes = False
 
     def __init__(
         self,
@@ -1567,26 +1572,30 @@ class BatchedBackend:
             problem, state, i0, length=length, eligible=eligible, jperp=jperp
         )
 
-    def run_shots(self, problem: dict, state, plateaus, n_shots: int):
+    def run_shots(self, problem: dict, state, plateaus, n_shots: int,
+                  live=None):
         """Advance ``n_shots`` full iterations (plateau chains) — one chunk.
 
         The chunk launch boundary is where the storage layout is *real*:
         under 'packed' the state entering/leaving this method — the HBM-
         resident buffers between service chunks — carries spins as uint32
-        bitplanes.
+        bitplanes.  ``live`` is a (B,) int32 mask of the lanes whose answer
+        is still wanted; where :attr:`skips_dead_lanes` holds, a 0 lane
+        costs no compute and keeps its spins, Itanh and bests, else the
+        mask is ignored.
         """
         if self.storage_layout == "packed":
             st = unpack_state(state, self.n_bucket)
-            st = self._run_shots_dense(problem, st, plateaus, n_shots)
+            st = self._run_shots_dense(problem, st, plateaus, n_shots, live)
             return pack_state(st)
-        return self._run_shots_dense(problem, state, plateaus, n_shots)
+        return self._run_shots_dense(problem, state, plateaus, n_shots, live)
 
     def _run_plateau_dense(self, problem: dict, state: EngineState, i0, *,
                            length, eligible, jperp=0):
         raise NotImplementedError
 
     def _run_shots_dense(self, problem: dict, state: EngineState, plateaus,
-                         n_shots: int):
+                         n_shots: int, live=None):
         raise NotImplementedError
 
     def finalize(self, state) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -1618,7 +1627,7 @@ class _VmapBatchedBackend(BatchedBackend):
             problem, state
         )
 
-    def _run_shots_dense(self, problem, state, plateaus, n_shots):
+    def _run_shots_dense(self, problem, state, plateaus, n_shots, live=None):
         plateaus = tuple(plateaus)
 
         def one(prob, st):
@@ -1852,9 +1861,13 @@ class BatchedPallasBackend(BatchedBackend):
     VMEM-resident as stacked `PackedJ` bitplanes (``j_bits`` planes, the
     group maximum) and :meth:`run_shots` launches each full iteration's
     plateau chain as ONE `pallas_call` — multi-plateau residency.
+
+    All three kernels take :meth:`run_shots`' ``live`` mask into SMEM: the
+    grid steps of a dead lane copy its state through and compute nothing.
     """
 
     name = "pallas"
+    skips_dead_lanes = True
 
     def __init__(self, *, j_dtype=jnp.float32, block_r: int = 8,
                  interpret: Optional[bool] = None, noise_mode: str = "auto",
@@ -1901,7 +1914,7 @@ class BatchedPallasBackend(BatchedBackend):
         return jax.lax.scan(draw, ns, None, length=length)
 
     def _plateau_packed(self, problem, st: PackedEngineState, i0, length,
-                        eligible, jperp=0) -> PackedEngineState:
+                        eligible, jperp=0, live=None) -> PackedEngineState:
         jperp = int(jperp)
         mp_o, it_o, rng_o, bh_o, bmp_o = self._kssa.ssa_plateau_packed_batched(
             st.m_packed,
@@ -1919,11 +1932,13 @@ class BatchedPallasBackend(BatchedBackend):
             interpret=self.interpret,
             jperp=jperp,
             n_replicas=self.n_replicas if jperp else 0,
+            live=live,
         )
         return PackedEngineState(rng_o, mp_o, it_o, bh_o, bmp_o)
 
     def _chain_popcount(self, problem, st: PackedEngineState, i0_sched,
-                        fold_sched, jperp_sched=None) -> PackedEngineState:
+                        fold_sched, jperp_sched=None,
+                        live=None) -> PackedEngineState:
         mp_o, it_o, rng_o, bh_o, bmp_o = self._kssa.ssa_plateau_popcount_batched(
             st.m_packed,
             st.itanh,
@@ -1944,6 +1959,7 @@ class BatchedPallasBackend(BatchedBackend):
                 else jnp.asarray(jperp_sched, jnp.int32)
             ),
             n_replicas=self.n_replicas,
+            live=live,
         )
         return PackedEngineState(rng_o, mp_o, it_o, bh_o, bmp_o)
 
@@ -1972,10 +1988,10 @@ class BatchedPallasBackend(BatchedBackend):
             st = self._plateau_packed(problem, st, i0, length, eligible, jperp)
         return st if packed_in else unpack_state(st, self.n_bucket)
 
-    def run_shots(self, problem, state, plateaus, n_shots):
+    def run_shots(self, problem, state, plateaus, n_shots, live=None):
         plateaus = tuple(plateaus)
         if self.noise_mode != "streamed":
-            return super().run_shots(problem, state, plateaus, n_shots)
+            return super().run_shots(problem, state, plateaus, n_shots, live)
         packed_in = self.storage_layout == "packed"
         st = state if packed_in else pack_state(state)
 
@@ -1988,14 +2004,14 @@ class BatchedPallasBackend(BatchedBackend):
 
             def iteration(st, _):
                 return self._chain_popcount(
-                    problem, st, i0_sched, fold_sched, jperp_sched
+                    problem, st, i0_sched, fold_sched, jperp_sched, live
                 ), None
         else:
 
             def iteration(st, _):
                 for p in plateaus:
                     st = self._plateau_packed(
-                        problem, st, p.i0, p.length, p.eligible, p.jperp
+                        problem, st, p.i0, p.length, p.eligible, p.jperp, live
                     )
                 return st, None
 
@@ -2003,7 +2019,7 @@ class BatchedPallasBackend(BatchedBackend):
         return st if packed_in else unpack_state(st, self.n_bucket)
 
     def _run_plateau_dense(self, problem, state, i0, *, length, eligible,
-                           jperp=0):
+                           jperp=0, live=None):
         if jperp:
             raise ValueError(
                 "SSQA requires noise_mode='streamed' on the batched pallas "
@@ -2024,15 +2040,16 @@ class BatchedPallasBackend(BatchedBackend):
             eligible=bool(eligible),
             block_r=self.block_r,
             interpret=self.interpret,
+            live=live,
         )
         return EngineState(ns, m_o.astype(jnp.int8), it_o, bh_o, bm_o)
 
-    def _run_shots_dense(self, problem, state, plateaus, n_shots):
+    def _run_shots_dense(self, problem, state, plateaus, n_shots, live=None):
         def iteration(st, _):
             for p in plateaus:
                 st = self._run_plateau_dense(
                     problem, st, p.i0, length=p.length, eligible=p.eligible,
-                    jperp=p.jperp,
+                    jperp=p.jperp, live=live,
                 )
             return st, None
 

@@ -91,6 +91,42 @@ def default_interpret() -> bool:
 # index them with the traced cycle counter, which VMEM loads cannot take.
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
+
+def _skip_dead_lanes(kernel, n_in: int, state_in: Tuple[int, ...]):
+    """``kernel`` behind a per-lane live mask, a leading (1, B) SMEM ref.
+
+    Grid step (b, i) of a live lane (``live[b] != 0``) runs ``kernel`` as
+    it is.  A dead lane's step copies its state inputs (``state_in``, ref
+    indices among the ``n_in`` inputs) to the outputs, which follow the
+    inputs in the same order, and computes nothing: its output blocks are
+    written back either way, so they must hold the state and not whatever
+    VMEM held.
+    """
+
+    def gated(live_ref, *refs):
+        alive = live_ref[0, pl.program_id(0)] != 0
+
+        @pl.when(alive)
+        def _run():
+            kernel(*refs)
+
+        @pl.when(jnp.logical_not(alive))
+        def _copy():
+            for k, i in enumerate(state_in):
+                refs[n_in + k][...] = refs[i][...]
+
+    return gated
+
+
+def _live_operand(live, B: int):
+    """``(specs, args)`` that prepend the live mask to a batched kernel's
+    operands; none when ``live`` is None (every lane live, the mask-free
+    kernel)."""
+    if live is None:
+        return [], []
+    return [_SMEM], [jnp.asarray(live, jnp.int32).reshape(1, B)]
+
+
 LANE = 128
 # VMEM per TensorCore of TPU v5e, the chip the kernels are sized for when no
 # TPU backs JAX (`pltpu.get_tpu_info` gives the real figure on the chip), so
@@ -381,6 +417,7 @@ def ssa_plateau_batched(
     eligible: bool = True,
     block_r: int = 8,
     interpret: Optional[bool] = None,
+    live: Optional[jnp.ndarray] = None,  # (B,) int32; 0 = skip the lane
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Run one constant-I0 plateau for B stacked problems fully on-chip.
 
@@ -389,6 +426,10 @@ def ssa_plateau_batched(
     whole shape bucket of heterogeneous instances (the serving layer's
     batched hot path).  Per-problem semantics are identical to the B=1
     kernel; :func:`ssa_plateau` is exactly this with B=1.
+
+    ``live`` masks lanes: a lane whose entry is 0 computes nothing and
+    returns its state as it came in (:func:`_skip_dead_lanes`).  None runs
+    every lane with the mask-free kernel.
     """
     interpret = default_interpret() if interpret is None else interpret
     B, R, N = m.shape
@@ -407,10 +448,15 @@ def ssa_plateau_batched(
     kernel = functools.partial(
         _plateau_kernel, n_cycles=C, n_rnd=n_rnd, eligible=eligible
     )
+    live_specs, live_args = _live_operand(live, B)
+    if live_args:
+        # State in: m, itanh, best_H, best_m; out in the same order.
+        kernel = _skip_dead_lanes(kernel, 8, (1, 2, 6, 7))
     m_o, it_o, bh_o, bm_o = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
+            *live_specs,
             _SMEM,
             pl.BlockSpec((1, block_r, Np), lambda b, i: (b, i, 0)),
             pl.BlockSpec((1, block_r, Np), lambda b, i: (b, i, 0)),
@@ -445,7 +491,7 @@ def ssa_plateau_batched(
             2,
         ),
         interpret=interpret,
-    )(i0a, mf, itp, Jp.astype(J.dtype), hp, np_, bhp, bmp)
+    )(*live_args, i0a, mf, itp, Jp.astype(J.dtype), hp, np_, bhp, bmp)
     return (
         m_o[:, :R, :N],
         it_o[:, :R, :N],
@@ -647,6 +693,7 @@ def ssa_plateau_packed_batched(
     interpret: Optional[bool] = None,
     jperp=0,                 # scalar int32 replica coupling (SSQA)
     n_replicas: int = 0,     # 0 = classical; >0 = SSQA Trotter-ring mode
+    live: Optional[jnp.ndarray] = None,  # (B,) int32; 0 = skip the lane
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Streamed-noise resident plateau for B stacked problems, packed refs.
 
@@ -655,6 +702,8 @@ def ssa_plateau_packed_batched(
     (B, C, R, N) buffer is ever materialized: noise bits are generated in
     VMEM from the carried lanes, and the HBM-facing spin state crosses the
     launch boundary as uint32 bitplanes (32× smaller than float32 spins).
+    ``live`` masks lanes as in :func:`ssa_plateau_batched`; a dead lane's
+    xorshift lanes do not advance either.
 
     Returns (m_packed, itanh, rng, best_H, best_m_packed) after the plateau.
     """
@@ -694,10 +743,17 @@ def ssa_plateau_packed_batched(
     if n_replicas:
         jperp_specs = [_SMEM]
         jperp_args = [jnp.asarray(jperp, jnp.int32).reshape(1, 1)]
+    live_specs, live_args = _live_operand(live, B)
+    if live_args:
+        # State in: mp, itanh, rng, best_H, best_m; out in the same order.
+        o = len(jperp_args)
+        kernel = _skip_dead_lanes(kernel, 8 + o,
+                                  (1 + o, 2 + o, 5 + o, 6 + o, 7 + o))
     mp_o, it_o, rng_o, bh_o, bmp_o = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
+            *live_specs,
             _SMEM,
             *jperp_specs,
             pl.BlockSpec((1, block_r, Nwp), lambda b, i: (b, i, 0)),
@@ -736,7 +792,8 @@ def ssa_plateau_packed_batched(
             2,
         ),
         interpret=interpret,
-    )(i0a, *jperp_args, mp, itp, Jp.astype(J.dtype), hp, rngp, bhp, bmp)
+    )(*live_args, i0a, *jperp_args, mp, itp, Jp.astype(J.dtype), hp, rngp,
+      bhp, bmp)
     nw = (N + 31) // 32
     return (
         mp_o[:, :R, :nw],
@@ -1038,6 +1095,7 @@ def ssa_plateau_popcount_batched(
     interpret: Optional[bool] = None,
     jperp_sched: Optional[jnp.ndarray] = None,  # (C,) int32 per-cycle J⊥
     n_replicas: int = 0,     # 0 = classical; >0 = SSQA Trotter-ring mode
+    live: Optional[jnp.ndarray] = None,  # (B,) int32; 0 = skip the lane
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Bit-parallel resident chain for B stacked problems (multi-plateau).
 
@@ -1048,6 +1106,10 @@ def ssa_plateau_popcount_batched(
     operands come from :func:`repro.core.engine.plateau_cycle_schedules`.
     Bit-identical to running the same chain plateau-by-plateau through any
     other backend (property-tested in tests/test_popcount.py).
+
+    ``live`` masks lanes as in :func:`ssa_plateau_batched`: a dead lane's
+    grid steps copy its state through and run no cycle.  None (every lane
+    live) compiles the mask-free kernel.
 
     Returns (m_packed, itanh, rng, best_H, best_m_packed) after the chain.
     """
@@ -1102,10 +1164,17 @@ def ssa_plateau_popcount_batched(
         jperp_specs = [_SMEM]
         jperp_args = [jnp.asarray(jperp_sched, jnp.int32).reshape(1, C)]
         ring_scratch = [pltpu.VMEM((2, block_r, Np), jnp.int32)]
+    live_specs, live_args = _live_operand(live, B)
+    if live_args:
+        # State in: mp, itanh, rng, best_H, best_m; out in the same order.
+        o = len(jperp_args)
+        kernel = _skip_dead_lanes(kernel, 11 + o,
+                                  (2 + o, 3 + o, 8 + o, 9 + o, 10 + o))
     mp_o, it_o, rng_o, bh_o, bmp_o = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
+            *live_specs,
             _SMEM,
             *jperp_specs,
             _SMEM,
@@ -1150,7 +1219,8 @@ def ssa_plateau_popcount_batched(
             2,
         ),
         interpret=interpret,
-    )(i0a, *jperp_args, folda, mp, itp, signp, magsp, basep, hp, rngp, bhp, bmp)
+    )(*live_args, i0a, *jperp_args, folda, mp, itp, signp, magsp, basep, hp,
+      rngp, bhp, bmp)
     nw = (N + 31) // 32
     return (
         mp_o[:, :R, :nw],

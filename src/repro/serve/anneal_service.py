@@ -1000,8 +1000,14 @@ class AnnealService:
                 self.stats["traces_init"] += 1
                 return bk.init_state(problem, ns0)
 
-            def chunk_fn(problem, state):
+            def chunk_fn(problem, state, live):
+                # ``live``: (b_bucket,) int32, a device operand and no part
+                # of the key.  The vmapped backends compute every lane
+                # whatever it says, so they are not handed it.
                 self.stats["traces_chunk"] += 1
+                if bk.skips_dead_lanes:
+                    return bk.run_shots(problem, state, plateaus, chunk,
+                                        live=live)
                 return bk.run_shots(problem, state, plateaus, chunk)
 
             ent = (bk, _Program(init_fn, cache_key + ("init",), bk, self.spans),
@@ -1054,9 +1060,9 @@ class AnnealService:
 
         state, chunk_traces, stops = self._chunk_loop(
             ctx.kind, nb, items, n_chunks, progress,
-            lambda st, c: chunk_fn(stacked, st), state,
+            lambda st, c, live: chunk_fn(stacked, st, live), state,
             lambda st: st.best_H, ctx, width=b_bucket,
-            snap=lambda st: bk.finalize(st),
+            snap=lambda st: bk.finalize(st), masks=bk.skips_dead_lanes,
         )
         with spans("finalize"):
             bh_dev, bm_dev = bk.finalize(state)  # unpacks bitplanes too
@@ -1139,7 +1145,8 @@ class AnnealService:
 
         carry, chunk_traces, stops = self._chunk_loop(
             "sa", nb, items, n_chunks, progress,
-            lambda ca, c: chunk_fn(stacked, ca, chunk_arrays[c], n_lives),
+            lambda ca, c, live: chunk_fn(stacked, ca, chunk_arrays[c],
+                                         n_lives),
             carry, lambda ca: ca[3], ctx, width=b_bucket,
             snap=lambda ca: (ca[3], ca[4]),
         )
@@ -1229,7 +1236,7 @@ class AnnealService:
             ])  # (B, n_rounds, 2)
             parities = jnp.arange(hp.n_rounds, dtype=jnp.int32) % 2
 
-        def step(st, c):
+        def step(st, c, live):
             sl = slice(c * chunk, (c + 1) * chunk)
             return chunk_fn(stacked, st, all_keys[:, sl], parities[sl])
 
@@ -1286,8 +1293,8 @@ class AnnealService:
     # deadline watchdog, non-finite detector, fault hooks
     # ------------------------------------------------------------------
     def _chunk_loop(self, kind, nb, items, n_chunks, progress, step, state,
-                    best_of, ctx, *, width=None, snap=None):
-        """Run up to n_chunks ``step(state, c)`` calls from the last
+                    best_of, ctx, *, width=None, snap=None, masks=False):
+        """Run up to n_chunks ``step(state, c, live)`` calls from the last
         checkpoint; report per-chunk bests; stop early when every request is
         done (target_cut reached or deadline expired).
 
@@ -1303,6 +1310,13 @@ class AnnealService:
         or None for a lane that ran to the group's end (its result comes
         from the final state).  ``width`` is the padded batch width, feeding
         the slot/live-lane occupancy counters the streaming benchmark reads.
+
+        ``live`` is a (width,) int32 mask built at every launch: 1 for a
+        lane not yet done, 0 for a stopped lane (from the chunk after its
+        stop) and for ``_pad_group``'s padding lanes (from chunk 0).  A
+        resume starts with every request lane live.  ``masks`` says the
+        step skips the dead lanes, which ``stats["masked_lane_chunks"]``
+        then counts.
         """
         spans = self.spans
         traces = [[] for _ in items]
@@ -1317,12 +1331,14 @@ class AnnealService:
         for c in range(start, n_chunks):
             with spans("chunk", chunk=c):
                 with spans("chunk.launch"):
-                    self.stats["slot_chunks"] += (width if width is not None
-                                                  else len(items))
-                    self.stats["live_lane_chunks"] += sum(
-                        1 for s in range(len(items)) if not done[s]
-                    )
-                    state = step(state, c)
+                    live = np.zeros(width or len(items), np.int32)
+                    live[:len(items)] = [not d for d in done]
+                    n_live = int(live.sum())
+                    self.stats["slot_chunks"] += live.size
+                    self.stats["live_lane_chunks"] += n_live
+                    if masks:
+                        self.stats["masked_lane_chunks"] += live.size - n_live
+                    state = step(state, c, live)
                 with spans("chunk.sync"):
                     best_H = np.asarray(best_of(state))  # the report
                 with spans("chunk.book"):

@@ -686,7 +686,11 @@ class StreamingAnnealService:
         svc = self.service
         try:
             with svc.spans("quantum.launch"):
-                new_state = table.chunk_fn(table.stacked, table.state)
+                # Empty and retired slots are dead lanes: their state is
+                # replaced whole when a request is seated there.
+                live = np.asarray([s is not None for s in table.slots],
+                                  np.int32)
+                new_state = table.chunk_fn(table.stacked, table.state, live)
             with svc.spans("quantum.sync"):
                 best_H = np.asarray(new_state.best_H)
         except Exception as exc:  # noqa: BLE001 — classified below
