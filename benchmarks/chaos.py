@@ -42,7 +42,7 @@ from repro.serve import AnnealRequest, AnnealService, ResiliencePolicy
 
 from .common import emit
 
-BACKENDS = ("sparse", "dense", "pallas")
+BACKEND_NAMES = ("sparse", "dense", "pallas")
 
 
 def _problems(smoke):
@@ -73,11 +73,11 @@ def run(smoke: bool = False, json_path: str = "BENCH_chaos.json",
     report = {"smoke": smoke, "scenarios": {}}
     baseline = {
         b: AnnealService(backend=b, min_bucket=16).solve(_requests(problems, hp))
-        for b in BACKENDS
+        for b in BACKEND_NAMES
     }
 
     # -- kill at a chunk boundary, resume from checkpoints ---------------
-    for backend in BACKENDS:
+    for backend in BACKEND_NAMES:
         t0 = time.perf_counter()
         with tempfile.TemporaryDirectory() as d:
             pol = ResiliencePolicy(checkpoint_dir=d)

@@ -86,14 +86,17 @@ def run(csv_prefix: str = "table4_memory"):
 
     # Live engine state, dense vs packed bitplane layout (DESIGN.md §4):
     # what actually sits in HBM between plateau launches.
-    from repro.core.engine import make_backend
+    from repro.core.engine import single_problem_backend
 
     def state_bytes(layout):
-        bk = make_backend(
-            "sparse", g.to_ising(), n_trials=hp_small.n_trials,
+        model = g.to_ising()
+        bk, problem = single_problem_backend(
+            model, "sparse", n_trials=hp_small.n_trials,
             noise="xorshift", storage_layout=layout,
         )
-        return memory.tree_device_bytes(bk.init_state(0))
+        return memory.tree_device_bytes(
+            bk.init_state(problem, bk.init_noise([0], [model.n]))
+        )
 
     dense_state = state_bytes("dense")
     packed_state = state_bytes("packed")
@@ -108,18 +111,21 @@ def run(csv_prefix: str = "table4_memory"):
     # sign/magnitude bitplanes (kernels.bitplane.PackedJ); report the
     # analytic codec size next to the bytes the two dense-backend
     # configurations actually pin on device.
-    from repro.core.engine import make_backend as _mk
     from repro.kernels.bitplane import adjacency_weight_bits, packed_j_nbytes
 
     model = g.to_ising()
     jb = adjacency_weight_bits(model.n, model.nbr_idx, model.nbr_w)
-    bk_dense = _mk("dense", model, n_trials=hp_small.n_trials,
-                   noise="xorshift", field_mode="dense", j_mode="dense")
-    bk_pc = _mk("dense", model, n_trials=hp_small.n_trials,
-                noise="xorshift", field_mode="popcount")
-    dense_j = memory.tree_device_bytes(bk_dense.J)
+    _, prob_dense = single_problem_backend(
+        model, "dense", n_trials=hp_small.n_trials, noise="xorshift",
+        field_mode="dense", j_mode="dense",
+    )
+    _, prob_pc = single_problem_backend(
+        model, "dense", n_trials=hp_small.n_trials, noise="xorshift",
+        field_mode="popcount",
+    )
+    dense_j = memory.tree_device_bytes(prob_dense["J"])
     packed_j = memory.tree_device_bytes(
-        (bk_pc.packed_j.sign, bk_pc.packed_j.mags, bk_pc.packed_j.base)
+        (prob_pc["sign"], prob_pc["mags"], prob_pc["base"])
     )
     emit(f"{csv_prefix}/j_bits", 0.0, f"{jb}")
     emit(f"{csv_prefix}/analytic_packed_j_bytes", 0.0,

@@ -36,7 +36,7 @@ from repro.serve import AnnealRequest, AnnealService
 
 from .common import emit
 
-BACKENDS = ("sparse", "dense", "pallas")
+BACKEND_NAMES = ("sparse", "dense", "pallas")
 
 # family → (smoke size, full size) in frontend units (see FAMILIES factories).
 SIZES = {
@@ -68,7 +68,7 @@ def run(smoke: bool = False, json_path: str = "BENCH_problems.json",
         enc = make_demo(kind, n=n_smoke if smoke else n_full, seed=0)
         row = {"name": enc.model.name, "n_spins": enc.model.n, "backends": {}}
         objectives = {}
-        for backend in BACKENDS:
+        for backend in BACKEND_NAMES:
             resp, wall = _solve_one(backend, enc, "auto", auto_base=base)
             rhp = resp.request.hp
             row["backends"][backend] = {

@@ -44,7 +44,7 @@ from repro.core import (
     gset,
 )
 from repro.core.autotune import resolve_hyperparams
-from repro.core.engine import make_backend, run_schedule, schedule_plateaus
+from repro.core.engine import schedule_plateaus, single_problem_backend
 from repro.core.ssqa import SSQAHyperParams
 
 from .common import emit, time_call
@@ -103,13 +103,12 @@ def _s_per_cycle(model, hp) -> float:
     nr = int(getattr(hp, "n_replicas", 0) or 0)
     if nr:
         opts["n_replicas"] = nr
-    bk = make_backend("dense", model, n_trials=hp.n_trials, n_rnd=hp.n_rnd,
-                      noise="xorshift", **opts)
-    state = bk.init_state(0)
-    chain = jax.jit(
-        lambda s: run_schedule(bk, plateaus, s, record="best",
-                               track_energy=False)[0]
+    bk, problem = single_problem_backend(
+        model, "dense", n_trials=hp.n_trials, n_rnd=hp.n_rnd,
+        noise="xorshift", **opts,
     )
+    state = bk.init_state(problem, bk.init_noise([0], [model.n]))
+    chain = jax.jit(lambda s: bk.run_shots(problem, s, plateaus, 1))
     # The tt gate divides two of these, so noise matters: median of 7 warm
     # calls (the deterministic cycles-to-target term carries the signal).
     us = time_call(chain, state, warmup=2, iters=7)

@@ -36,7 +36,7 @@ import os
 import jax
 
 from repro.core import gset
-from repro.core.engine import make_backend, run_schedule, schedule_plateaus
+from repro.core.engine import schedule_plateaus, single_problem_backend
 from repro.core.ssa import SSAHyperParams
 from repro.kernels.bitplane import adjacency_weight_bits
 
@@ -115,22 +115,14 @@ def _steady_spin_cycles_per_s(model, hp, field_mode: str) -> tuple:
     """(spin-cycles/s, measured J-residency bytes, wall us) at steady state."""
     plateaus = schedule_plateaus(hp.schedule("hassa"))
     cycles = sum(p.length for p in plateaus)
-    bk = make_backend(
-        "dense", model, n_trials=hp.n_trials, n_rnd=hp.n_rnd,
+    bk, problem = single_problem_backend(
+        model, "dense", n_trials=hp.n_trials, n_rnd=hp.n_rnd,
         noise="xorshift", field_mode=field_mode,
     )
-    if bk.field_mode == "popcount":
-        pj = bk.packed_j
-        j_bytes = int(pj.sign.nbytes + pj.mags.nbytes + pj.base.nbytes)
-    elif bk.j_mode == "dense":
-        j_bytes = int(bk.J.nbytes)
-    else:  # tiled: the adjacency is what stays resident
-        j_bytes = int(bk.nbr_idx.nbytes + bk.nbr_w.nbytes)
-    state = bk.init_state(0)
-    chain = jax.jit(
-        lambda s: run_schedule(bk, plateaus, s, record="best",
-                               track_energy=False)[0]
-    )
+    # What stays resident: bitplanes, dense J, or (tiled) the adjacency.
+    j_bytes = int(sum(a.nbytes for k, a in problem.items() if k != "h"))
+    state = bk.init_state(problem, bk.init_noise([0], [model.n]))
+    chain = jax.jit(lambda s: bk.run_shots(problem, s, plateaus, 1))
     us = time_call(chain, state, warmup=1, iters=3)
     return cycles * hp.n_trials * model.n / (us * 1e-6), j_bytes, us
 

@@ -53,9 +53,8 @@ def _worker(args) -> None:
     from repro.core import SSAHyperParams, anneal, gset, memory
     from repro.core.engine import (
         MAX_UNSHARDED_SPINS,
-        make_backend,
-        run_schedule,
         schedule_plateaus,
+        single_problem_backend,
     )
     from repro.sharding import spin_mesh
 
@@ -96,16 +95,13 @@ def _worker(args) -> None:
                         i0_min=1, i0_max=args.i0_max)
     plateaus = schedule_plateaus(hp.schedule("hassa"))
     cycles = sum(p.length for p in plateaus)
-    bk = make_backend("dense", model, n_trials=hp.n_trials, n_rnd=hp.n_rnd,
-                      noise="xorshift", partition="spin", mesh=mesh)
-    state = bk.init_state(0)
-    out["max_device_bytes"] = memory.max_device_bytes(
-        (bk._problem, state)
+    bk, problem = single_problem_backend(
+        model, "dense", n_trials=hp.n_trials, n_rnd=hp.n_rnd,
+        noise="xorshift", partition="spin", mesh=mesh,
     )
-    chain = jax.jit(
-        lambda s: run_schedule(bk, plateaus, s, record="best",
-                               track_energy=False)[0]
-    )
+    state = bk.init_state(problem, bk.init_noise([0], [model.n]))
+    out["max_device_bytes"] = memory.max_device_bytes((problem, state))
+    chain = jax.jit(lambda s: bk.run_shots(problem, s, plateaus, 1))
     us = time_call(chain, state, warmup=1, iters=3)
     out["n"] = n
     out["wall_us"] = us

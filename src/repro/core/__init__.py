@@ -6,7 +6,8 @@ Public API:
   SSAHyperParams, anneal, solve_maxcut— SSA + HA-SSA (ssa.py)
   SSQAHyperParams, anneal_ssqa        — Trotter-replica SSQA (ssqa.py)
   SolverConfig                        — typed solver options (config.py)
-  PlateauBackend, make_backend        — plateau engine protocol (engine.py)
+  BatchedBackend, make_batched_backend— the plateau engine (engine.py);
+                                        the drivers run it at B=1
   SAHyperParams, anneal_sa            — conventional SA baseline (sa.py)
   PTHyperParams, anneal_pt            — parallel-tempering baseline (pt.py)
   memory                              — Eq.(5)/(6) memory models
@@ -23,20 +24,14 @@ from .engine import (  # noqa: F401
     TILED_J_THRESHOLD,
     BaseResult,
     BatchedBackend,
-    DenseBackend,
     EngineState,
     PackedEngineState,
-    PallasBackend,
     Plateau,
-    PlateauBackend,
-    SparseBackend,
     bucket_n,
-    make_backend,
     make_batched_backend,
     pack_state,
     pad_model,
     padded_noise_init,
-    run_schedule,
     schedule_plateaus,
     unpack_state,
 )

@@ -8,7 +8,7 @@ is what executable-cache keys, checkpoint ``group_fingerprint``s, and
 ``filter_backend_opts`` consume.
 
 ``anneal()``, :class:`~repro.serve.AnnealRequest`, ``AnnealService``, and
-``make_[batched_]backend`` all accept ``config=SolverConfig(...)``; the old
+``make_batched_backend`` all accept ``config=SolverConfig(...)``; the old
 kwargs keep working through :func:`legacy_kwargs_to_config`, which warns
 ``DeprecationWarning`` once per call site.
 
@@ -84,7 +84,7 @@ class SolverConfig:
 
     def __post_init__(self):
         # PR-8 spelling rode partition/mesh inside backend_opts.  Hoist them
-        # into the typed fields so make_backend never receives them twice and
+        # into the typed fields so a backend never receives them twice and
         # the signature never falls back to repr() of a live Mesh object.
         opts = dict(self.backend_opts) if self.backend_opts else {}
         for key, default in (("partition", "problem"), ("mesh", None)):
@@ -97,7 +97,7 @@ class SolverConfig:
                     )
                 object.__setattr__(self, key, val)
         object.__setattr__(self, "backend_opts", _canon_opts(opts))
-        if isinstance(self.backend, str) and self.backend not in _BACKENDS:
+        if self.backend not in _BACKENDS:
             raise ValueError(
                 f"backend {self.backend!r} not in {_BACKENDS}"
             )
@@ -133,7 +133,7 @@ class SolverConfig:
         return dict(self.backend_opts)
 
     def engine_opts(self) -> Dict[str, Any]:
-        """kwargs for ``make_[batched_]backend(**...)`` minus backend/noise.
+        """kwargs for ``make_batched_backend(**...)`` minus backend/noise.
 
         Typed fields that are per-backend-family knobs are only emitted when
         the configured backend's constructor accepts them (sparse rejects
@@ -157,8 +157,7 @@ class SolverConfig:
         """Stable 16-hex digest over every behavior-affecting field."""
         payload = (
             "SolverConfig/v1",
-            self.backend if isinstance(self.backend, str)
-            else type(self.backend).__name__,
+            self.backend,
             self.storage_layout,
             self.field_mode,
             self.j_mode,

@@ -45,7 +45,6 @@ from .engine import (
     EngineState,
     PackedEngineState,
     Plateau,
-    PlateauBackend,
     TILED_J_THRESHOLD,
     _stack_packed_models,
     _stack_sparse_models,
@@ -67,7 +66,6 @@ __all__ = [
     "make_batched_iteration_step",
     "batched_anneal_step_lowering",
     "SPIN_AXIS",
-    "SpinShardedBackend",
     "BatchedSpinShardedBackend",
 ]
 
@@ -494,7 +492,7 @@ class BatchedSpinShardedBackend(BatchedBackend):
     def init_state(self, problem, noise0):
         return self._put_state(super().init_state(problem, noise0))
 
-    def _energy_local(self, m, field, h):
+    def _energy(self, m, field, h):
         # energy_from_field with the trial sums psum'd over shards BEFORE
         # the floor division: local (h·m + m·field) may be odd, the global
         # sum is what's even; int32 addition is order-free, so this is
@@ -517,7 +515,7 @@ class BatchedSpinShardedBackend(BatchedBackend):
             return unpack_spins(self._gather_words(m_local), self.n_bucket)
         return jax.lax.all_gather(m_local, self.axis, axis=-1, tiled=True)
 
-    def _field_local(self, prob, m_local):
+    def _field(self, prob, m_local):
         """This shard's fields from its J rows + the all-gathered spins."""
         if self.field_style == "popcount":
             mw = self._gather_words(m_local)
@@ -574,17 +572,9 @@ class BatchedSpinShardedBackend(BatchedBackend):
         )
 
     def _local_chain(self, prob, st, plateaus, n_shots):
-        h3 = prob["h"][:, None, :]
-        field_fn = lambda m: self._field_local(prob, m)  # noqa: E731
-
         def iteration(st, _):
             for p in plateaus:
-                st, _, _ = run_plateau_scan(
-                    field_fn, self._noise_step, h3, self.n_rnd, st, p.i0,
-                    length=p.length, eligible=p.eligible,
-                    energy_fn=self._energy_local,
-                    jperp=p.jperp, n_replicas=self.n_replicas,
-                )
+                st, _, _ = self._plateau_scan(prob, st, p)
             return st, None
 
         st, _ = jax.lax.scan(iteration, st, None, length=n_shots)
@@ -613,101 +603,42 @@ class BatchedSpinShardedBackend(BatchedBackend):
         p = Plateau(int(i0), int(length), bool(eligible), int(jperp))
         return self._sharded_chain((p,), 1)(problem, state)
 
-    def run_plateau_traced(self, problem, state, plateau: Plateau,
-                           track_energy: bool):
-        """One plateau with energy traces (the track_energy driver path)."""
+    def run_plateau_traced(self, problem, state, plateau: Plateau, *,
+                           track_energy: bool = False, emit: bool = False):
+        """The shared traced plateau, run inside the shard_map program.
+
+        Energies are psum'd over the shards, so the trace is the unsharded
+        one.  Trajectory planes are not supported: emit semantics would be
+        per-device partial planes — use partition='problem' for them.
+        """
+        if emit:
+            raise NotImplementedError(
+                "record='traj' is not supported under partition='spin'; "
+                "use partition='problem' for trajectory capture"
+            )
+        if not track_energy:
+            return super().run_plateau_traced(problem, state, plateau)
         packed = self.storage_layout == "packed"
         sspec = self._state_specs()
 
         def local_fn(prob, st):
             if packed:
                 st = self._unpack_local(st)
-            h3 = prob["h"][:, None, :]
-            st, trace, _ = run_plateau_scan(
-                lambda m: self._field_local(prob, m), self._noise_step, h3,
-                self.n_rnd, st, plateau.i0, length=plateau.length,
-                eligible=plateau.eligible, track_energy=track_energy,
-                energy_fn=self._energy_local,
-                jperp=plateau.jperp, n_replicas=self.n_replicas,
-            )
+            st, trace, _ = self._plateau_scan(prob, st, plateau,
+                                              track_energy=True)
             if packed:
                 st = self._pack_local(st)
-            if track_energy:
-                return st, trace
-            return st, (jnp.zeros((0,)), jnp.zeros((0,)))
+            return st, trace
 
-        return jax.shard_map(
+        st, trace = jax.shard_map(
             local_fn, mesh=self.mesh,
             in_specs=(self._problem_specs(), sspec),
             out_specs=(sspec, (P(None), P(None))),
             check_vma=False,
         )(problem, state)
+        return st, trace, None
 
     def run_shots(self, problem, state, plateaus, n_shots, live=None):
         return self._sharded_chain(tuple(plateaus), int(n_shots))(
             problem, state
         )
-
-
-class SpinShardedBackend(PlateauBackend):
-    """Single-problem spin-sharded backend (the `anneal` driver path).
-
-    Wraps a B=1 :class:`BatchedSpinShardedBackend`: the model is padded up
-    to a multiple of the mesh axis (padding-invariant — live lanes evolve
-    bit-identically, the pad columns are inert), its row shards are laid
-    out at construction, and every plateau runs as the shard_map collective
-    program.  `record='traj'` (trajectory planes) is not supported on this
-    path — emit semantics are per-device partial planes; use
-    partition='problem' for trajectory studies.
-    """
-
-    name = "spinshard"
-
-    def __init__(self, model, *, n_trials: int, n_rnd: int = 2,
-                 noise: str = "xorshift", storage_layout: str = "dense",
-                 mesh: Optional[Mesh] = None, axis: str = SPIN_AXIS, **opts):
-        if noise != "xorshift":
-            raise ValueError(
-                "partition='spin' requires noise='xorshift': shard-local "
-                "lane seeding is what makes sharded runs bit-identical"
-            )
-        super().__init__(model, n_trials=n_trials, n_rnd=n_rnd, noise=noise,
-                         storage_layout=storage_layout)
-        mesh = spin_mesh(axis=axis) if mesh is None else mesh
-        n_dev = mesh_axis_size(mesh, axis)
-        n_pad = -(-model.n // n_dev) * n_dev
-        self._bk = BatchedSpinShardedBackend(
-            mesh=mesh, axis=axis, n_bucket=n_pad, n_trials=n_trials,
-            n_rnd=n_rnd, noise=noise, storage_layout=storage_layout, **opts,
-        )
-        self.mesh = mesh
-        self.n_replicas = self._bk.n_replicas
-        self._problem = self._bk.stack([model])
-
-    def init_state(self, seed: int):
-        noise0 = self._bk.init_noise([seed], [self.model.n])
-        return self._bk.init_state(self._problem, noise0)
-
-    def run_plateau(self, state, i0, *, length, eligible, track_energy=False,
-                    emit=False, jperp=0):
-        if emit:
-            raise NotImplementedError(
-                "record='traj' is not supported under partition='spin'; "
-                "use partition='problem' for trajectory capture"
-            )
-        p = Plateau(int(i0), int(length), bool(eligible), int(jperp))
-        if track_energy:
-            st, trace = self._bk.run_plateau_traced(self._problem, state, p, True)
-            return st, trace, None
-        st = self._bk.run_plateau(
-            self._problem, state, p.i0, length=p.length, eligible=p.eligible,
-            jperp=p.jperp,
-        )
-        return st, None, None
-
-    def run_plateaus(self, state, plateaus):
-        return self._bk.run_shots(self._problem, state, tuple(plateaus), 1)
-
-    def finalize(self, state):
-        best_H, best_m = self._bk.finalize(state)
-        return best_H[0], best_m[0, :, : self.model.n]

@@ -5,33 +5,36 @@ pseudo-inverse temperature I0 — as the natural unit of execution and of
 storage (Eq. 4–6): the schedule advances plateau-by-plateau, and the BRAM
 write-enable is a *per-plateau* predicate (I0 == I0max), not a per-cycle
 mask.  This module makes the plateau the unit of the software architecture
-too:
+too.
 
-* :class:`PlateauBackend` — the pluggable execution protocol
-  (``init_state / run_plateau / finalize``).  A backend advances one
-  constant-I0 plateau of C cycles at a time; everything above it (drivers,
-  the distributed iteration step, benchmarks, the serving batch API) is
-  backend-agnostic.
-* :class:`SparseBackend` / :class:`DenseBackend` — `lax.scan` implementations
-  over one plateau sharing :func:`run_plateau_scan`.  The local-field
-  contraction runs **once per cycle**: the field computed for the Eq. (2a)
-  update of state m(t) is reused to evaluate H(m(t)) for solution tracking
-  and energy traces (the seed implementation evaluated it twice in
-  ``record='best'`` mode).
-* :class:`PallasBackend` — the resident plateau kernel: one ``pallas_call``
-  per plateau with J pinned in VMEM.  With xorshift noise this is the
-  streamed-noise packed kernel
-  (:func:`repro.kernels.ssa_update.ssa_plateau_packed`): uint32-bitplane
-  HBM refs, per-cycle noise generated in-kernel from carried xorshift
-  lanes — no (C, R, N) noise buffer exists anywhere.  Per-cycle HBM traffic
-  drops from O(N²) to O(R·N) — the TPU transcription of the FPGA's
-  "everything on-chip" design point.
+There is one engine: the batched backends.  A :class:`BatchedBackend`
+advances B stacked, bucket-padded problems through one compiled plateau
+program (``stack / init_noise / init_state / run_plateau / run_shots /
+finalize``).  The serving layer runs it at whatever B a request group
+needs; the library drivers (``ssa.anneal``, ``pt.anneal_pt_ssa``) run it at
+B=1 with the bucket at the model's own width (:func:`single_problem_backend`).
+
+* :class:`BatchedSparseBackend` / :class:`BatchedDenseBackend` — `lax.scan`
+  over :func:`run_plateau_scan`, vmapped over the problem axis.  The
+  local-field contraction runs **once per cycle**: the field computed for
+  the Eq. (2a) update of state m(t) is reused to evaluate H(m(t)) for
+  solution tracking and energy traces.
+* :class:`BatchedPallasBackend` — the resident plateau kernels on a
+  (B, R-tile) grid with J pinned in VMEM.  With xorshift noise the per-cycle
+  noise is generated in-kernel from carried xorshift lanes — no (C, R, N)
+  noise buffer exists anywhere — and with ``field_mode='popcount'`` a whole
+  plateau chain runs in one launch.
+
+Per-cycle *outputs* (energy traces, trajectory planes) come from
+:meth:`BatchedBackend.run_plateau_traced`, which runs a plateau on the scan
+path through the backend's own field, bit-identically to its production
+program; the resident kernels produce none.
 
 Storage layouts (DESIGN.md §4): every backend carries a
 ``storage_layout`` axis — 'dense' keeps :class:`EngineState` (int8 spins),
 'packed' keeps :class:`PackedEngineState` (uint32 bitplanes between
 launches).  Results are bit-identical; only the resident bytes differ.
-Dense-field backends additionally carry ``j_mode`` — 'tiled' streams
+The dense-field backend additionally carries ``j_mode`` — 'tiled' streams
 (tile_n, N) J slabs instead of materializing (N, N), admitting
 G77/G81-class instances.
 
@@ -87,12 +90,6 @@ __all__ = [
     "pack_state",
     "unpack_state",
     "Plateau",
-    "PlateauBackend",
-    "SparseBackend",
-    "DenseBackend",
-    "PallasBackend",
-    "BACKENDS",
-    "make_backend",
     "resolve_backend",
     "route_backend",
     "resolve_field_mode",
@@ -113,7 +110,6 @@ __all__ = [
     "schedule_plateaus",
     "tile_plateaus",
     "run_plateau_scan",
-    "run_schedule",
     "pack_spins",
     "unpack_spins",
     "packed_words",
@@ -133,6 +129,7 @@ __all__ = [
     "BatchedPallasBackend",
     "BATCHED_BACKENDS",
     "make_batched_backend",
+    "single_problem_backend",
 ]
 
 # Sentinel "no solution yet" energy (any real H is far below this).
@@ -163,7 +160,6 @@ from repro.kernels.bitplane import (  # noqa: E402
     PackedJ,
     adjacency_planes,
     adjacency_weight_bits,
-    pack_couplings_from_adjacency,
     pack_spins,
     packed_words,
     unpack_spins,
@@ -587,175 +583,8 @@ def run_plateau_scan(
 
 
 # ---------------------------------------------------------------------------
-# Backends
+# Routing: backend, field arithmetic, noise datapath and partition
 # ---------------------------------------------------------------------------
-class PlateauBackend:
-    """The pluggable execution protocol: init_state / run_plateau / finalize.
-
-    Subclasses provide the local-field contraction (and may override the
-    whole plateau execution, as the Pallas backend does).  Everything above
-    this protocol — the `anneal` driver, the distributed iteration step, the
-    benchmarks and the batch API — is backend-agnostic.
-    """
-
-    name = "abstract"
-
-    def __init__(
-        self,
-        model: IsingModel,
-        *,
-        n_trials: int,
-        n_rnd: int = 2,
-        noise: str = "threefry",
-        storage_layout: str = "dense",
-        n_replicas: int = 0,
-    ):
-        if storage_layout not in ("dense", "packed"):
-            raise ValueError(f"unknown storage_layout {storage_layout!r}")
-        self.model = model
-        self.n_trials = int(n_trials)
-        self.n_rnd = int(n_rnd)
-        self.noise = noise
-        self.storage_layout = storage_layout
-        self.n_replicas = int(n_replicas)
-        if self.n_replicas:
-            if self.n_replicas < 2:
-                raise ValueError("n_replicas must be >= 2 (or 0 to disable)")
-            if self.n_trials % self.n_replicas:
-                raise ValueError(
-                    f"n_trials {self.n_trials} not divisible by "
-                    f"n_replicas {self.n_replicas}"
-                )
-        self.h = jnp.asarray(model.h, jnp.int32)
-        lanes = (self.n_trials, model.n)
-        if noise == "xorshift":
-            self._noise_init = lambda seed: xorshift_init(seed, lanes)  # noqa: E731
-            self._noise_step = xorshift_next_bits
-        elif noise == "threefry":
-            self._noise_init = lambda seed: jax.random.PRNGKey(seed)  # noqa: E731
-
-            def step(key):
-                key, sub = jax.random.split(key)
-                return key, threefry_noise(sub, lanes)
-
-            self._noise_step = step
-        else:
-            raise ValueError(f"unknown noise {noise!r}")
-
-    # -- protocol ---------------------------------------------------------
-    def init_state(self, seed: int):
-        """Random ±1 start from the first noise draw (shared stream layout).
-
-        Returns :class:`EngineState` (storage_layout='dense') or
-        :class:`PackedEngineState` (storage_layout='packed'); drivers stay
-        layout-agnostic by only touching state through backend methods.
-        """
-        ns = self._noise_init(seed)
-        ns, r0 = self._noise_step(ns)
-        m0 = r0.astype(jnp.int8)
-        itanh0 = jnp.where(m0 > 0, 0, -1).astype(jnp.int32)
-        best_H = jnp.full((self.n_trials,), BIG_ENERGY, jnp.int32)
-        st = EngineState(ns, m0, itanh0, best_H, m0)
-        return pack_state(st) if self.storage_layout == "packed" else st
-
-    def run_plateau(
-        self,
-        state,
-        i0,
-        *,
-        length: int,
-        eligible: bool,
-        track_energy: bool = False,
-        emit: bool = False,
-        jperp: int = 0,
-    ):
-        """Advance one plateau in this backend's storage layout.
-
-        The packed layout wraps the dense implementation in the exact
-        pack/unpack codec (spins are ±1, so the round trip is bit-exact);
-        the Pallas backend overrides this to keep the HBM-facing kernel
-        refs packed end-to-end.  ``jperp`` is the SSQA replica coupling
-        (requires a backend built with ``n_replicas > 0``).
-        """
-        if self.storage_layout == "packed":
-            st = unpack_state(state, self.model.n)
-            st, trace, planes = self._run_plateau_dense(
-                st, i0, length=length, eligible=eligible,
-                track_energy=track_energy, emit=emit, jperp=jperp,
-            )
-            return pack_state(st), trace, planes
-        return self._run_plateau_dense(
-            state, i0, length=length, eligible=eligible,
-            track_energy=track_energy, emit=emit, jperp=jperp,
-        )
-
-    def run_plateaus(self, state, plateaus: Sequence[Plateau]):
-        """Advance a whole plateau chain (record='best', no traces).
-
-        The default chains :meth:`run_plateau`; resident backends override
-        it to execute the chain in one launch (multi-plateau residency).
-        Bit-identical either way — the chain semantics are defined by the
-        per-plateau fold rules.
-        """
-        for p in plateaus:
-            state, _, _ = self.run_plateau(
-                state, p.i0, length=p.length, eligible=p.eligible,
-                jperp=p.jperp,
-            )
-        return state
-
-    def _run_plateau_dense(self, state, i0, *, length, eligible,
-                           track_energy=False, emit=False, jperp=0):
-        raise NotImplementedError
-
-    def finalize(self, state) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Extract (best_H, best_m int8) after the last plateau."""
-        if self.storage_layout == "packed":
-            return state.best_H, unpack_spins(state.best_m_packed, self.model.n)
-        return state.best_H, state.best_m
-
-    # -- shared scan implementation --------------------------------------
-    def _field(self, m: jnp.ndarray) -> jnp.ndarray:
-        raise NotImplementedError
-
-    def _run_plateau_scan(self, state, i0, *, length, eligible, track_energy,
-                          emit, jperp=0):
-        return run_plateau_scan(
-            self._field,
-            self._noise_step,
-            self.h,
-            self.n_rnd,
-            state,
-            i0,
-            length=length,
-            eligible=eligible,
-            track_energy=track_energy,
-            emit=emit,
-            jperp=jperp,
-            n_replicas=self.n_replicas,
-        )
-
-
-class SparseBackend(PlateauBackend):
-    """Padded-adjacency gather field (4/8-regular G-set-class instances)."""
-
-    name = "sparse"
-
-    def __init__(self, model: IsingModel, **kw):
-        super().__init__(model, **kw)
-        _, self.nbr_idx, self.nbr_w = model.device_arrays()
-
-    def _field(self, m):
-        return local_fields_sparse(m.astype(jnp.int32), self.h, self.nbr_idx, self.nbr_w)
-
-    def _run_plateau_dense(self, state, i0, *, length, eligible,
-                           track_energy=False, emit=False, jperp=0):
-        return self._run_plateau_scan(
-            state, i0, length=length, eligible=eligible,
-            track_energy=track_energy, emit=emit, jperp=jperp,
-        )
-
-
 def resolve_j_mode(j_mode: str, n: int) -> str:
     """'auto' picks tiled above TILED_J_THRESHOLD spins, dense below."""
     if j_mode == "auto":
@@ -914,448 +743,6 @@ def resolve_noise_mode(noise_mode: str, noise: str) -> str:
     return noise_mode
 
 
-class DenseBackend(PlateauBackend):
-    """(T,N)·(N,N) MXU matmul field (K2000-class dense instances).
-
-    ``j_mode`` controls the coupling-matrix residency: 'dense' materializes
-    (N, N) J once; 'tiled' streams (tile_n, N) slabs scattered on the fly
-    from the padded adjacency (:func:`repro.core.ising.local_fields_tiled`) —
-    bit-identical, and the only way G77/G81-class N fits in memory.  'auto'
-    (the default) switches at TILED_J_THRESHOLD spins.
-
-    ``field_mode`` selects the contraction *arithmetic*: 'popcount' packs J
-    as sign/magnitude bitplanes (`kernels.bitplane.PackedJ`, ~32× smaller
-    than f32 J) and computes fields by XNOR-popcount on the uint32 words
-    (:func:`repro.core.ising.local_fields_popcount`) — exact-integer equal
-    to the matmul, so results stay bit-identical.  'auto' uses popcount for
-    couplings within POPCOUNT_AUTO_MAX_BITS magnitude planes.  Under
-    popcount no J matrix (dense or tiled) is materialized at all.
-    """
-
-    name = "dense"
-
-    def __init__(self, model: IsingModel, *, j_dtype=jnp.float32,
-                 j_mode: str = "auto", tile_n: int = 512,
-                 field_mode: str = "dense", double_buffer: bool = False,
-                 **kw):
-        super().__init__(model, **kw)
-        self.j_mode = resolve_j_mode(j_mode, model.n)
-        self.tile_n = int(tile_n)
-        self.double_buffer = bool(double_buffer)
-        self.field_mode = resolve_field_mode(
-            field_mode,
-            model_weight_bits(model) if field_mode == "auto" else 1,
-        )
-        if self.field_mode == "popcount":
-            self.packed_j = pack_couplings_from_adjacency(
-                model.n, model.nbr_idx, model.nbr_w
-            )
-            # Row-tile the contraction in the same regime the matmul would
-            # tile J: the broadcast XNOR buffer stays O(T·tile_n·N/32).
-            self._pc_tile = (
-                None if model.n <= TILED_J_THRESHOLD else self.tile_n
-            )
-        elif self.j_mode == "dense":
-            self.J = jnp.asarray(model.dense_J(), j_dtype)
-        else:
-            _, self.nbr_idx, self.nbr_w = model.device_arrays()
-
-    def _field(self, m):
-        if self.field_mode == "popcount":
-            return local_fields_popcount(
-                pack_spins(m), self.h, self.packed_j, tile_n=self._pc_tile
-            )
-        if self.j_mode == "tiled":
-            return local_fields_tiled(
-                m, self.h, self.nbr_idx, self.nbr_w, tile_n=self.tile_n,
-                double_buffer=self.double_buffer,
-            )
-        return local_fields_dense(m, self.h, self.J)
-
-    def _run_plateau_dense(self, state, i0, *, length, eligible,
-                           track_energy=False, emit=False, jperp=0):
-        return self._run_plateau_scan(
-            state, i0, length=length, eligible=eligible,
-            track_energy=track_energy, emit=emit, jperp=jperp,
-        )
-
-
-class PallasBackend(PlateauBackend):
-    """The resident plateau kernel: one `pallas_call` per plateau.
-
-    J is pinned in VMEM for all C cycles of the plateau.  With ``xorshift``
-    noise the plateau runs the **streamed-noise packed kernel**
-    (:func:`repro.kernels.ssa_update.ssa_plateau_packed`): the per-cycle
-    noise is generated *inside* the kernel by stepping the carried
-    xorshift128 lanes — bit-identical to pre-generated draws, but no
-    (C, T, N) noise buffer exists anywhere — and the HBM-facing spin refs
-    are uint32 bitplanes.  ``threefry`` noise cannot be reproduced in-kernel
-    and keeps the per-plateau (C, T, N) int8 pregen path (the
-    statistical-reference configuration, not the production one).
-
-    Per-cycle *outputs* (energy traces, trajectory planes) are the one thing
-    the resident kernel deliberately does not produce; plateaus that need
-    them (record='traj' store phases, track_energy runs) fall back to the
-    bit-identical scan path over the Pallas `local_field` kernel.  The
-    production solve path — record='best', track_energy=False — is entirely
-    resident.
-
-    ``field_mode='popcount'`` switches the resident kernel to the
-    bit-parallel chain kernel (:func:`~repro.kernels.ssa_update.
-    ssa_plateau_popcount`): J lives in VMEM as `PackedJ` bitplanes, the
-    contraction is XNOR-popcount on uint32 words, and — via
-    :meth:`run_plateaus` — a whole plateau chain runs in ONE `pallas_call`
-    (multi-plateau residency), amortizing launch overhead the way the dual-
-    BRAM FPGA overlaps streaming with compute.  Requires the streamed
-    (xorshift) noise path; no f32 J is ever materialized.
-    """
-
-    name = "pallas"
-
-    def __init__(
-        self,
-        model: IsingModel,
-        *,
-        j_dtype=jnp.float32,
-        block_r: int = 8,
-        interpret: Optional[bool] = None,
-        noise_mode: str = "auto",
-        field_mode: str = "dense",
-        **kw,
-    ):
-        super().__init__(model, **kw)
-        # Lazy import: keeps repro.core importable without the kernels pkg.
-        from repro.kernels import ops as kops
-        from repro.kernels import ssa_update as kssa
-
-        self._kops = kops
-        self._kssa = kssa
-        # SSQA (n_replicas > 0) pins the R-tile to the replica ring so each
-        # kernel tile holds exactly one ring (the roll stays tile-local).
-        self.block_r = self.n_replicas if self.n_replicas else int(block_r)
-        self.interpret = interpret
-        self.noise_mode = resolve_noise_mode(noise_mode, self.noise)
-        self.field_mode = resolve_field_mode(
-            field_mode,
-            model_weight_bits(model) if field_mode == "auto" else 1,
-        )
-        if self.field_mode == "popcount" and self.noise_mode != "streamed":
-            raise ValueError(
-                "field_mode='popcount' on the pallas backend requires "
-                "noise_mode='streamed' (noise='xorshift'): the bit-"
-                "parallel chain kernel generates its noise in-kernel"
-            )
-        _require_vmem_fit(
-            model.n, noise=self.noise, noise_mode=self.noise_mode,
-            field_mode=self.field_mode, block_r=self.block_r,
-            n_replicas=self.n_replicas, j_dtype=j_dtype,
-            j_bits=(model_weight_bits(model)
-                    if self.field_mode == "popcount" else 1),
-        )
-        if self.field_mode == "popcount":
-            self.packed_j = pack_couplings_from_adjacency(
-                model.n, model.nbr_idx, model.nbr_w
-            )
-        else:
-            self.J = jnp.asarray(model.dense_J(), j_dtype)
-
-    def _field(self, m):
-        if self.field_mode == "popcount":
-            # Scan fallback (traces/trajectories) stays on the packed
-            # arithmetic — no f32 J exists in this mode at all.
-            return local_fields_popcount(pack_spins(m), self.h, self.packed_j)
-        return self._kops.local_field(m.astype(jnp.float32), self.h, self.J)
-
-    def _popcount_call(self, mp, itanh, rng, i0_sched, fold_sched, bh, bmp,
-                       jperp_sched=None):
-        pj = self.packed_j
-        return self._kssa.ssa_plateau_popcount(
-            mp, itanh, pj.sign, pj.mags, pj.base, self.h, rng,
-            jnp.asarray(i0_sched, jnp.int32),
-            jnp.asarray(fold_sched, jnp.int32),
-            bh, bmp,
-            n_rnd=self.n_rnd,
-            block_r=self.block_r,
-            interpret=self.interpret,
-            jperp_sched=(
-                None if jperp_sched is None
-                else jnp.asarray(jperp_sched, jnp.int32)
-            ),
-            n_replicas=self.n_replicas,
-        )
-
-    def run_plateaus(self, state, plateaus: Sequence[Plateau]):
-        """Whole-chain execution: one `pallas_call` for the full schedule.
-
-        Only the popcount kernel carries per-cycle I0/fold operands, so only
-        ``field_mode='popcount'`` gets true multi-plateau residency; other
-        configurations chain per-plateau launches via the default.
-        """
-        if self.field_mode != "popcount" or not plateaus:
-            return super().run_plateaus(state, plateaus)
-        packed = self.storage_layout == "packed"
-        mp = state.m_packed if packed else pack_spins(state.m)
-        bmp = state.best_m_packed if packed else pack_spins(state.best_m)
-        i0_sched, fold_sched, jperp_sched = plateau_cycle_schedules(plateaus)
-        mp_o, it_o, rng_o, bh_o, bmp_o = self._popcount_call(
-            mp, state.itanh, state.noise_state, i0_sched, fold_sched,
-            state.best_H, bmp,
-            jperp_sched=jperp_sched if jperp_sched.any() else None,
-        )
-        if packed:
-            return PackedEngineState(rng_o, mp_o, it_o, bh_o, bmp_o)
-        n = self.model.n
-        return EngineState(
-            rng_o, unpack_spins(mp_o, n), it_o, bh_o, unpack_spins(bmp_o, n)
-        )
-
-    def _pregen_noise(self, ns, length: int):
-        def draw(ns, _):
-            ns, r = self._noise_step(ns)
-            return ns, r.astype(jnp.int8)
-
-        return jax.lax.scan(draw, ns, None, length=length)
-
-    def run_plateau(self, state, i0, *, length, eligible, track_energy=False,
-                    emit=False, jperp=0):
-        packed = self.storage_layout == "packed"
-        jperp = int(jperp)
-        # The pregen kernel is not jperp-extended: SSQA plateaus on the
-        # pregen path (threefry, or opt-in xorshift pregen) run the
-        # bit-identical scan fallback over the Pallas field kernel.
-        scan_fallback = emit or track_energy or (
-            jperp and self.noise_mode != "streamed"
-        )
-        if scan_fallback:
-            st = unpack_state(state, self.model.n) if packed else state
-            st, trace, planes = self._run_plateau_scan(
-                st, i0, length=length, eligible=eligible,
-                track_energy=track_energy, emit=emit, jperp=jperp,
-            )
-            return (pack_state(st) if packed else st), trace, planes
-        if self.field_mode == "popcount":
-            # One plateau is a length-C chain with constant I0; i0 may be
-            # traced (broadcast), eligibility is static host data.
-            mp = state.m_packed if packed else pack_spins(state.m)
-            bmp = state.best_m_packed if packed else pack_spins(state.best_m)
-            i0_sched = jnp.broadcast_to(
-                jnp.asarray(i0, jnp.int32), (int(length),)
-            )
-            fold_sched = np.asarray(
-                [0] + [int(bool(eligible))] * int(length), np.int32
-            )
-            jperp_sched = (
-                np.full(int(length), jperp, np.int32) if jperp else None
-            )
-            mp_o, it_o, rng_o, bh_o, bmp_o = self._popcount_call(
-                mp, state.itanh, state.noise_state, i0_sched, fold_sched,
-                state.best_H, bmp, jperp_sched=jperp_sched,
-            )
-            if packed:
-                return PackedEngineState(rng_o, mp_o, it_o, bh_o, bmp_o), None, None
-            n = self.model.n
-            return (
-                EngineState(
-                    rng_o, unpack_spins(mp_o, n), it_o, bh_o, unpack_spins(bmp_o, n)
-                ),
-                None,
-                None,
-            )
-        if self.noise_mode == "streamed":
-            # Streamed path: packed HBM refs, noise generated in-kernel.
-            mp = state.m_packed if packed else pack_spins(state.m)
-            bmp = state.best_m_packed if packed else pack_spins(state.best_m)
-            mp_o, it_o, rng_o, bh_o, bmp_o = self._kssa.ssa_plateau_packed(
-                mp,
-                state.itanh,
-                self.J,
-                self.h,
-                state.noise_state,
-                jnp.asarray(i0, jnp.int32),
-                state.best_H,
-                bmp,
-                n_cycles=int(length),
-                n_rnd=self.n_rnd,
-                eligible=bool(eligible),
-                block_r=self.block_r,
-                interpret=self.interpret,
-                jperp=jperp,
-                n_replicas=self.n_replicas if jperp else 0,
-            )
-            if packed:
-                return PackedEngineState(rng_o, mp_o, it_o, bh_o, bmp_o), None, None
-            n = self.model.n
-            return (
-                EngineState(
-                    rng_o, unpack_spins(mp_o, n), it_o, bh_o, unpack_spins(bmp_o, n)
-                ),
-                None,
-                None,
-            )
-        # Pregen path: the legacy per-plateau (C, T, N) buffer — mandatory
-        # for threefry (not reproducible in-kernel), opt-in for xorshift
-        # (noise_mode='pregen'; bit-identical to streamed, used as the
-        # measured baseline in benchmarks/timing.py --memory).
-        st = unpack_state(state, self.model.n) if packed else state
-        ns, noise = self._pregen_noise(st.noise_state, length)
-        m_o, it_o, bh_o, bm_o = self._kssa.ssa_plateau(
-            st.m.astype(jnp.float32),
-            st.itanh,
-            self.J,
-            self.h,
-            noise,
-            jnp.asarray(i0, jnp.int32),
-            st.best_H,
-            st.best_m,
-            n_rnd=self.n_rnd,
-            eligible=bool(eligible),
-            block_r=self.block_r,
-            interpret=self.interpret,
-        )
-        out = EngineState(ns, m_o.astype(jnp.int8), it_o, bh_o, bm_o)
-        return (pack_state(out) if packed else out), None, None
-
-
-BACKENDS = {
-    "sparse": SparseBackend,
-    "dense": DenseBackend,
-    "pallas": PallasBackend,
-}
-
-
-def make_backend(
-    backend: Union[str, PlateauBackend, type, None] = None,
-    model: IsingModel = None,
-    *,
-    n_trials: int,
-    n_rnd: int = 2,
-    noise: str = None,
-    partition: str = None,
-    mesh=None,
-    partition_axis: str = "model",
-    config=None,
-    **opts,
-) -> PlateauBackend:
-    """Resolve a backend spec: name, PlateauBackend subclass, or instance.
-
-    ``partition='spin'`` (or 'auto' on a multi-device mesh) reroutes to the
-    spin-sharded shard_map backend (DESIGN.md §11); ``backend`` then names
-    the *field contraction* the shards run (sparse gather / tiled f32 /
-    popcount via field_mode), not a single-device execution engine.
-
-    ``config=SolverConfig(...)`` supplies backend/noise/partition/mesh and
-    the engine opts in one typed object (DESIGN.md §13); the loose kwargs
-    remain as a deprecated shim (warning once per process).
-    """
-    if config is not None:
-        from .config import legacy_kwargs_to_config
-
-        cfg = legacy_kwargs_to_config(
-            "make_backend", config,
-            backend=backend if isinstance(backend, str) else None,
-            noise=noise, partition=partition,
-        )
-        backend = cfg.backend if backend is None else backend
-        noise, partition = cfg.noise, cfg.partition
-        mesh = cfg.mesh if mesh is None else mesh
-        merged = cfg.engine_opts()
-        merged.update(opts)
-        opts = merged
-    if backend is None:
-        backend = "sparse"
-    if noise is None:
-        noise = "threefry"
-    if partition is None:
-        partition = "problem"
-    part = resolve_partition(partition, model.n, mesh, axis=partition_axis)
-    if part == "spin":
-        from .distributed import SpinShardedBackend  # lazy: circular import
-
-        base = backend if isinstance(backend, str) else "dense"
-        return SpinShardedBackend(
-            model, n_trials=n_trials, n_rnd=n_rnd, noise=noise, mesh=mesh,
-            axis=partition_axis, base_backend=base, **opts,
-        )
-    if isinstance(backend, PlateauBackend):
-        if backend.n_trials != int(n_trials) or backend.n_rnd != int(n_rnd):
-            raise ValueError(
-                f"backend instance was built for n_trials={backend.n_trials}, "
-                f"n_rnd={backend.n_rnd}; caller wants n_trials={n_trials}, "
-                f"n_rnd={n_rnd}"
-            )
-        return backend
-    if isinstance(backend, type) and issubclass(backend, PlateauBackend):
-        cls = backend
-    else:
-        if backend == "auto":
-            backend = resolve_backend(backend, model.n, **{
-                "noise": noise, "j_bits": model_weight_bits(model), **opts,
-            })
-        try:
-            cls = BACKENDS[backend]
-        except (KeyError, TypeError):
-            raise ValueError(
-                f"unknown backend {backend!r}; known: {sorted(BACKENDS)}"
-            ) from None
-    return cls(model, n_trials=n_trials, n_rnd=n_rnd, noise=noise, **opts)
-
-
-# ---------------------------------------------------------------------------
-# The backend-agnostic schedule driver
-# ---------------------------------------------------------------------------
-def run_schedule(
-    backend: PlateauBackend,
-    plateaus: Sequence[Plateau],
-    state: EngineState,
-    *,
-    record: str = "best",
-    track_energy: bool = False,
-):
-    """Chain ``run_plateau`` over a plateau sequence (traceable).
-
-    record='best': eligible plateaus fold their states into the running
-    arg-best on the fly (the production path — what the FPGA cannot afford
-    and the TPU gets almost for free next to the field contraction).
-
-    record='traj': eligible plateaus emit bit-packed spin planes instead
-    (the FPGA's UART-shipped trajectory); best-tracking is left to the
-    caller's post-scan over the planes.
-
-    Returns (state, trace, planes): trace = (mean_H, min_H) concatenated
-    over all cycles when track_energy, planes concatenated over eligible
-    plateaus when record='traj'.
-    """
-    if record == "best" and not track_energy:
-        # Production path: no per-plateau outputs, so the whole chain can be
-        # handed to the backend at once — resident backends execute it in a
-        # single launch (multi-plateau residency), bit-identically.
-        return backend.run_plateaus(state, tuple(plateaus)), None, None
-    tr_mean, tr_min, planes = [], [], []
-    for p in plateaus:
-        if record == "traj":
-            state, _, pl = backend.run_plateau(
-                state, p.i0, length=p.length, eligible=False,
-                track_energy=False, emit=p.eligible, jperp=p.jperp,
-            )
-            if pl is not None:
-                planes.append(pl)
-        elif record == "best":
-            state, tr, _ = backend.run_plateau(
-                state, p.i0, length=p.length, eligible=p.eligible,
-                track_energy=track_energy, emit=False, jperp=p.jperp,
-            )
-            if tr is not None:
-                tr_mean.append(tr[0])
-                tr_min.append(tr[1])
-        else:
-            raise ValueError(f"unknown record {record!r}")
-    trace = (
-        (jnp.concatenate(tr_mean), jnp.concatenate(tr_min)) if tr_mean else None
-    )
-    planes_out = jnp.concatenate(planes, axis=0) if planes else None
-    return state, trace, planes_out
-
-
 # ---------------------------------------------------------------------------
 # Shape buckets and padded problems (the serving substrate, DESIGN.md §7)
 # ---------------------------------------------------------------------------
@@ -1467,21 +854,21 @@ def padded_noise_init_slice(seed: int, n_trials: int, n_live: int,
 # Batched backends: B stacked problems through one compiled plateau program
 # ---------------------------------------------------------------------------
 class BatchedBackend:
-    """Batched execution of B stacked, bucket-padded problems.
+    """Batched execution of B stacked, bucket-padded problems — the engine.
 
-    The serving counterpart of :class:`PlateauBackend` (DESIGN.md §7): problem
-    arrays are **call-time arguments** (a dict of stacked jnp arrays from
-    :meth:`stack`), not constructor state, so one jitted program per
+    Problem arrays are **call-time arguments** (a dict of stacked jnp arrays
+    from :meth:`stack`), not constructor state, so one jitted program per
     (backend, N_bucket, B, n_trials, schedule signature) serves every request
     group that shape-matches — the serving layer's compiled-executable cache
-    keys on exactly those statics.
+    keys on exactly those statics (DESIGN.md §7).  The library drivers run
+    the same classes at B=1 (:func:`single_problem_backend`).
 
     State layout is :class:`EngineState` with a leading problem axis:
     spins (B, T, N), best_H (B, T), xorshift lanes (B, 4, T, N).  ``sparse``
-    and ``dense`` vmap the single-problem plateau scan over the problem axis;
-    ``pallas`` launches the resident kernel on a (B, R-tile) grid.  All three
-    are bit-identical per problem to the corresponding unbatched backend —
-    property-tested.
+    and ``dense`` vmap the one-problem plateau scan over the problem axis;
+    ``pallas`` launches the resident kernel on a (B, R-tile) grid.  All
+    three are bit-identical per problem to one another and, on live lanes,
+    to an unpadded run of the same problem — property-tested.
     """
 
     name = "abstract"
@@ -1552,7 +939,7 @@ class BatchedBackend:
 
     # -- traced -----------------------------------------------------------
     def init_state(self, problem: dict, noise0):
-        """Random ±1 start from the first noise draw (matches PlateauBackend)."""
+        """Random ±1 start from the first noise draw (shared stream layout)."""
         ns, r0 = self._noise_step(noise0)
         m0 = r0.astype(jnp.int8)
         itanh0 = jnp.where(m0 > 0, 0, -1).astype(jnp.int32)
@@ -1590,6 +977,54 @@ class BatchedBackend:
             return pack_state(st)
         return self._run_shots_dense(problem, state, plateaus, n_shots, live)
 
+    def run_plateau_traced(self, problem: dict, state, plateau: Plateau, *,
+                           track_energy: bool = False, emit: bool = False):
+        """One plateau with its per-cycle outputs (the library drivers).
+
+        The resident kernels produce no per-cycle outputs, so a plateau that
+        wants energy traces or trajectory planes runs on the scan path
+        through this backend's own field — bit-identical to its production
+        program.  Wanting neither, it is :meth:`run_plateau`.
+
+        Returns ``(state', trace, planes)``: trace is ``(mean_H, min_H)``,
+        each (C,) over every lane, aligned to the produced states, when
+        ``track_energy``; planes the (C, B, T, ceil(N/32)) uint32 bit-packed
+        trajectory when ``emit``.
+        """
+        p = plateau
+        if not (track_energy or emit):
+            st = self.run_plateau(problem, state, p.i0, length=p.length,
+                                  eligible=p.eligible, jperp=p.jperp)
+            return st, None, None
+        packed = self.storage_layout == "packed"
+        st = unpack_state(state, self.n_bucket) if packed else state
+        st, trace, planes = self._plateau_scan(
+            problem, st, p, track_energy=track_energy, emit=emit
+        )
+        return (pack_state(st) if packed else st), trace, planes
+
+    def _plateau_scan(self, problem: dict, st: EngineState, p: Plateau, *,
+                      track_energy: bool = False, emit: bool = False):
+        """:func:`run_plateau_scan` over every lane at once (dense layout)."""
+        return run_plateau_scan(
+            lambda m: self._field(problem, m), self._noise_step,
+            problem["h"][:, None, :], self.n_rnd, st, p.i0,
+            length=p.length, eligible=p.eligible, track_energy=track_energy,
+            emit=emit, energy_fn=self._energy, jperp=p.jperp,
+            n_replicas=self.n_replicas,
+        )
+
+    def _field_one(self, prob: dict, m: jnp.ndarray) -> jnp.ndarray:
+        """Local fields of one problem lane's (T, N) spins."""
+        raise NotImplementedError
+
+    def _field(self, problem: dict, m: jnp.ndarray) -> jnp.ndarray:
+        """Local fields of every lane's (B, T, N) spins."""
+        return jax.vmap(self._field_one)(problem, m)
+
+    def _energy(self, m, field, h):
+        return energy_from_field(m, field, h)
+
     def _run_plateau_dense(self, problem: dict, state: EngineState, i0, *,
                            length, eligible, jperp=0):
         raise NotImplementedError
@@ -1606,9 +1041,6 @@ class BatchedBackend:
 
 class _VmapBatchedBackend(BatchedBackend):
     """Shared vmap-over-problems implementation (sparse/dense fields)."""
-
-    def _field_one(self, prob: dict, m: jnp.ndarray) -> jnp.ndarray:
-        raise NotImplementedError
 
     def _run_one_plateaus(self, prob, st, plateaus):
         field_fn = lambda m: self._field_one(prob, m)  # noqa: E731
@@ -1864,6 +1296,8 @@ class BatchedPallasBackend(BatchedBackend):
 
     All three kernels take :meth:`run_shots`' ``live`` mask into SMEM: the
     grid steps of a dead lane copy its state through and compute nothing.
+    Plateaus that want per-cycle outputs (:meth:`run_plateau_traced`) run
+    the scan path over the same arithmetic instead.
     """
 
     name = "pallas"
@@ -1889,12 +1323,6 @@ class BatchedPallasBackend(BatchedBackend):
                 "field_mode='popcount' on the batched pallas backend "
                 "requires noise_mode='streamed' (noise='xorshift')"
             )
-        if self.n_replicas and self.noise_mode != "streamed":
-            raise ValueError(
-                "SSQA (n_replicas > 0) on the batched pallas backend "
-                "requires noise_mode='streamed' (noise='xorshift'); the "
-                "pregen kernel has no replica-coupling path"
-            )
         _require_vmem_fit(
             self.n_bucket, noise=self.noise, noise_mode=self.noise_mode,
             field_mode=self.field_mode, j_bits=self.j_bits,
@@ -1905,6 +1333,14 @@ class BatchedPallasBackend(BatchedBackend):
         if self.field_mode == "popcount":
             return _stack_packed_models(models, self.n_bucket, self.j_bits)
         return _stack_dense_models(models, self.n_bucket, self.j_dtype)
+
+    def _field_one(self, prob, m):
+        # The scan path (per-cycle outputs) keeps the kernel's arithmetic:
+        # XNOR-popcount on the same bitplanes, or the f32 J matmul.
+        if self.field_mode == "popcount":
+            pj = PackedJ(prob["sign"], prob["mags"], prob["base"])
+            return local_fields_popcount(pack_spins(m), prob["h"], pj)
+        return local_fields_dense(m, prob["h"], prob["J"])
 
     def _pregen(self, ns, length: int):
         def draw(ns, _):
@@ -2021,10 +1457,10 @@ class BatchedPallasBackend(BatchedBackend):
     def _run_plateau_dense(self, problem, state, i0, *, length, eligible,
                            jperp=0, live=None):
         if jperp:
-            raise ValueError(
-                "SSQA requires noise_mode='streamed' on the batched pallas "
-                "backend (pregen kernel has no replica-coupling path)"
-            )
+            # The pregen kernel has no replica coupling: an SSQA plateau
+            # runs the scan path over the same f32 J, every lane.
+            p = Plateau(int(i0), int(length), bool(eligible), int(jperp))
+            return self._plateau_scan(problem, state, p)[0]
         ns, noise = self._pregen(state.noise_state, length)  # (C, B, T, N)
         noise = jnp.swapaxes(noise, 0, 1)                    # (B, C, T, N)
         m_o, it_o, bh_o, bm_o = self._kssa.ssa_plateau_batched(
@@ -2099,9 +1535,8 @@ def make_batched_backend(
     if part == "spin":
         from .distributed import BatchedSpinShardedBackend  # lazy: circular
 
-        base = backend if isinstance(backend, str) else "dense"
         return BatchedSpinShardedBackend(
-            base_backend=base, mesh=mesh, axis=partition_axis,
+            base_backend=backend, mesh=mesh, axis=partition_axis,
             n_bucket=n_bucket, n_trials=n_trials, n_rnd=n_rnd, noise=noise,
             **opts,
         )
@@ -2114,3 +1549,50 @@ def make_batched_backend(
             f"unknown batched backend {backend!r}; known: {sorted(BATCHED_BACKENDS)}"
         ) from None
     return cls(n_bucket=n_bucket, n_trials=n_trials, n_rnd=n_rnd, noise=noise, **opts)
+
+
+def single_problem_backend(
+    model: IsingModel,
+    backend: str = "sparse",
+    *,
+    n_trials: int,
+    n_rnd: int = 2,
+    noise: str = "threefry",
+    partition: str = "problem",
+    mesh=None,
+    partition_axis: str = "model",
+    **opts,
+) -> Tuple[BatchedBackend, dict]:
+    """``(bk, problem)``: a batched backend at B=1 and ``model`` stacked on it.
+
+    The library drivers' engine.  The bucket is the model's own width, so
+    the noise draws keep the unpadded shape (threefry draws are shape-
+    dependent).  Under ``partition='spin'`` the bucket rounds up to a
+    multiple of the mesh axis instead — the pad columns are inert and the
+    xorshift lanes that partition requires are padding-invariant — and
+    ``mesh`` defaults to every device.  The field-capable backends get the
+    model's own ``j_bits``; ``backend='auto'`` routes on them.  Callers
+    slice lane 0, and the first ``model.n`` spins, out of :meth:`finalize`.
+    """
+    part = resolve_partition(partition, model.n, mesh, axis=partition_axis)
+    n_bucket = model.n
+    j_bits = model_weight_bits(model)
+    if part == "spin":
+        from repro.sharding import mesh_axis_size, spin_mesh  # lazy
+
+        mesh = spin_mesh(axis=partition_axis) if mesh is None else mesh
+        n_dev = mesh_axis_size(mesh, partition_axis)
+        n_bucket = -(-model.n // n_dev) * n_dev
+        opts.setdefault("j_bits", j_bits)
+    else:
+        backend = resolve_backend(backend, model.n, **{
+            "noise": noise, "j_bits": j_bits, **opts,
+        })
+        if backend in ("dense", "pallas"):
+            opts.setdefault("j_bits", j_bits)
+    bk = make_batched_backend(
+        backend, n_bucket=n_bucket, n_trials=n_trials, n_rnd=n_rnd,
+        noise=noise, partition=part, mesh=mesh,
+        partition_axis=partition_axis, **opts,
+    )
+    return bk, bk.stack([model])

@@ -36,9 +36,9 @@ from .engine import (
     EngineState,
     energy_from_field,
     finalize_cut,
-    make_backend,
     normalize_problem,
     run_plateau_scan,
+    single_problem_backend,
 )
 from .ising import IsingModel, MaxCutProblem
 
@@ -271,7 +271,8 @@ def anneal_pt_ssa(
 
     ``backend`` must be 'sparse' or 'dense': the resident Pallas kernel takes
     a scalar plateau I0 (per-replica I0 columns are a kernel extension left
-    to a later PR), so PT-SSA runs the scan path.
+    to a later PR), so PT-SSA runs the scan path.  The problem runs as lane
+    0 of a one-problem batch, through the rounds the service vmaps.
     """
     if backend == "pallas":
         raise ValueError(
@@ -279,22 +280,27 @@ def anneal_pt_ssa(
             "kernel is scalar-I0 — use backend='sparse' or 'dense'"
         )
     maxcut, model = normalize_problem(problem)
-    bk = make_backend(
-        backend, model, n_trials=hp.n_replicas, n_rnd=hp.n_rnd, noise=noise
+    bk, stacked = single_problem_backend(
+        model, backend, n_trials=hp.n_replicas, n_rnd=hp.n_rnd, noise=noise
     )
-    h = jnp.asarray(model.h, jnp.int32)
+    noise0 = bk.init_noise([seed], [model.n])
 
     @jax.jit
-    def run():
-        state = bk.init_state(seed)
+    def run(prob, ns0):
+        state = bk.init_state(prob, ns0)
         keys = jax.random.split(jax.random.PRNGKey(seed ^ 0x5CA1AB1E), hp.n_rounds)
         parities = jnp.arange(hp.n_rounds, dtype=jnp.int32) % 2
-        state = pt_ssa_rounds(
-            bk._field, bk._noise_step, h, hp, state, keys, parities
-        )
-        return bk.finalize(state)
 
-    best_H, best_m = run()
+        def one(pr, st):
+            field_fn = lambda m: bk._field_one(pr, m)  # noqa: E731
+            return pt_ssa_rounds(
+                field_fn, bk._noise_step_one, pr["h"], hp, st, keys, parities
+            )
+
+        best_H, best_m = bk.finalize(jax.vmap(one)(prob, state))
+        return best_H[0], best_m[0]
+
+    best_H, best_m = run(stacked, noise0)
     best_H = np.asarray(best_H)
     return PTSSAResult(
         best_cut=np.asarray(finalize_cut(best_H, maxcut)),

@@ -20,21 +20,23 @@ The *only* difference between SSA and HA-SSA is outside this update path:
 TPU adaptation (see DESIGN.md §2): :func:`anneal` is a thin driver over the
 plateau-structured engine in :mod:`repro.core.engine`.  The schedule is
 grouped into constant-I0 plateaus — HA-SSA's unit of execution and storage —
-and each plateau is advanced by a pluggable :class:`~repro.core.engine.PlateauBackend`:
+and the problem runs as a one-lane batch on the engine's batched backends
+(:func:`~repro.core.engine.single_problem_backend`), the same programs the
+serving layer runs:
 
 * ``backend='sparse'`` — padded-adjacency gather field, `lax.scan` per plateau;
 * ``backend='dense'``  — (T,N)·(N,N) MXU matmul field, `lax.scan` per plateau
-  (``j_mode='tiled'`` streams (tile_n, N) J slabs for G77/G81-class N);
-* ``backend='pallas'`` — the resident plateau kernel: one ``pallas_call``
-  per plateau with J pinned in VMEM (DESIGN.md §2.3).  With ``xorshift``
-  noise this is the **streamed-noise packed kernel**: per-cycle noise is
-  generated inside the kernel from the carried xorshift lanes and the
-  HBM-facing spin refs are uint32 bitplanes — no (C, R, N) noise buffer is
-  ever allocated, in the driver or anywhere else.  (``threefry`` keeps the
-  per-plateau pregen reference path; it cannot be reproduced in-kernel.)
+  (``j_mode='tiled'`` streams (tile_n, N) J slabs for G77/G81-class N;
+  ``field_mode='popcount'`` contracts XNOR-popcount bitplanes);
+* ``backend='pallas'`` — the resident plateau kernels with J pinned in VMEM
+  (DESIGN.md §2.3).  With ``xorshift`` noise per-cycle noise is generated
+  inside the kernel from the carried xorshift lanes and the HBM-facing spin
+  refs are uint32 bitplanes — no (C, R, N) noise buffer is ever allocated.
+  (``threefry`` keeps the per-plateau pregen reference path; it cannot be
+  reproduced in-kernel.)
 
 ``storage_layout='packed'`` additionally keeps the engine state *between*
-plateaus as uint32 bitplanes (DESIGN.md §4) — bit-identical results, 8–32×
+launches as uint32 bitplanes (DESIGN.md §4) — bit-identical results, 8–32×
 smaller resident spin storage.
 
 All three advance the field contraction **once per cycle** (the field used
@@ -68,12 +70,11 @@ import numpy as np
 from .engine import (
     BaseResult,
     finalize_cut,
-    make_backend,
     normalize_problem,
     pack_spins,
     packed_words,
-    run_schedule,
     schedule_plateaus,
+    single_problem_backend,
     ssa_cycle_update,
     tile_plateaus,
     unpack_spins,
@@ -138,8 +139,40 @@ class AnnealResult(BaseResult):
 
 
 # ---------------------------------------------------------------------------
-# Main annealer: a thin driver over the plateau engine
+# Main annealer: a thin driver over the plateau engine at B=1
 # ---------------------------------------------------------------------------
+def _traced_chain(bk, problem, plateaus, state, *, record, track_energy):
+    """One pass of a plateau chain with per-cycle outputs, lane 0's planes.
+
+    record='best': eligible plateaus fold their states into the running
+    arg-best; ``track_energy`` adds the (mean_H, min_H) trace of every cycle.
+    record='traj': eligible plateaus emit bit-packed spin planes instead
+    (the FPGA's UART-shipped trajectory); best-tracking is left to the
+    caller's post-scan over the planes.  Plateaus that want no output run
+    the backend's own plateau program (the resident kernel on pallas).
+    """
+    tr_mean, tr_min, planes = [], [], []
+    for p in plateaus:
+        if record == "traj":
+            state, _, pl = bk.run_plateau_traced(
+                problem, state, dataclasses.replace(p, eligible=False),
+                emit=p.eligible,
+            )
+            if pl is not None:
+                planes.append(pl[:, 0])
+        else:
+            state, tr, _ = bk.run_plateau_traced(
+                problem, state, p, track_energy=track_energy
+            )
+            if tr is not None:
+                tr_mean.append(tr[0])
+                tr_min.append(tr[1])
+    trace = (
+        (jnp.concatenate(tr_mean), jnp.concatenate(tr_min)) if tr_mean else None
+    )
+    return state, trace, jnp.concatenate(planes) if planes else None
+
+
 def anneal(
     problem: Union[MaxCutProblem, IsingModel],
     hp: Union[SSAHyperParams, str] = SSAHyperParams(),
@@ -147,7 +180,7 @@ def anneal(
     *,
     storage: str = "i0max",        # 'i0max' (HA-SSA) | 'all' (conventional SSA)
     record: str = "best",          # 'best' | 'traj'
-    backend=None,                  # legacy: 'sparse' | 'dense' | 'pallas' | inst
+    backend: Optional[str] = None,  # legacy: 'sparse'|'dense'|'pallas'|'auto'
     noise: Optional[str] = None,   # legacy: 'threefry' | 'xorshift'
     track_energy: bool = True,
     schedule_kind: str = "hassa",  # 'hassa' Eq.(4) | 'ssa' Eq.(3)
@@ -175,18 +208,20 @@ def anneal(
     (DESIGN.md §13).  The loose ``backend``/``noise``/``storage_layout``/
     ``backend_opts`` kwargs keep working as a deprecated shim (one
     ``DeprecationWarning`` per process) with their historical defaults
-    (sparse backend, threefry noise, dense layout).
+    (sparse backend, threefry noise, dense layout).  ``backend`` is a
+    name — 'sparse', 'dense', 'pallas' or 'auto' — never an instance.
 
     An :class:`~repro.core.ssqa.SSQAHyperParams` ``hp`` switches the run to
     SSQA (DESIGN.md §13): the schedule carries the J⊥ ramp and the backend
     is built with the hp's Trotter-replica count.
 
-    The hot loop iterates ``m_shot × steps`` plateaus over the selected
-    backend; ``backend='pallas'`` executes each plateau as a single resident
-    ``pallas_call``.  Per-cycle energy traces (``track_energy``) and
-    trajectory planes (``record='traj'``) need per-cycle outputs, which the
-    resident kernel does not produce — those plateaus run the bit-identical
-    scan path instead.
+    The problem runs as lane 0 of a one-problem batch on the engine's
+    batched backend.  A ``record='best'`` run without ``track_energy`` is
+    the service's own chunk program (``run_shots``): on ``backend='pallas'``
+    each iteration is resident kernel launches.  Per-cycle energy traces
+    (``track_energy``) and trajectory planes (``record='traj'``) need
+    per-cycle outputs, which the resident kernel does not produce — those
+    plateaus run the bit-identical scan path instead.
     """
     from .config import legacy_kwargs_to_config
 
@@ -196,10 +231,11 @@ def anneal(
         from .autotune import resolve_hyperparams
 
         hp, _ = resolve_hyperparams(hp, model, base=auto_base)
+    if record not in ("best", "traj"):
+        raise ValueError(f"unknown record {record!r}")
     cfg = legacy_kwargs_to_config(
         "repro.core.ssa.anneal", config,
-        backend=backend if isinstance(backend, str) else None,
-        noise=noise, storage_layout=storage_layout,
+        backend=backend, noise=noise, storage_layout=storage_layout,
         backend_opts=dict(backend_opts) if backend_opts else None,
     )
     if config is None and noise is None:
@@ -213,15 +249,14 @@ def anneal(
     nr = int(getattr(hp, "n_replicas", 0) or 0)
     if nr:
         opts.setdefault("n_replicas", nr)
-    bk = make_backend(
-        backend if backend is not None and not isinstance(backend, str)
-        else cfg.backend,
-        model, n_trials=hp.n_trials, n_rnd=hp.n_rnd, noise=cfg.noise,
-        partition=cfg.partition, mesh=cfg.mesh,
-        **opts,
+    bk, stacked = single_problem_backend(
+        model, cfg.backend, n_trials=hp.n_trials, n_rnd=hp.n_rnd,
+        noise=cfg.noise, partition=cfg.partition, mesh=cfg.mesh, **opts,
     )
+    noise0 = bk.init_noise([seed], [model.n])
     plateaus = schedule_plateaus(sched, storage)
     stored_per_iter = sum(p.length for p in plateaus if p.eligible)
+    n = model.n
 
     if record == "traj":
         # Iteration-structured: heat plateaus emit nothing; eligible plateaus
@@ -229,17 +264,19 @@ def anneal(
         # (stored/cpi)× smaller, mirroring the BRAM depth saving.
         hh, nbr_idx, nbr_w = model.device_arrays()
 
-        def run():
-            state = bk.init_state(seed)
+        def run(prob, ns0):
+            state = bk.init_state(prob, ns0)
 
             def iteration(st, _):
-                st, _, planes = run_schedule(bk, plateaus, st, record="traj")
+                st, _, planes = _traced_chain(
+                    bk, prob, plateaus, st, record="traj", track_energy=False
+                )
                 return st, planes
 
             state, traj = jax.lax.scan(iteration, state, None, length=hp.m_shot)
             # Solution = best stored state, scanned outside the hot loop.
-            flat = traj.reshape(-1, hp.n_trials, packed_words(model.n))
-            spins = unpack_spins(flat, model.n)  # (S, T, N)
+            flat = traj.reshape(-1, hp.n_trials, packed_words(n))
+            spins = unpack_spins(flat, n)  # (S, T, N)
             H = ising_energy(spins.astype(jnp.int32), hh, nbr_idx, nbr_w)  # (S, T)
             if maxcut is not None:
                 idx = jnp.argmax((maxcut.w_total - H) // 2, axis=0)
@@ -250,68 +287,49 @@ def anneal(
             best_H = H[idx, tt]
             return best_H, best_m, traj
 
-        best_H, best_m, traj = jax.jit(run)()
+        best_H, best_m, traj = jax.jit(run)(stacked, noise0)
         e_mean = e_min = None
     else:
-        if record != "best":
-            raise ValueError(f"unknown record {record!r}")
         if total_cycles is None:
-            # Iteration-aligned: scan the per-iteration plateau chain m_shot×.
-            def run():
-                state = bk.init_state(seed)
-
-                def iteration(st, _):
-                    st, trace, _ = run_schedule(
-                        bk, plateaus, st, record="best", track_energy=track_energy
-                    )
-                    return st, trace
-
-                state, trace = jax.lax.scan(
-                    iteration, state, None, length=hp.m_shot
-                )
-                best_H, best_m = bk.finalize(state)
-                return best_H, best_m, trace
+            # Iteration-aligned: the per-iteration plateau chain m_shot×.
+            full_iters, tail = hp.m_shot, ()
         else:
-            # Cycle-count duration control: scan the full iterations, then
-            # chain the truncated tail's plateaus (keeps the compiled program
-            # one iteration body + tail, not total_cycles/τ unrolled scans).
-            cpi = sched.cycles_per_iter
-            full_iters, rem = divmod(int(total_cycles), cpi)
+            # Cycle-count duration control: the full iterations, then the
+            # truncated tail's plateaus (keeps the compiled program one
+            # iteration body + tail, not total_cycles/τ unrolled scans).
+            full_iters, rem = divmod(int(total_cycles), sched.cycles_per_iter)
             tail = tile_plateaus(plateaus, rem) if rem else ()
 
-            def run():
-                state = bk.init_state(seed)
-                traces = []
-                if full_iters:
-                    def iteration(st, _):
-                        st, trace, _ = run_schedule(
-                            bk, plateaus, st, record="best",
-                            track_energy=track_energy,
-                        )
-                        return st, trace
+        def advance(prob, st, chain, n_iters):
+            """``n_iters`` passes of ``chain``: (state, flat trace or None)."""
+            if not track_energy:
+                return bk.run_shots(prob, st, chain, n_iters), None
 
-                    state, tr = jax.lax.scan(
-                        iteration, state, None, length=full_iters
-                    )
-                    if track_energy:
-                        traces.append((tr[0].reshape(-1), tr[1].reshape(-1)))
-                if tail:
-                    state, tr, _ = run_schedule(
-                        bk, tail, state, record="best", track_energy=track_energy
-                    )
-                    if track_energy:
-                        traces.append(tr)
-                best_H, best_m = bk.finalize(state)
-                trace = (
-                    tuple(
-                        jnp.concatenate([t[i] for t in traces]) for i in (0, 1)
-                    )
-                    if track_energy
-                    else None
+            def iteration(s, _):
+                s, trace, _ = _traced_chain(
+                    bk, prob, chain, s, record="best", track_energy=True
                 )
-                return best_H, best_m, trace
+                return s, trace
 
-        best_H, best_m, trace = jax.jit(run)()
+            st, tr = jax.lax.scan(iteration, st, None, length=n_iters)
+            return st, (tr[0].reshape(-1), tr[1].reshape(-1))
+
+        def run(prob, ns0):
+            state = bk.init_state(prob, ns0)
+            traces = []
+            for chain, n_iters in ((plateaus, full_iters), (tail, 1)):
+                if chain and n_iters:
+                    state, tr = advance(prob, state, chain, n_iters)
+                    traces.append(tr)
+            best_H, best_m = bk.finalize(state)
+            trace = (
+                tuple(jnp.concatenate([t[i] for t in traces]) for i in (0, 1))
+                if track_energy
+                else None
+            )
+            return best_H[0], best_m[0, :, :n], trace
+
+        best_H, best_m, trace = jax.jit(run)(stacked, noise0)
         traj = None
         if track_energy:
             e_mean = np.asarray(trace[0]).reshape(-1)

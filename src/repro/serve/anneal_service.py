@@ -447,8 +447,8 @@ class AnnealService:
     Bit-exactness contract (noise='xorshift'): an SSA or PT-SSA request
     solved through the service — padded, stacked, chunked, checkpointed,
     resumed — returns the same best energy/spins on its live lanes as the
-    corresponding single-problem driver (`anneal` / `anneal_pt_ssa`) on the
-    unpadded instance.  SA requests are valid runs but not bit-comparable
+    library driver (`anneal` / `anneal_pt_ssa`, the same backend at B=1) on
+    the unpadded instance.  SA requests are valid runs but not bit-comparable
     (their threefry init draw is shape-dependent).
     """
 

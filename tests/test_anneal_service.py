@@ -31,7 +31,7 @@ from repro.core.pt import PTSSAHyperParams, anneal_pt_ssa
 from repro.serve import AnnealRequest, AnnealService
 
 HP = SSAHyperParams(n_trials=3, m_shot=4, tau=4, i0_min=1, i0_max=8)
-BACKENDS = ["sparse", "dense", "pallas"]
+BACKEND_NAMES = ["sparse", "dense", "pallas"]
 
 
 def _mixed_problems():
@@ -47,7 +47,7 @@ def _mixed_problems():
 # ---------------------------------------------------------------------------
 # The acceptance property: mixed-size batches == per-problem unpadded runs
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKEND_NAMES)
 def test_mixed_batch_bit_identical_to_unpadded_runs(backend):
     problems = _mixed_problems()
     reqs = [AnnealRequest(problem=p, hp=HP, seed=10 + i)
@@ -84,7 +84,7 @@ def test_padding_invariance_property(seed):
 
     ref = anneal(p, HP, seed=seed, record="best", noise="xorshift",
                  backend="sparse", track_energy=False)
-    for backend in BACKENDS:
+    for backend in BACKEND_NAMES:
         svc = AnnealService(backend=backend, min_bucket=16)
         resp = svc.solve([AnnealRequest(problem=p, hp=HP, seed=seed)])[0]
         np.testing.assert_array_equal(ref.best_cut, resp.result.best_cut)

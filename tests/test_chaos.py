@@ -37,7 +37,7 @@ from repro.serve import (
 )
 
 HP = SSAHyperParams(n_trials=3, m_shot=6, tau=4, i0_min=1, i0_max=8)
-BACKENDS = ("sparse", "dense", "pallas")
+BACKEND_NAMES = ("sparse", "dense", "pallas")
 
 
 def _problems():
@@ -59,13 +59,13 @@ def _assert_bit_identical(a, b):
 @pytest.fixture(scope="module")
 def baselines():
     return {b: AnnealService(backend=b, min_bucket=16).solve(_requests())
-            for b in BACKENDS}
+            for b in BACKEND_NAMES}
 
 
 # ---------------------------------------------------------------------------
 # Checkpoint / resume
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKEND_NAMES)
 def test_kill_mid_solve_resumes_bit_identical(backend, baselines, tmp_path):
     pol = ResiliencePolicy(checkpoint_dir=str(tmp_path))
     inj = FaultInjector()

@@ -12,14 +12,23 @@ import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core import SSAHyperParams, anneal, fig4_example, gset, make_backend
+from repro.core import (
+    SSAHyperParams,
+    anneal,
+    fig4_example,
+    gset,
+    make_batched_backend,
+)
 from repro.core.engine import (
+    BATCHED_BACKENDS,
+    BatchedDenseBackend,
     Plateau,
     schedule_plateaus,
+    single_problem_backend,
     tile_plateaus,
 )
 
-BACKENDS = ["sparse", "dense", "pallas"]
+BACKEND_NAMES = ["sparse", "dense", "pallas"]
 
 
 def _gset_twin():
@@ -89,7 +98,7 @@ def test_backend_equivalence_property(seed):
     hp = SSAHyperParams(n_trials=2, m_shot=2, tau=3, i0_min=1, i0_max=4)
     runs = [
         anneal(p, hp, seed=seed, record="traj", noise="xorshift", backend=b)
-        for b in BACKENDS
+        for b in BACKEND_NAMES
     ]
     for other in runs[1:]:
         np.testing.assert_array_equal(runs[0].traj, other.traj)
@@ -131,32 +140,34 @@ def test_pallas_backend_one_call_per_plateau():
     p = _gset_twin()
     model = p.to_ising()
     hp = SSAHyperParams(n_trials=2, m_shot=3, tau=4, i0_min=1, i0_max=8)
-    bk = make_backend("pallas", model, n_trials=hp.n_trials, n_rnd=hp.n_rnd,
-                      noise="xorshift")
-    state = bk.init_state(0)
+    bk, problem = single_problem_backend(
+        model, "pallas", n_trials=hp.n_trials, n_rnd=hp.n_rnd,
+        noise="xorshift",
+    )
+    state = bk.init_state(problem, bk.init_noise([0], [model.n]))
 
     jaxpr = jax.make_jaxpr(
-        lambda st: bk.run_plateau(st, 8, length=hp.tau, eligible=True)[0]
+        lambda st: bk.run_plateau(problem, st, 8, length=hp.tau, eligible=True)
     )(state)
     assert _count_primitive(jaxpr.jaxpr, "pallas_call") == 1
 
-    from repro.core.engine import run_schedule, schedule_plateaus
-
     plateaus = schedule_plateaus(hp.schedule("hassa"), "i0max")
     jaxpr = jax.make_jaxpr(
-        lambda st: run_schedule(bk, plateaus, st, record="best")[0]
+        lambda st: bk.run_shots(problem, st, plateaus, 1)
     )(state)
     assert _count_primitive(jaxpr.jaxpr, "pallas_call") == len(plateaus) == hp.steps
 
 
 def test_backend_factory_accepts_instances_and_classes():
-    from repro.core.engine import DenseBackend
-
+    """Every backend name resolves to its class; an unknown name raises."""
+    for name, cls in BATCHED_BACKENDS.items():
+        bk = make_batched_backend(name, n_bucket=8, n_trials=2)
+        assert type(bk) is cls and bk.name == name
     model = fig4_example().to_ising()
-    bk = make_backend("dense", model, n_trials=2)
-    assert isinstance(bk, DenseBackend)
-    assert make_backend(bk, model, n_trials=2) is bk
-    bk2 = make_backend(DenseBackend, model, n_trials=2)
-    assert isinstance(bk2, DenseBackend)
+    bk, _ = single_problem_backend(model, "dense", n_trials=2)
+    assert isinstance(bk, BatchedDenseBackend) and bk.n_bucket == model.n
     with pytest.raises(ValueError):
-        make_backend("no-such-backend", model, n_trials=2)
+        make_batched_backend("no-such-backend", n_bucket=8, n_trials=2)
+    with pytest.raises(ValueError):  # a name, never an instance
+        anneal(fig4_example(), SSAHyperParams(n_trials=2, m_shot=1),
+               backend=bk)

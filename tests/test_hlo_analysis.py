@@ -82,19 +82,21 @@ def test_plateau_cycle_has_one_contraction(track_energy):
     one inside the cycle loop — i.e. one per cycle — plus one epilogue for
     the plateau's final state.  The seed's record='best' scan evaluated the
     field twice per cycle; this pins the fix per backend."""
-    from repro.core import gset, make_backend
+    from repro.core import gset
+    from repro.core.engine import Plateau, single_problem_backend
 
     model = gset.toroidal_grid(64, seed=17).to_ising()
     counts = {"dense": "dot", "sparse": "gather"}
     for kind, op in counts.items():
-        bk = make_backend(kind, model, n_trials=4, noise="xorshift")
-        state = bk.init_state(0)
+        bk, problem = single_problem_backend(model, kind, n_trials=4,
+                                             noise="xorshift")
+        state = bk.init_state(problem, bk.init_noise([0], [model.n]))
         f = jax.jit(
-            lambda st, bk=bk: bk.run_plateau(
-                st, 8, length=16, eligible=True, track_energy=track_energy
+            lambda pr, st, bk=bk: bk.run_plateau_traced(
+                pr, st, Plateau(8, 16, True), track_energy=track_energy
             )[0]
         )
-        hlo = f.lower(state).compile().as_text()
+        hlo = f.lower(problem, state).compile().as_text()
         assert count_hlo_ops(hlo, op) == 2, (kind, op)
         # and the dense loop uses no gathers / the sparse loop no dots
         other = "gather" if op == "dot" else "dot"

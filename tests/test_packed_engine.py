@@ -17,9 +17,9 @@ from hypothesis import strategies as st
 from repro.core import SSAHyperParams, anneal, gset
 from repro.core.engine import (
     PackedEngineState,
-    make_backend,
     make_batched_backend,
     resolve_j_mode,
+    single_problem_backend,
 )
 from repro.core.ising import (
     local_fields_dense,
@@ -28,7 +28,7 @@ from repro.core.ising import (
 )
 
 HP = SSAHyperParams(n_trials=3, m_shot=2, tau=4, i0_min=1, i0_max=8)
-BACKENDS = ["sparse", "dense", "pallas"]
+BACKEND_NAMES = ["sparse", "dense", "pallas"]
 
 
 def _problem():
@@ -36,10 +36,16 @@ def _problem():
     return gset.toroidal_grid(50, seed=17)
 
 
+def _one(model, backend, seed=0, **kw):
+    """``(bk, problem, state)``: ``model`` as lane 0 of a B=1 batch."""
+    bk, problem = single_problem_backend(model, backend, **kw)
+    return bk, problem, bk.init_state(problem, bk.init_noise([seed], [model.n]))
+
+
 # ---------------------------------------------------------------------------
 # The acceptance property: packed ≡ dense, all backends × storage policies
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKEND_NAMES)
 @pytest.mark.parametrize("storage", ["i0max", "all"])
 def test_packed_bitwise_equal_to_dense(backend, storage):
     p = _problem()
@@ -60,7 +66,7 @@ def test_packed_equivalence_property(seed):
     runs = [
         anneal(p, hp, seed=seed, record="best", noise="xorshift",
                backend=b, storage_layout=layout, track_energy=False)
-        for b in BACKENDS
+        for b in BACKEND_NAMES
         for layout in ("dense", "packed")
     ]
     for other in runs[1:]:
@@ -72,16 +78,15 @@ def test_packed_state_is_the_engine_carry():
     """storage_layout='packed' really stores bitplanes: the state between
     plateaus is a PackedEngineState with uint32 spin words."""
     model = _problem().to_ising()
-    bk = make_backend("sparse", model, n_trials=3, noise="xorshift",
-                      storage_layout="packed")
-    st = bk.init_state(0)
+    bk, problem, st = _one(model, "sparse", n_trials=3, noise="xorshift",
+                           storage_layout="packed")
     assert isinstance(st, PackedEngineState)
     assert st.m_packed.dtype == jnp.uint32
-    assert st.m_packed.shape == (3, (model.n + 31) // 32)
-    st2, _, _ = bk.run_plateau(st, 4, length=3, eligible=True)
+    assert st.m_packed.shape == (1, 3, (model.n + 31) // 32)
+    st2 = bk.run_plateau(problem, st, 4, length=3, eligible=True)
     assert isinstance(st2, PackedEngineState)
     bh, bm = bk.finalize(st2)
-    assert bm.shape == (3, model.n) and bm.dtype == jnp.int8
+    assert bm.shape == (1, 3, model.n) and bm.dtype == jnp.int8
 
 
 # ---------------------------------------------------------------------------
@@ -103,22 +108,27 @@ def _collect_avals(jaxpr, out):
     return out
 
 
+def _is_noise_buffer(aval, length, n_trials, n):
+    """A per-plateau int8 noise buffer of one problem lane: (C, 1, T, N) as
+    drawn, or (1, C, T, N) as the pregen kernel takes it."""
+    return (getattr(aval, "dtype", None) == jnp.int8
+            and getattr(aval, "shape", None) in (
+                (length, 1, n_trials, n), (1, length, n_trials, n)))
+
+
 def test_xorshift_pallas_plateau_has_no_noise_buffer():
     """The legacy datapath pregenerated (C, T, N) int8 noise per plateau;
     the streamed kernel must not materialize it at any nesting level."""
     model = _problem().to_ising()
     length = 7
-    bk = make_backend("pallas", model, n_trials=3, noise="xorshift")
-    state = bk.init_state(0)
+    bk, problem, state = _one(model, "pallas", n_trials=3, noise="xorshift")
     jaxpr = jax.make_jaxpr(
-        lambda st: bk.run_plateau(st, 8, length=length, eligible=True)[0]
+        lambda st: bk.run_plateau(problem, st, 8, length=length, eligible=True)
     )(state)
     avals = _collect_avals(jaxpr.jaxpr, [])
-    noise_shape = (length, bk.n_trials, model.n)
-    assert not any(
-        getattr(a, "shape", None) == noise_shape and a.dtype == jnp.int8
-        for a in avals
-    ), "found a (C, T, N) int8 noise buffer in the streamed plateau program"
+    assert not any(_is_noise_buffer(a, length, bk.n_trials, model.n)
+                   for a in avals), (
+        "found a (C, T, N) int8 noise buffer in the streamed plateau program")
 
 
 def test_threefry_pallas_still_pregenerates():
@@ -126,17 +136,13 @@ def test_threefry_pallas_still_pregenerates():
     in-kernel, so its plateau program still carries the (C, T, N) buffer."""
     model = _problem().to_ising()
     length = 7
-    bk = make_backend("pallas", model, n_trials=3, noise="threefry")
-    state = bk.init_state(0)
+    bk, problem, state = _one(model, "pallas", n_trials=3, noise="threefry")
     jaxpr = jax.make_jaxpr(
-        lambda st: bk.run_plateau(st, 8, length=length, eligible=True)[0]
+        lambda st: bk.run_plateau(problem, st, 8, length=length, eligible=True)
     )(state)
     avals = _collect_avals(jaxpr.jaxpr, [])
-    noise_shape = (length, bk.n_trials, model.n)
-    assert any(
-        getattr(a, "shape", None) == noise_shape and a.dtype == jnp.int8
-        for a in avals
-    )
+    assert any(_is_noise_buffer(a, length, bk.n_trials, model.n)
+               for a in avals)
 
 
 def test_pregen_noise_mode_is_bit_identical_and_materializes_buffer():
@@ -147,19 +153,15 @@ def test_pregen_noise_mode_is_bit_identical_and_materializes_buffer():
     p = _problem()
     model = p.to_ising()
     length = 7
-    bk = make_backend("pallas", model, n_trials=3, noise="xorshift",
-                      noise_mode="pregen")
+    bk, problem, state = _one(model, "pallas", n_trials=3, noise="xorshift",
+                              noise_mode="pregen")
     assert bk.noise_mode == "pregen"
-    state = bk.init_state(0)
     jaxpr = jax.make_jaxpr(
-        lambda st: bk.run_plateau(st, 8, length=length, eligible=True)[0]
+        lambda st: bk.run_plateau(problem, st, 8, length=length, eligible=True)
     )(state)
     avals = _collect_avals(jaxpr.jaxpr, [])
-    noise_shape = (length, bk.n_trials, model.n)
-    assert any(
-        getattr(a, "shape", None) == noise_shape and a.dtype == jnp.int8
-        for a in avals
-    )
+    assert any(_is_noise_buffer(a, length, bk.n_trials, model.n)
+               for a in avals)
     kw = dict(seed=3, record="best", noise="xorshift", track_energy=False)
     ref = anneal(p, HP, backend="pallas", **kw)
     out = anneal(p, HP, backend="pallas",
@@ -167,8 +169,8 @@ def test_pregen_noise_mode_is_bit_identical_and_materializes_buffer():
     np.testing.assert_array_equal(ref.best_energy, out.best_energy)
     np.testing.assert_array_equal(ref.best_m, out.best_m)
     with pytest.raises(ValueError, match="streamed"):
-        make_backend("pallas", model, n_trials=3, noise="threefry",
-                     noise_mode="streamed")
+        single_problem_backend(model, "pallas", n_trials=3, noise="threefry",
+                               noise_mode="streamed")
 
 
 def test_streamed_kernel_advances_the_same_rng_stream():
@@ -178,13 +180,13 @@ def test_streamed_kernel_advances_the_same_rng_stream():
 
     model = _problem().to_ising()
     length = 5
-    bk = make_backend("pallas", model, n_trials=2, noise="xorshift")
-    state = bk.init_state(0)
-    st2, _, _ = bk.run_plateau(state, 4, length=length, eligible=True)
-    ns = state.noise_state
+    bk, problem, state = _one(model, "pallas", n_trials=2, noise="xorshift")
+    st2 = bk.run_plateau(problem, state, 4, length=length, eligible=True)
+    ns = state.noise_state[0]
     for _ in range(length):
         ns, _ = xorshift_next_bits(ns)
-    np.testing.assert_array_equal(np.asarray(st2.noise_state), np.asarray(ns))
+    np.testing.assert_array_equal(np.asarray(st2.noise_state[0]),
+                                  np.asarray(ns))
 
 
 # ---------------------------------------------------------------------------
@@ -228,10 +230,10 @@ def test_j_mode_auto_threshold():
 def test_tiled_backend_never_materializes_dense_J():
     """Above the threshold the dense backend holds adjacency, not (N, N)."""
     model = _problem().to_ising()
-    bk = make_backend("dense", model, n_trials=2, noise="xorshift",
-                      j_mode="tiled")
-    assert not hasattr(bk, "J")
-    assert bk.nbr_idx.shape == (model.n, model.max_degree)
+    bk, problem = single_problem_backend(model, "dense", n_trials=2,
+                                         noise="xorshift", j_mode="tiled")
+    assert bk.j_mode == "tiled" and "J" not in problem
+    assert problem["nbr_idx"].shape == (1, model.n, model.max_degree)
     bkb = make_batched_backend("dense", n_bucket=64, n_trials=2,
                                noise="xorshift", j_mode="tiled")
     stacked = bkb.stack([model])
@@ -241,7 +243,7 @@ def test_tiled_backend_never_materializes_dense_J():
 # ---------------------------------------------------------------------------
 # The service: packed layout + tiled J end-to-end (the G77 path, scaled down)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKEND_NAMES)
 def test_service_packed_bit_identical_to_unpadded_runs(backend):
     from repro.serve import AnnealRequest, AnnealService
 

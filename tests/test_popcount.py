@@ -17,13 +17,12 @@ from repro.core import SSAHyperParams, anneal, gset
 from repro.core.engine import (
     MIN_RESIDENT_N,
     POPCOUNT_AUTO_MAX_BITS,
-    make_backend,
     make_batched_backend,
     model_weight_bits,
     resolve_backend,
     resolve_field_mode,
-    run_schedule,
     schedule_plateaus,
+    single_problem_backend,
 )
 from repro.core.ising import local_fields_popcount
 from repro.kernels.bitplane import (
@@ -178,11 +177,13 @@ def test_popcount_field_path_has_no_float_values():
 
 def test_dense_backend_popcount_materializes_no_J():
     m = _torus().to_ising()
-    bk = make_backend("dense", m, n_trials=2, noise="xorshift",
-                      field_mode="popcount")
+    bk, problem = single_problem_backend(m, "dense", n_trials=2,
+                                         noise="xorshift",
+                                         field_mode="popcount")
     assert bk.field_mode == "popcount"
-    assert not hasattr(bk, "J")
-    assert isinstance(bk.packed_j, PackedJ)
+    assert "J" not in problem
+    pj = PackedJ(problem["sign"][0], problem["mags"][0], problem["base"][0])
+    assert pj.sign.dtype == jnp.uint32 and pj.mags.shape[0] == bk.j_bits
 
 
 # ---------------------------------------------------------------------------
@@ -243,28 +244,30 @@ def _count_primitive(jaxpr, name):
 
 def test_popcount_chain_is_one_resident_launch():
     m = _torus().to_ising()
-    bk = make_backend("pallas", m, n_trials=2, n_rnd=HP.n_rnd,
-                      noise="xorshift", field_mode="popcount")
+    bk, problem = single_problem_backend(m, "pallas", n_trials=2,
+                                         n_rnd=HP.n_rnd, noise="xorshift",
+                                         field_mode="popcount")
     plateaus = schedule_plateaus(HP.schedule("hassa"), "i0max")
     assert len(plateaus) > 1
-    state = bk.init_state(0)
+    state = bk.init_state(problem, bk.init_noise([0], [m.n]))
     jaxpr = jax.make_jaxpr(
-        lambda s: run_schedule(bk, plateaus, s, record="best")[0]
+        lambda s: bk.run_shots(problem, s, plateaus, 1)
     )(state)
     assert _count_primitive(jaxpr.jaxpr, "pallas_call") == 1
 
 
 def test_popcount_run_plateaus_equals_chained_run_plateau():
     m = _king().to_ising()
-    bk = make_backend("pallas", m, n_trials=2, n_rnd=HP.n_rnd,
-                      noise="xorshift", field_mode="popcount")
+    bk, problem = single_problem_backend(m, "pallas", n_trials=2,
+                                         n_rnd=HP.n_rnd, noise="xorshift",
+                                         field_mode="popcount")
     plateaus = schedule_plateaus(HP.schedule("hassa"), "i0max")
-    st0 = bk.init_state(0)
-    whole = bk.run_plateaus(st0, plateaus)
+    st0 = bk.init_state(problem, bk.init_noise([0], [m.n]))
+    whole = bk.run_shots(problem, st0, plateaus, 1)
     chained = st0
     for p in plateaus:
-        chained, _, _ = bk.run_plateau(chained, p.i0, length=p.length,
-                                       eligible=p.eligible)
+        chained = bk.run_plateau(problem, chained, p.i0, length=p.length,
+                                 eligible=p.eligible)
     for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(chained)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -272,8 +275,8 @@ def test_popcount_run_plateaus_equals_chained_run_plateau():
 def test_pallas_popcount_requires_streamed_noise():
     m = _torus().to_ising()
     with pytest.raises(ValueError, match="streamed"):
-        make_backend("pallas", m, n_trials=2, noise="threefry",
-                     field_mode="popcount")
+        single_problem_backend(m, "pallas", n_trials=2, noise="threefry",
+                               field_mode="popcount")
 
 
 # ---------------------------------------------------------------------------
@@ -334,7 +337,7 @@ def test_resolve_backend_min_resident_n():
 
 def test_make_backend_auto_routes_small_n_to_dense():
     m = _torus().to_ising()  # 50 spins < MIN_RESIDENT_N
-    bk = make_backend("auto", m, n_trials=2, noise="xorshift")
+    bk, _ = single_problem_backend(m, "auto", n_trials=2, noise="xorshift")
     assert bk.name == "dense"
 
 

@@ -104,7 +104,7 @@ def test_engine_opts_gated_by_backend_family():
 
 def test_partition_and_mesh_hoisted_out_of_backend_opts():
     # PR-8 spelling: partition/mesh rode inside backend_opts.  They must be
-    # hoisted into the typed fields (so make_backend never sees them twice)
+    # hoisted into the typed fields (so a backend never sees them twice)
     # and never linger in backend_opts/engine_opts.
     cfg = SolverConfig(backend_opts={"partition": "spin", "tile_n": 64})
     assert cfg.partition == "spin"

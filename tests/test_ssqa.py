@@ -3,9 +3,9 @@
 Contracts under test:
 
 * the Trotter-replica ring coupling is backend-invariant: sparse, dense,
-  pallas (streamed noise), pallas XNOR-popcount and packed-storage runs
-  produce bit-identical best states, single-problem and batched (including
-  spin-sharded);
+  pallas (streamed and pregen noise), pallas XNOR-popcount and
+  packed-storage runs produce bit-identical best states, single-problem
+  and batched (including spin-sharded);
 * classical runs are untouched: a backend built with ``n_replicas`` set
   executes jperp-free schedules bit-identically to a classical backend;
 * the J⊥ ramp rides the schedule and is visible to ``Schedule.signature()``
@@ -21,14 +21,11 @@ import pytest
 from repro.core import SSAHyperParams, anneal, gset
 from repro.core.autotune import resolve_hyperparams
 from repro.core.engine import (
-    DenseBackend,
-    PallasBackend,
-    SparseBackend,
     bucket_n,
     make_batched_backend,
     replica_coupling,
-    run_schedule,
     schedule_plateaus,
+    single_problem_backend,
 )
 from repro.core.schedule import hassa_schedule, ssqa_schedule
 from repro.core.ssqa import SSQAHyperParams, anneal_ssqa
@@ -39,30 +36,34 @@ MODEL = TORUS.to_ising()
 SCHED = ssqa_schedule(1, 8, tau=4, jperp_max=3)
 PLATEAUS = schedule_plateaus(SCHED, "i0max")
 
+def _single(backend, **opts):
+    """One problem as lane 0 of a B=1 batch (what ``anneal`` runs)."""
+    return lambda: single_problem_backend(
+        MODEL, backend, n_trials=T, n_rnd=2, noise="xorshift", **opts)
+
+
 SINGLE_BACKENDS = {
-    "sparse": lambda: SparseBackend(
-        MODEL, n_trials=T, n_rnd=2, noise="xorshift", n_replicas=R),
-    "dense": lambda: DenseBackend(
-        MODEL, n_trials=T, n_rnd=2, noise="xorshift", n_replicas=R),
-    "pallas": lambda: PallasBackend(
-        MODEL, n_trials=T, n_rnd=2, noise="xorshift",
-        noise_mode="streamed", n_replicas=R),
-    "pallas-popcount": lambda: PallasBackend(
-        MODEL, n_trials=T, n_rnd=2, noise="xorshift",
-        noise_mode="streamed", field_mode="popcount", n_replicas=R),
-    "sparse-packed": lambda: SparseBackend(
-        MODEL, n_trials=T, n_rnd=2, noise="xorshift",
-        storage_layout="packed", n_replicas=R),
+    "sparse": _single("sparse", n_replicas=R),
+    "dense": _single("dense", n_replicas=R),
+    "pallas": _single("pallas", noise_mode="streamed", n_replicas=R),
+    "pallas-popcount": _single("pallas", noise_mode="streamed",
+                               field_mode="popcount", n_replicas=R),
+    # the pregen kernel has no ring: coupled plateaus run the scan path
+    "pallas-pregen": _single("pallas", noise_mode="pregen", n_replicas=R),
+    "sparse-packed": _single("sparse", storage_layout="packed",
+                             n_replicas=R),
 }
 
 
-def _run_single(mk):
-    bk = mk()
-    st = bk.init_state(seed=7)
-    for _ in range(3):
-        st, _, _ = run_schedule(bk, PLATEAUS, st)
+def _run(bk, problem, plateaus, seed=7, iters=3):
+    st = bk.init_state(problem, bk.init_noise([seed], [MODEL.n]))
+    st = bk.run_shots(problem, st, plateaus, iters)
     bh, bm = bk.finalize(st)
-    return np.asarray(bh), np.asarray(bm)
+    return np.asarray(bh[0]), np.asarray(bm[0])
+
+
+def _run_single(mk):
+    return _run(*mk(), PLATEAUS)
 
 
 # ---------------------------------------------------------------------------
@@ -111,26 +112,16 @@ def test_classical_schedule_unchanged_by_replica_backend():
     """jperp=0 disables the coupling entirely: a backend carrying
     n_replicas runs classical plateau programs bit-identically."""
     cplat = schedule_plateaus(hassa_schedule(1, 8, tau=4), "i0max")
-    bk0 = SparseBackend(MODEL, n_trials=T, n_rnd=2, noise="xorshift")
-    bkr = SparseBackend(MODEL, n_trials=T, n_rnd=2, noise="xorshift",
-                        n_replicas=R)
-    s0, sr = bk0.init_state(seed=7), bkr.init_state(seed=7)
-    s0, _, _ = run_schedule(bk0, cplat, s0)
-    sr, _, _ = run_schedule(bkr, cplat, sr)
-    np.testing.assert_array_equal(np.asarray(bk0.finalize(s0)[0]),
-                                  np.asarray(bkr.finalize(sr)[0]))
-    np.testing.assert_array_equal(np.asarray(bk0.finalize(s0)[1]),
-                                  np.asarray(bkr.finalize(sr)[1]))
+    h0, m0 = _run(*_single("sparse")(), cplat, iters=1)
+    hr, mr = _run(*_single("sparse", n_replicas=R)(), cplat, iters=1)
+    np.testing.assert_array_equal(h0, hr)
+    np.testing.assert_array_equal(m0, mr)
 
 
 def test_coupling_changes_the_dynamics():
     """Sanity: on the coupled schedule SSQA is not SSA in disguise."""
     bh_q, _ = _run_single(SINGLE_BACKENDS["sparse"])
-    bk = SparseBackend(MODEL, n_trials=T, n_rnd=2, noise="xorshift")
-    st = bk.init_state(seed=7)
-    for _ in range(3):
-        st, _, _ = run_schedule(bk, PLATEAUS, st)
-    bh_c = np.asarray(bk.finalize(st)[0])
+    bh_c, _ = _run(*_single("sparse")(), PLATEAUS)
     assert not np.array_equal(bh_q, bh_c)
 
 

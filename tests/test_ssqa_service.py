@@ -30,7 +30,7 @@ from repro.serve.resilience import group_fingerprint
 
 HP = SSQAHyperParams(n_trials=8, n_replicas=4, m_shot=3, tau=4,
                      i0_min=1, i0_max=8)
-BACKENDS = ["sparse", "dense", "pallas"]
+BACKEND_NAMES = ["sparse", "dense", "pallas"]
 
 
 def _problems():
@@ -46,7 +46,7 @@ def _requests(**kw):
 # ---------------------------------------------------------------------------
 # Service: backend-invariant, matches the single-problem driver
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKEND_NAMES)
 def test_service_matches_driver(backend):
     svc = AnnealService(backend=backend, min_bucket=16)
     responses = svc.solve(_requests())

@@ -29,7 +29,7 @@ from repro.serve import (
 )
 
 HP = SSAHyperParams(n_trials=3, m_shot=4, tau=4, i0_min=1, i0_max=8)
-BACKENDS = ("sparse", "dense", "pallas")
+BACKEND_NAMES = ("sparse", "dense", "pallas")
 
 
 def _requests(k=6, **kw):
@@ -48,7 +48,7 @@ def _assert_lane_identical(resp, base):
 def baselines():
     """One-shot solo solves: the bit-identity reference, per backend."""
     out = {}
-    for b in BACKENDS:
+    for b in BACKEND_NAMES:
         svc = AnnealService(backend=b, min_bucket=16)
         out[b] = [svc.solve([r])[0] for r in _requests()]
     return out
@@ -57,7 +57,7 @@ def baselines():
 # ---------------------------------------------------------------------------
 # Live-lane bit-identity across slot backfill (the acceptance property)
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend", BACKEND_NAMES)
 def test_stream_bit_identical_across_backfill(backend, baselines):
     """6 requests through a 2-slot table = 3 backfill generations; every
     lane must match its one-shot solo solve bit for bit."""

@@ -12,10 +12,10 @@ import pytest
 
 from repro.core import SSAHyperParams, gset
 from repro.core.engine import (
-    make_backend,
     make_batched_backend,
     pallas_vmem_shortfall,
     resolve_backend,
+    single_problem_backend,
 )
 from repro.kernels import ssa_update
 from repro.kernels.ssa_update import (
@@ -72,9 +72,11 @@ def test_explicit_pallas_over_budget_raises_before_building():
 def test_explicit_single_problem_pallas_over_budget_raises(small_vmem):
     model = gset.toroidal_grid(512, seed=3).to_ising()
     with pytest.raises(VmemBudgetError, match="budget"):
-        make_backend("pallas", model, n_trials=8, noise="xorshift")
-    assert make_backend("auto", model, n_trials=8,
-                        noise="xorshift").name == "dense"
+        single_problem_backend(model, "pallas", n_trials=8,
+                               noise="xorshift")
+    bk, _ = single_problem_backend(model, "auto", n_trials=8,
+                                   noise="xorshift")
+    assert bk.name == "dense"
 
 
 def test_service_pallas_over_budget_is_not_a_fallback(small_vmem):
