@@ -48,6 +48,7 @@ from .engine import (
     TILED_J_THRESHOLD,
     _stack_packed_models,
     _stack_sparse_models,
+    model_couplings,
     pack_spins,
     resolve_backend,
     resolve_field_mode,
@@ -454,9 +455,10 @@ class BatchedSpinShardedBackend(BatchedBackend):
         return type(st)(*(put(x, s) for x, s in zip(st, self._state_specs())))
 
     # -- host side --------------------------------------------------------
-    def stack(self, models) -> dict:
+    def stack(self, models, couplings=model_couplings) -> dict:
         if self.field_style == "popcount":
-            problem = _stack_packed_models(models, self.n_bucket, self.j_bits)
+            problem = _stack_packed_models(models, self.n_bucket, self.j_bits,
+                                           couplings)
         else:
             problem = _stack_sparse_models(models, self.n_bucket)
         specs = self._problem_specs()

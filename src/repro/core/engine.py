@@ -101,6 +101,7 @@ __all__ = [
     "spin_axis_size",
     "SPIN_SHARD_MIN_N",
     "MAX_UNSHARDED_SPINS",
+    "model_couplings",
     "model_weight_bits",
     "plateau_cycle_schedules",
     "normalize_problem",
@@ -157,18 +158,25 @@ POPCOUNT_AUTO_MAX_BITS = 4
 # layout; re-exported here for the core-level callers.
 # ---------------------------------------------------------------------------
 from repro.kernels.bitplane import (  # noqa: E402
+    Coalesced,
     PackedJ,
-    adjacency_planes,
-    adjacency_weight_bits,
+    coalesce_adjacency,
+    coalesced_planes,
     pack_spins,
     packed_words,
     unpack_spins,
 )
 
 
+def model_couplings(model: IsingModel) -> Coalesced:
+    """A model's coalesced couplings: the one pass that both its weight-bit
+    count and its bitplanes are read from."""
+    return coalesce_adjacency(model.n, model.nbr_idx, model.nbr_w)
+
+
 def model_weight_bits(model: IsingModel) -> int:
     """Magnitude bitplanes a model's couplings need (coalesced max |J_ij|)."""
-    return adjacency_weight_bits(model.n, model.nbr_idx, model.nbr_w)
+    return model_couplings(model).weight_bits
 
 
 # ---------------------------------------------------------------------------
@@ -924,8 +932,14 @@ class BatchedBackend:
         self._noise_step = jax.vmap(self._noise_step_one)
 
     # -- host side --------------------------------------------------------
-    def stack(self, models: Sequence[IsingModel]) -> dict:
-        """Pad each model to the bucket and stack its arrays over axis 0."""
+    def stack(self, models: Sequence[IsingModel],
+              couplings=model_couplings) -> dict:
+        """Pad each model to the bucket and stack its arrays over axis 0.
+
+        A layout packed from J's bitplanes reads each model's
+        ``couplings(model)`` (:func:`model_couplings` unless the caller
+        already holds them); the others read the adjacency.
+        """
         raise NotImplementedError
 
     def init_noise(self, seeds: Sequence[int], n_lives: Sequence[int]):
@@ -1171,7 +1185,7 @@ class BatchedSparseBackend(_VmapBatchedBackend):
 
     name = "sparse"
 
-    def stack(self, models):
+    def stack(self, models, couplings=model_couplings):
         return _stack_sparse_models(models, self.n_bucket)
 
     def _field_one(self, prob, m):
@@ -1199,24 +1213,28 @@ def _stack_dense_models(models, n_bucket: int, j_dtype) -> dict:
             "J": jnp.stack([Js[k] for k in lanes])}
 
 
-def _stack_packed_models(models, n_bucket: int, j_bits: int) -> dict:
+def _stack_packed_models(models, n_bucket: int, j_bits: int,
+                         couplings=model_couplings) -> dict:
     """Stacked, bucket-padded PackedJ views {h, sign, mags, base}.
 
     ``j_bits`` forces the magnitude-plane count for *every* model so the
     stacked ``mags`` tensor has one uniform shape (a program-structural
     parameter — the executable cache keys on it); callers pass the group
-    maximum from :func:`repro.kernels.bitplane.adjacency_weight_bits`.
-    Each distinct model is packed once on the host; the (B, ...) arrays
-    index those packings by lane and move to the device in one transfer
-    each.
+    maximum of :func:`model_weight_bits`.  Each distinct model is packed
+    once on the host, from ``couplings(model)`` (the service passes the
+    coalescing its weight-bit scan already made; padding rows carry no
+    coupling, so the unpadded model's is the padded model's); the (B, ...)
+    arrays index those packings by lane and move to the device in one
+    transfer each.
     """
     distinct, lanes = _distinct(models)
     planes = []   # per distinct model: (h, sign, mags, base) host arrays
     for m in distinct:
-        p = pad_model(m, n_bucket)
-        planes.append((np.asarray(p.h, np.int32),
-                       *adjacency_planes(p.n, p.nbr_idx, p.nbr_w,
-                                         n_bits=j_bits)))
+        sign, mags, base = coalesced_planes(couplings(m), n_bucket,
+                                            n_bits=j_bits)
+        h = np.zeros(n_bucket, np.int32)
+        h[:m.n] = m.h
+        planes.append((h, sign, mags, base))
     return {key: jnp.asarray(np.stack([planes[k][i] for k in lanes]))
             for i, key in enumerate(("h", "sign", "mags", "base"))}
 
@@ -1253,9 +1271,10 @@ class BatchedDenseBackend(_VmapBatchedBackend):
                           if self.field_mode == "popcount"
                           else self.j_mode == "tiled")
 
-    def stack(self, models):
+    def stack(self, models, couplings=model_couplings):
         if self.field_mode == "popcount":
-            return _stack_packed_models(models, self.n_bucket, self.j_bits)
+            return _stack_packed_models(models, self.n_bucket, self.j_bits,
+                                        couplings)
         if self.j_mode == "tiled":
             return _stack_sparse_models(models, self.n_bucket)
         return _stack_dense_models(models, self.n_bucket, self.j_dtype)
@@ -1329,9 +1348,10 @@ class BatchedPallasBackend(BatchedBackend):
             block_r=self.block_r, n_replicas=self.n_replicas, j_dtype=j_dtype,
         )
 
-    def stack(self, models):
+    def stack(self, models, couplings=model_couplings):
         if self.field_mode == "popcount":
-            return _stack_packed_models(models, self.n_bucket, self.j_bits)
+            return _stack_packed_models(models, self.n_bucket, self.j_bits,
+                                        couplings)
         return _stack_dense_models(models, self.n_bucket, self.j_dtype)
 
     def _field_one(self, prob, m):

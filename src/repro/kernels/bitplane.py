@@ -39,7 +39,7 @@ identity per coupling plane is
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +54,9 @@ __all__ = [
     "PackedJ",
     "pack_couplings",
     "pack_couplings_from_adjacency",
+    "Coalesced",
+    "coalesce_adjacency",
+    "coalesced_planes",
     "adjacency_planes",
     "adjacency_weight_bits",
     "packed_j_nbytes",
@@ -205,19 +208,81 @@ def pack_couplings(J: np.ndarray, n_bits=None) -> PackedJ:
     return PackedJ(jnp.asarray(sign), jnp.asarray(mags), jnp.asarray(base))
 
 
-def _coalesced_adjacency(n: int, nbr_idx, nbr_w):
-    """(rows, cols, weights) with duplicate (i, j) slots weight-summed."""
-    idx = np.asarray(nbr_idx, dtype=np.int64)
-    w = np.asarray(nbr_w, dtype=np.int64)
-    rows = np.broadcast_to(np.arange(n, dtype=np.int64)[:, None], idx.shape)
+# The dense route bins every cell of an n x n matrix; it is taken where those
+# n² cells are at most this many times the adjacency's n·max_deg slots.
+_DENSE_CELLS_PER_SLOT = 4
+
+
+def _narrowest_int(max_abs: int):
+    """The narrowest signed integer type that holds ±``max_abs``."""
+    return next(t for t in (np.int8, np.int16, np.int32, np.int64)
+                if max_abs <= np.iinfo(t).max)
+
+
+class Coalesced(NamedTuple):
+    """A model's couplings with duplicate (i, j) adjacency slots weight-summed
+    and zero sums dropped — J as ``IsingModel.dense_J`` has it.
+
+    :func:`coalesce_adjacency` fills one of two forms, by its route:
+    ``dense``, J as an (n, n) matrix (``rows``, ``cols``, ``wsum`` None); or
+    ``rows``, ``cols`` (int32) and ``wsum``, J's nonzero entries in
+    row-major order (``dense`` None).  Weights are held in the narrowest
+    signed integer type that holds ``max_abs``, max |J_ij|.
+    """
+
+    n: int
+    max_abs: int
+    dense: Optional[np.ndarray]
+    rows: Optional[np.ndarray]
+    cols: Optional[np.ndarray]
+    wsum: Optional[np.ndarray]
+
+    @property
+    def weight_bits(self) -> int:
+        """Magnitude bitplanes the couplings need (≥ 1)."""
+        return max(1, self.max_abs.bit_length())
+
+
+def coalesce_adjacency(n: int, nbr_idx, nbr_w) -> Coalesced:
+    """Sum the duplicate (i, j) slots of a padded adjacency, in one pass.
+
+    The route follows the shape; both give the same J:
+
+    * **dense**, where the n² cells are at most 4× the adjacency's slots
+      (K2000: 2048² cells for 2048×1999 slots): one ``np.bincount`` over the
+      flat ``row·n + col`` keys.  Its float64 sums are exact while the
+      total |weight| stays below 2^53, which is checked.
+    * **sorted**, for sparse instances (degree-4 G-set; G81 at bucket
+      32768, whose n² cells would be 8 GiB): one stable sort of the live
+      slots' keys and ``np.add.reduceat`` over runs of equal keys, in
+      int64.  No n² array is made.
+    """
+    n = int(n)
+    idx = np.asarray(nbr_idx)
+    w = np.asarray(nbr_w)
+    rows = np.arange(n, dtype=np.int64)[:, None]
+    if n * n <= _DENSE_CELLS_PER_SLOT * idx.size:
+        if np.abs(w, dtype=np.int64).sum() >= 1 << 53:
+            raise ValueError("coupling weights too large to sum exactly")
+        J = np.bincount((rows * n + idx).reshape(-1),
+                        weights=w.reshape(-1), minlength=n * n)
+        max_abs = int(max(-J.min(initial=0), J.max(initial=0)))
+        return Coalesced(n, max_abs,
+                         J.astype(_narrowest_int(max_abs)).reshape(n, n),
+                         None, None, None)
     live = w != 0
-    keys = rows[live] * n + idx[live]
-    uniq, inv = np.unique(keys, return_inverse=True)
-    wsum = np.zeros(uniq.shape[0], dtype=np.int64)
-    np.add.at(wsum, inv, w[live])
+    keys = np.broadcast_to(rows * n, idx.shape)[live] + idx[live]
+    order = np.argsort(keys, kind="stable")
+    keys = keys[order]
+    start = np.flatnonzero(np.diff(keys, prepend=-1))
+    wsum = (np.add.reduceat(w[live][order].astype(np.int64), start)
+            if start.size else np.zeros(0, np.int64))
     nz = wsum != 0
-    uniq, wsum = uniq[nz], wsum[nz]
-    return uniq // n, uniq % n, wsum
+    keys, wsum = keys[start][nz], wsum[nz]
+    max_abs = int(np.abs(wsum).max(initial=0))
+    return Coalesced(n, max_abs, None, (keys // n).astype(np.int32),
+                     (keys % n).astype(np.int32),
+                     wsum.astype(_narrowest_int(max_abs)))
 
 
 def adjacency_weight_bits(n: int, nbr_idx, nbr_w) -> int:
@@ -228,8 +293,7 @@ def adjacency_weight_bits(n: int, nbr_idx, nbr_w) -> int:
     count :func:`pack_couplings_from_adjacency` would produce.  This is the
     number `field_mode='auto'` compares against POPCOUNT_AUTO_MAX_BITS.
     """
-    _, _, wsum = _coalesced_adjacency(int(n), nbr_idx, nbr_w)
-    return max(1, int(np.abs(wsum).max(initial=0)).bit_length())
+    return coalesce_adjacency(n, nbr_idx, nbr_w).weight_bits
 
 
 def pack_couplings_from_adjacency(
@@ -239,8 +303,8 @@ def pack_couplings_from_adjacency(
 
     ``nbr_idx``/``nbr_w`` are the `IsingModel` padded neighbor lists
     (weight 0 = padding slot).  Duplicate (i, j) entries are weight-summed
-    first, matching ``IsingModel.dense_J``.  O(N·max_deg) host work — this
-    is the constructor the 20k-spin instances use.
+    first, matching ``IsingModel.dense_J``.  O(N·max_deg) host work on
+    sparse instances — this is the constructor the 20k-spin instances use.
     """
     return PackedJ(*(jnp.asarray(a) for a in
                      adjacency_planes(n, nbr_idx, nbr_w, n_bits)))
@@ -252,26 +316,56 @@ def adjacency_planes(n: int, nbr_idx: np.ndarray, nbr_w: np.ndarray,
     ``(sign uint32 (N, W), mags uint32 (n_bits, N, W), base int32 (N,))``,
     for callers that stack many problems on the host before one transfer.
     """
-    n = int(n)
-    nw = packed_words(n)
-    r, c, wsum = _coalesced_adjacency(n, nbr_idx, nbr_w)
-    word, bit = c // 32, (c % 32).astype(np.uint32)
+    return coalesced_planes(coalesce_adjacency(n, nbr_idx, nbr_w), n, n_bits)
 
-    mag = np.abs(wsum)
-    n_bits = _resolve_n_bits(mag.max(initial=0), n_bits)
-    sign = np.zeros((n, nw), np.uint32)
-    pos = wsum > 0
-    np.bitwise_or.at(
-        sign, (r[pos], word[pos]), np.uint32(1) << bit[pos]
-    )
-    mags = np.zeros((n_bits, n, nw), np.uint32)
-    base = np.zeros(n, np.int64)
-    for b in range(n_bits):
-        sel = ((mag >> b) & 1) == 1
-        np.bitwise_or.at(
-            mags[b], (r[sel], word[sel]), np.uint32(1) << bit[sel]
-        )
-        np.add.at(base, r[sel], -(np.int64(1) << b))
+
+def coalesced_planes(c: Coalesced, n: int, n_bits=None):
+    """:func:`adjacency_planes` of coalesced couplings, padded to ``n`` spins.
+
+    Each (i, j) is in ``c`` once, so its bit is set by a sum, not an OR:
+    the dense form packs 0/1 matrices with ``np.packbits``; the sorted form
+    sums each word's bits over the runs of its word index, which row-major
+    entries keep sorted.  ``base`` is −Σ_j |J_ij|: every plane bit of row i
+    weighted by 2^b.
+    """
+    n = int(n)
+    if c.n > n:
+        raise ValueError(f"couplings of {c.n} spins do not fit {n} spins")
+    nw = packed_words(n)
+    n_bits = _resolve_n_bits(c.max_abs, n_bits)
+    # Little-endian words, so a word's bytes are its columns' packed bytes
+    # in order; planes above max_abs stay 0.
+    sign = np.zeros((n, nw), "<u4")
+    mags = np.zeros((n_bits, n, nw), "<u4")
+    if c.dense is not None:
+        mag = np.abs(c.dense)
+
+        def fill(out, bits):  # (c.n, c.n) bool into the (n, nw) words
+            packed = np.packbits(bits, axis=1, bitorder="little")
+            out.view(np.uint8)[:c.n, :packed.shape[1]] = packed
+
+        fill(sign, c.dense > 0)
+        for b in range(c.max_abs.bit_length()):
+            fill(mags[b], (mag & (1 << b)) != 0)
+        base = np.zeros(n, np.int64)
+        base[:c.n] = -mag.sum(axis=1, dtype=np.int64)
+    else:
+        r, col, wsum = c.rows, c.cols, c.wsum
+        word = r.astype(np.int64) * nw + col // 32
+        bit = np.uint32(1) << (col % 32).astype(np.uint32)
+        mag = np.abs(wsum)
+
+        def fill(out, sel):  # the selected entries' bits into the words
+            ws = word[sel]
+            start = np.flatnonzero(np.diff(ws, prepend=-1))
+            if start.size:
+                out.reshape(-1)[ws[start]] = np.add.reduceat(
+                    bit[sel], start, dtype=np.uint32)
+
+        fill(sign, wsum > 0)
+        for b in range(c.max_abs.bit_length()):
+            fill(mags[b], (mag & (1 << b)) != 0)
+        base = -np.bincount(r, weights=mag, minlength=n)
     return sign, mags, base.astype(np.int32)
 
 

@@ -111,7 +111,7 @@ from repro.core.engine import (
     bucket_n,
     finalize_cut,
     make_batched_backend,
-    model_weight_bits,
+    model_couplings,
     next_pow2,
     normalize_problem,
     resolve_field_mode,
@@ -363,10 +363,10 @@ class _GroupCtx:
 
     def __init__(self, service: "AnnealService", kind: str, nb: int, items,
                  backend: str, backend_opts: dict, solve_t0: float,
-                 chunk: int, weight_bits,
+                 chunk: int, couplings,
                  events: Optional[List[ServiceEvent]] = None):
         self.kind = kind
-        self.weight_bits = weight_bits
+        self.couplings = couplings
         self.backend = backend
         self.backend_opts = dict(backend_opts)
         self.solve_t0 = solve_t0
@@ -585,7 +585,9 @@ class AnnealService:
         # Each distinct problem object is prepared once per call; the entry
         # holds the object, so its id is not reused while the call runs.
         prepared: dict = {}   # id(problem) -> (problem, maxcut, model)
-        weight_bits = _ByIdentity(model_weight_bits)
+        # Each distinct model's couplings are coalesced once per call: the
+        # weight-bit scans and the bitplane packing all read that one pass.
+        couplings = _ByIdentity(self._coalesce)
         for idx, req in enumerate(requests):
             seen = prepared.get(id(req.problem))
             with spans("normalize"):
@@ -621,7 +623,7 @@ class AnnealService:
         for key, items in sorted(groups.items(), key=lambda kv: repr(kv[0])):
             kind, nb = key[0], key[1]
             self._solve_group_resilient(kind, nb, items, responses, progress,
-                                        t_solve0, weight_bits=weight_bits)
+                                        t_solve0, couplings=couplings)
         with spans("decode"):
             for idx, resp in enumerate(responses):
                 resp.autotune = reports.get(idx)
@@ -717,7 +719,7 @@ class AnnealService:
         return fam.group_key(req, req.hp, nb) + (cfg_sig,)
 
     def _resolve_field_opts(self, backend: str, opts: dict, items,
-                            weight_bits=model_weight_bits) -> dict:
+                            couplings=model_couplings) -> dict:
         """Resolve field_mode='auto' + group ``j_bits`` for one request group.
 
         The popcount contraction's magnitude-plane count is program-
@@ -725,19 +727,28 @@ class AnnealService:
         uniform across the group: every model packs to the group maximum.
         The resolved values land in the opts dict — and therefore in the
         executable-cache key via ``_opts_key`` — so a ±1 group and a 3-bit
-        group never collide on one compiled program.  ``weight_bits`` is
-        the call's per-model scan (memoized by :meth:`solve`).
+        group never collide on one compiled program.  ``couplings`` gives
+        each model's coalesced couplings (memoized per call by :meth:`solve`).
         """
         if backend not in ("dense", "pallas") or "field_mode" not in opts:
             return dict(opts)
         opts = dict(opts)
-        jb = max(weight_bits(model) for _, _, _, model in items)
+        jb = max(couplings(model).weight_bits for *_, model in items)
         opts["field_mode"] = resolve_field_mode(opts["field_mode"], jb)
         if opts["field_mode"] == "popcount":
             opts["j_bits"] = max(jb, int(opts.get("j_bits", 1)))
         else:
             opts.pop("j_bits", None)
         return opts
+
+    def _coalesce(self, model):
+        """:func:`model_couplings`, counting in ``stats["coalesce_dense"]``
+        the models whose couplings took the dense route."""
+        c = model_couplings(model)
+        if c.dense is not None:
+            with self.spans.lock:
+                self.stats["coalesce_dense"] += 1
+        return c
 
     def _pad_group(self, items):
         """Pad a request group to a power-of-two batch (executable reuse).
@@ -758,7 +769,8 @@ class AnnealService:
         once it succeeds.
         """
         with self.spans("stack"):
-            stacked = bk.stack([model for _, _, _, model in padded])
+            stacked = bk.stack([model for _, _, _, model in padded],
+                               couplings=ctx.couplings)
         nbytes = sum(a.nbytes for a in jax.tree_util.tree_leaves(stacked))
         with self.spans.lock:
             self.stats["stack_bytes"] += nbytes
@@ -769,7 +781,7 @@ class AnnealService:
     # Resilient group dispatch: fallback chain + quarantine + retry
     # ------------------------------------------------------------------
     def _solve_group_resilient(self, kind, nb, items, responses, progress,
-                               solve_t0, *, weight_bits,
+                               solve_t0, *, couplings,
                                requeue_quarantine: bool = True):
         """Run one group with the resilience wrapper (DESIGN.md §10).
 
@@ -778,8 +790,9 @@ class AnnealService:
         preserved — the trajectory depends only on the noise stream, not the
         backend).  A quarantine signal splits the group: healthy requests
         re-run as a fresh group, offenders retry solo with backoff.  Kills
-        and unclassified errors propagate.  ``weight_bits`` is the call's
-        memoized weight-bit scan, shared by routing and the field options.
+        and unclassified errors propagate.  ``couplings`` is the call's
+        memoized coalescing, shared by routing, the field options and the
+        packing.
         """
         solver = getattr(self, registered_algos()[kind].solver)
         cfg = items[0][1].config
@@ -797,7 +810,7 @@ class AnnealService:
         if backend == "auto":
             with self.spans("weight_bits"):  # routing scans the weight bits
                 backend, opts, why = self.route_auto(kind, nb, items, opts,
-                                                     weight_bits)
+                                                     couplings)
             if why is not None:
                 carried_events.append(ServiceEvent(
                     "route", {"backend": backend, "reason": why},
@@ -805,7 +818,7 @@ class AnnealService:
                 ))
         while True:
             ctx = _GroupCtx(self, kind, nb, items, backend, opts, solve_t0,
-                            self._chunk_of(kind, items), weight_bits,
+                            self._chunk_of(kind, items), couplings,
                             events=carried_events)
             try:
                 # One span per attempt; a quarantine's re-runs and solo
@@ -856,19 +869,19 @@ class AnnealService:
             ctx.finish_success()
             return
 
-    def route_auto(self, kind, nb, items, opts, weight_bits=model_weight_bits):
+    def route_auto(self, kind, nb, items, opts, couplings=model_couplings):
         """Resolve backend='auto' for one group: ``(backend, opts, why)``.
 
         Resident pallas at or above ``engine.MIN_RESIDENT_N`` spins where its
         kernel fits the chip's VMEM budget, XLA dense otherwise; ``why`` is
         the budget shortfall when that is what sent the group to dense.  The
         opts are then filtered to what the chosen backend accepts — 'auto'
-        users pass a union.  ``weight_bits`` is the call's per-model scan.
+        users pass a union.  ``couplings`` is the call's per-model coalescing.
         """
         hp = items[0][1].hp
         kernel_opts = dict(
             opts, noise=self.noise, n_cycles=getattr(hp, "tau", 1),
-            j_bits=max(weight_bits(model) for *_, model in items),
+            j_bits=max(couplings(model).weight_bits for *_, model in items),
         )
         if kind == "ssqa":
             kernel_opts["n_replicas"] = hp.n_replicas
@@ -900,13 +913,13 @@ class AnnealService:
         bad_items = [it for s, it in enumerate(items) if s in bad]
         if good:
             self._solve_group_resilient(kind, nb, good, responses, progress,
-                                        solve_t0, weight_bits=ctx.weight_bits)
+                                        solve_t0, couplings=ctx.couplings)
         for it in bad_items:
             self._retry_solo(kind, nb, it, responses, progress, solve_t0,
-                             ctx.weight_bits)
+                             ctx.couplings)
 
     def _retry_solo(self, kind, nb, item, responses, progress, solve_t0,
-                    weight_bits):
+                    couplings):
         """Quarantined request: exponential backoff + re-autotuned I0max.
 
         Each attempt re-derives the I0 clamp from the instance's local-field
@@ -941,7 +954,7 @@ class AnnealService:
                 self._solve_group_resilient(
                     kind, nb, [(idx, req_retry, maxcut, model)], responses,
                     progress, solve_t0, requeue_quarantine=False,
-                    weight_bits=weight_bits,
+                    couplings=couplings,
                 )
             except QuarantineFault:
                 self.stats["retry_requarantined"] += 1
@@ -1029,7 +1042,7 @@ class AnnealService:
         backend, opts = ctx.backend, ctx.backend_opts
         with spans("weight_bits"):
             opts = self._resolve_field_opts(backend, opts, items,
-                                            ctx.weight_bits)
+                                            ctx.couplings)
         nr = int(getattr(hp, "n_replicas", 0) or 0)
         if nr:
             # SSQA: the Trotter depth is program-structural (ring width per
@@ -1211,7 +1224,7 @@ class AnnealService:
         padded, b_live, b_bucket = self._pad_group(items)
         with self.spans("weight_bits"):
             opts = self._resolve_field_opts(backend, opts, items,
-                                            ctx.weight_bits)
+                                            ctx.couplings)
         with self.spans("program"):
             bk, init_fn, chunk_fn = self._ptssa_programs(
                 nb, b_bucket, hp, backend, opts, chunk, ctx)

@@ -111,6 +111,46 @@ def test_self_loop_rejected():
         IsingModel.from_edges(3, np.array([[0, 0]]), np.array([1]))
 
 
+def _sequential_fill(n, edges, weights):
+    """Per-edge fill: each edge takes the next free slot of i, then of j."""
+    rows = [[] for _ in range(n)]
+    for (i, j), w in zip(edges.tolist(), weights.tolist()):
+        rows[i].append((j, w))
+        rows[j].append((i, w))
+    d = max([len(r) for r in rows] + [1])
+    idx = np.tile(np.arange(n, dtype=np.int32)[:, None], (1, d))
+    wts = np.zeros((n, d), np.int32)
+    for v, r in enumerate(rows):
+        for s, (j, w) in enumerate(r):
+            idx[v, s], wts[v, s] = j, w
+    return idx, wts
+
+
+@pytest.mark.parametrize("n,n_edges", [(50, 400), (2**16, 300),
+                                       (2**16 + 3000, 300)],
+                         ids=["small", "uint16-limit", "over-uint16"])
+def test_from_edges_matches_sequential_fill(n, n_edges):
+    """Slot order is the per-edge fill's, with sources sorted as uint16 up
+    to 2^16 spins and as int32 above: repeated edges, both orientations,
+    endpoints at the top of the range."""
+    rng = np.random.default_rng(n)
+    e = rng.integers(0, n, (n_edges, 2))
+    e = e[e[:, 0] != e[:, 1]]
+    e = np.concatenate([e, e[:20], e[:20, ::-1], [[n - 1, 0], [0, n - 1]]])
+    w = rng.integers(-3, 4, len(e))
+    m = IsingModel.from_edges(n, e, w)
+    idx, wts = _sequential_fill(n, e, w)
+    assert m.nbr_idx.dtype == m.nbr_w.dtype == np.int32
+    np.testing.assert_array_equal(m.nbr_idx, idx)
+    np.testing.assert_array_equal(m.nbr_w, wts)
+
+
+def test_from_edges_rejects_out_of_range_endpoints():
+    for bad in ([[0, 3]], [[-1, 1]]):
+        with pytest.raises(ValueError, match="endpoints"):
+            IsingModel.from_edges(3, np.array(bad), np.array([1]))
+
+
 def test_from_edges_rejects_nonfinite_weights():
     edges = np.array([[0, 1], [1, 2]])
     with pytest.raises(ValueError, match="finite"):

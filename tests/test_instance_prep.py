@@ -10,7 +10,7 @@ import copy
 import numpy as np
 import pytest
 
-from repro.core import SSAHyperParams, gset
+from repro.core import MaxCutProblem, SSAHyperParams, gset
 from repro.core import engine
 from repro.core.engine import (
     _stack_dense_models,
@@ -104,15 +104,15 @@ def test_mixed_list_answers_equal_requests_sent_alone(service):
 
 
 def test_weight_bits_scanned_once_per_instance(monkeypatch):
-    """Routing and the field options read one weight-bit scan per distinct
-    model per call; a second call scans again."""
+    """Routing, the field options and the packing read one coalescing per
+    distinct model per call; a second call coalesces again."""
     calls = []
 
     def counting(model):
         calls.append(model)
-        return engine.model_weight_bits(model)
+        return engine.model_couplings(model)
 
-    monkeypatch.setattr(anneal_service, "model_weight_bits", counting)
+    monkeypatch.setattr(anneal_service, "model_couplings", counting)
     svc = AnnealService(backend="auto", min_bucket=16,
                         backend_opts={"field_mode": "auto"})
     a = gset.toroidal_grid(36, seed=1)
@@ -124,6 +124,49 @@ def test_weight_bits_scanned_once_per_instance(monkeypatch):
     assert svc.stats["span_n.weight_bits"] == 2  # route_auto + field opts
     svc.solve(reqs)
     assert len(calls) == 4
+
+
+def _complete(n, seed):
+    """A dense ±1 Max-Cut instance: the complete graph on ``n`` vertices."""
+    ii, jj = np.triu_indices(n, k=1)
+    w = np.random.default_rng(seed).choice([-1, 1], size=len(ii))
+    return MaxCutProblem(n=n, edges=np.stack([ii, jj], 1), weights=w,
+                         name=f"K{n}")
+
+
+@pytest.mark.parametrize("problem,dense", [
+    (_complete(40, 5), 1), (gset.toroidal_grid(36, seed=1), 0),
+], ids=["complete", "torus"])
+def test_one_coalescing_per_instance_per_call(monkeypatch, problem, dense):
+    """Eight requests on one instance coalesce its couplings exactly once
+    in a call, for routing, field options and packing alike, and the one
+    packing reads that coalescing; the dense route is counted in
+    ``stats["coalesce_dense"]``."""
+    calls, packed = [], []
+
+    def counting(*args):
+        calls.append(coalesce(*args))
+        return calls[-1]
+
+    def packing(c, *args, **kw):
+        packed.append(c)
+        return pack(c, *args, **kw)
+
+    coalesce, pack = engine.coalesce_adjacency, engine.coalesced_planes
+    monkeypatch.setattr(engine, "coalesce_adjacency", counting)
+    monkeypatch.setattr(engine, "coalesced_planes", packing)
+    svc = AnnealService(backend="auto", min_bucket=16,
+                        backend_opts={"field_mode": "auto"})
+    resps = svc.solve(_sweep(problem, [None] * 8))
+    assert all(r.status == "ok" for r in resps)
+    assert resps[0].backend in ("dense", "pallas")
+    assert svc.stats["prep_instances"] == 1
+    assert len(calls) == 1
+    assert len(packed) == 1 and packed[0] is calls[0]
+    assert svc.stats["coalesce_dense"] == dense
+    svc.solve(_sweep(problem, [None] * 8))
+    assert len(calls) == 2
+    assert svc.stats["coalesce_dense"] == 2 * dense
 
 
 def _per_model(stack, models, *args):

@@ -60,8 +60,8 @@ class _StackSpy:
         self.nbytes = []
         orig = cls.stack
 
-        def stack(bk, models):
-            out = orig(bk, models)
+        def stack(bk, models, **kw):
+            out = orig(bk, models, **kw)
             self.nbytes.append(sum(a.nbytes
                                    for a in jax.tree_util.tree_leaves(out)))
             return out

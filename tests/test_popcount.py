@@ -27,11 +27,16 @@ from repro.core.engine import (
 from repro.core.ising import local_fields_popcount
 from repro.kernels.bitplane import (
     PackedJ,
+    _resolve_n_bits,
+    adjacency_planes,
     adjacency_weight_bits,
+    coalesce_adjacency,
+    coalesced_planes,
     pack_couplings,
     pack_couplings_from_adjacency,
     pack_spins,
     packed_j_nbytes,
+    packed_words,
     popcount_u32,
 )
 
@@ -88,6 +93,128 @@ def test_packed_j_nbytes_matches_arrays():
     assert pj.n_bits == jb == 2
     got = pj.sign.nbytes + pj.mags.nbytes + pj.base.nbytes
     assert got == packed_j_nbytes(m.n, jb)
+
+
+# ---------------------------------------------------------------------------
+# Coalescing and plane packing against a scatter oracle
+# ---------------------------------------------------------------------------
+def _oracle_coalesce(n, nbr_idx, nbr_w):
+    """``np.unique`` + ``np.add.at``: the plain coalescing."""
+    idx = np.asarray(nbr_idx, dtype=np.int64)
+    w = np.asarray(nbr_w, dtype=np.int64)
+    rows = np.broadcast_to(np.arange(n, dtype=np.int64)[:, None], idx.shape)
+    live = w != 0
+    keys = rows[live] * n + idx[live]
+    uniq, inv = np.unique(keys, return_inverse=True)
+    wsum = np.zeros(uniq.shape[0], dtype=np.int64)
+    np.add.at(wsum, inv, w[live])
+    nz = wsum != 0
+    uniq, wsum = uniq[nz], wsum[nz]
+    return uniq // n, uniq % n, wsum
+
+
+def _oracle_planes(n, nbr_idx, nbr_w, n_bits=None):
+    """``np.bitwise_or.at`` / ``np.add.at`` scatters of the oracle triple."""
+    nw = packed_words(n)
+    r, c, wsum = _oracle_coalesce(n, nbr_idx, nbr_w)
+    word, bit = c // 32, (c % 32).astype(np.uint32)
+    mag = np.abs(wsum)
+    n_bits = _resolve_n_bits(mag.max(initial=0), n_bits)
+    sign = np.zeros((n, nw), np.uint32)
+    pos = wsum > 0
+    np.bitwise_or.at(sign, (r[pos], word[pos]), np.uint32(1) << bit[pos])
+    mags = np.zeros((n_bits, n, nw), np.uint32)
+    base = np.zeros(n, np.int64)
+    for b in range(n_bits):
+        sel = ((mag >> b) & 1) == 1
+        np.bitwise_or.at(mags[b], (r[sel], word[sel]),
+                         np.uint32(1) << bit[sel])
+        np.add.at(base, r[sel], -(np.int64(1) << b))
+    return sign, mags, base.astype(np.int32)
+
+
+def _entries(c):
+    """A coalescing's nonzero entries ``(rows, cols, wsum)``, row-major,
+    read from whichever form its route filled."""
+    if c.dense is None:
+        return c.rows, c.cols, c.wsum
+    keys = np.flatnonzero(c.dense)
+    return keys // c.n, keys % c.n, c.dense.reshape(-1)[keys]
+
+
+def _adjacency(n, deg, w_max, seed, pad_rows=0):
+    """Random padded adjacency with duplicate slots, duplicates that cancel
+    to 0, zero-weight slots and ``pad_rows`` trailing padding rows."""
+    rng = np.random.default_rng(seed)
+    idx = rng.integers(0, n, (n, deg)).astype(np.int32)
+    w = rng.integers(-w_max, w_max + 1, (n, deg)).astype(np.int32)
+    w[rng.random((n, deg)) < 0.2] = 0
+    idx[:, 1] = idx[:, 0]                          # a duplicate slot...
+    w[::2, 1] = -w[::2, 0]                         # ...cancelling in half
+    idx[:, 2], w[:, 2] = idx[:, 3], w[:, 3]        # ...summing in all
+    if pad_rows:
+        idx[-pad_rows:] = np.arange(n - pad_rows, n)[:, None]
+        w[-pad_rows:] = 0
+    return idx, w
+
+
+@pytest.mark.parametrize("n,deg,w_max,pad_rows,dense", [
+    (40, 24, 1, 0, True),     # ±1, duplicates, n % 32 != 0
+    (70, 40, 15, 5, True),    # 4-bit weights of both signs, padded rows
+    (64, 16, 7, 0, True),     # n² exactly 4x the slots: still dense
+    (65, 16, 7, 3, False),    # just past the dense rule
+    (300, 6, 15, 7, False),   # sparse: 4-bit weights, padded rows
+    (97, 4, 1, 0, False),     # sparse ±1, n % 32 != 0
+], ids=["dense-pm1", "dense-4bit-pad", "dense-edge", "sorted-edge",
+        "sorted-4bit-pad", "sorted-pm1"])
+def test_coalescing_and_planes_equal_scatter_oracle(n, deg, w_max, pad_rows,
+                                                    dense):
+    """The bincount/sort coalescing and the packbits/reduceat planes equal
+    the np.unique/np.add.at/np.bitwise_or.at scatters bit for bit, on both
+    routes, at the model's own size, padded to a bucket, and with forced
+    extra magnitude planes."""
+    idx, w = _adjacency(n, deg, w_max, seed=n * 1000 + deg, pad_rows=pad_rows)
+    c = coalesce_adjacency(n, idx, w)
+    assert (c.dense is not None) == dense
+    want = _oracle_coalesce(n, idx, w)
+    got = _entries(c)
+    assert np.any(want[2] != 0) and len(want[2]) < n * deg  # duplicates
+    for g, x in zip(got, want):
+        np.testing.assert_array_equal(g, x)
+    need = max(1, int(np.abs(want[2]).max()).bit_length())
+    assert adjacency_weight_bits(n, idx, w) == c.weight_bits == need
+
+    nb = -(-n // 64) * 64 + 32  # a bucket with whole extra words
+    pad_i = np.concatenate([idx, np.tile(np.arange(n, nb)[:, None],
+                                         (1, deg))]).astype(np.int32)
+    pad_w = np.concatenate([w, np.zeros((nb - n, deg), np.int32)])
+    for size, ii, ww, bits in [(n, idx, w, None), (nb, pad_i, pad_w, None),
+                               (nb, pad_i, pad_w, need + 2)]:
+        want_p = _oracle_planes(size, ii, ww, bits)
+        for got_p in (adjacency_planes(size, ii, ww, bits),
+                      coalesced_planes(c, size, bits)):
+            for g, x in zip(got_p, want_p):
+                assert g.dtype == x.dtype and g.shape == x.shape
+                np.testing.assert_array_equal(g, x)
+
+
+@pytest.mark.parametrize("n,deg,dense", [(8, 8, True), (64, 4, False)],
+                         ids=["dense", "sorted"])
+def test_coalescing_exact_at_large_weights(n, deg, dense):
+    """Weights whose total passes 2^53: the float64 bincount of the dense
+    route refuses them, the int64 sums of the sorted route stay exact."""
+    idx = np.tile((np.arange(n) + 1)[:, None] % n, (1, deg))
+    w = np.zeros((n, deg), np.int64)
+    w[:, 0] = w[:, 1] = (1 << 52) + 1
+    if dense:
+        with pytest.raises(ValueError, match="exactly"):
+            coalesce_adjacency(n, idx, w)
+        return
+    c = coalesce_adjacency(n, idx, w)
+    assert c.dense is None and c.max_abs == (1 << 53) + 2
+    np.testing.assert_array_equal(c.wsum, np.full(n, (1 << 53) + 2))
+    for g, x in zip(_entries(c), _oracle_coalesce(n, idx, w)):
+        np.testing.assert_array_equal(g, x)
 
 
 # ---------------------------------------------------------------------------
