@@ -368,6 +368,8 @@ class BatchedSpinShardedBackend(BatchedBackend):
     the XNOR-popcount bitplane contraction (``field_mode='popcount'``).
     The resident Pallas kernels are single-device programs, so under spin
     sharding 'pallas' runs its arithmetic through these scan paths.
+    Without a ``mesh`` the spin axis shards over every device JAX sees
+    (:func:`repro.sharding.spin_mesh`).
     """
 
     name = "spinshard"
@@ -384,7 +386,7 @@ class BatchedSpinShardedBackend(BatchedBackend):
                 "lane seeding is what makes sharded runs bit-identical"
             )
         del j_mode, j_dtype, block_r, interpret, noise_mode  # single-device knobs
-        self.mesh = spin_mesh(1, axis=axis) if mesh is None else mesh
+        self.mesh = spin_mesh(axis=axis) if mesh is None else mesh
         self.axis = axis
         self.n_dev = mesh_axis_size(self.mesh, axis)
         if self.n_bucket % self.n_dev:
@@ -456,6 +458,9 @@ class BatchedSpinShardedBackend(BatchedBackend):
 
     # -- host side --------------------------------------------------------
     def stack(self, models, couplings=model_couplings) -> dict:
+        """Host arrays of the stacked problems, each device's row block put
+        on that device straight from the host: no device ever holds
+        another's rows of J."""
         if self.field_style == "popcount":
             problem = _stack_packed_models(models, self.n_bucket, self.j_bits,
                                            couplings)
@@ -466,6 +471,27 @@ class BatchedSpinShardedBackend(BatchedBackend):
             k: jax.device_put(v, NamedSharding(self.mesh, specs[k]))
             for k, v in problem.items()
         }
+
+    def gather_bytes(self, b_bucket: int, plateaus, n_shots: int) -> int:
+        """Bytes of spins one chunk program all-gathers over the mesh.
+
+        One gather per field evaluation: every cycle of every plateau, plus
+        the epilogue of each eligible plateau (its final state's fold).
+        Each gathers the (B, T, N_bucket) spins as packed words, N_bucket/8
+        bytes per (lane, trial) row, when every shard is word-aligned, else
+        as int8, N_bucket bytes per row; packed and unaligned, the chunk's
+        end gathers m and best_m once more as int8.  So, word-aligned:
+        n_shots x (cycles + eligible plateaus) x B x T x N_bucket / 8.
+        """
+        rows = int(b_bucket) * self.n_trials
+        per_gather = rows * self.n_bucket // (8 if self._words_shardable
+                                              else 1)
+        gathers = int(n_shots) * sum(p.length + int(p.eligible)
+                                     for p in plateaus)
+        end = (2 * rows * self.n_bucket
+               if self.storage_layout == "packed"
+               and not self._words_shardable else 0)
+        return gathers * per_gather + end
 
     def init_noise(self, seeds, n_lives):
         """Shard-local lane seeding: each device seeds only its columns.

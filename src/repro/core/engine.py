@@ -1135,7 +1135,8 @@ def _distinct(models):
 
 
 def _stack_sparse_models(models, n_bucket: int) -> dict:
-    """Stacked, bucket-padded adjacency views {h, nbr_idx, nbr_w}.
+    """Stacked, bucket-padded adjacency views {h, nbr_idx, nbr_w}, as host
+    arrays (:func:`_on_device` places them on one device).
 
     Each distinct model is padded once; lanes that carry the same model
     object index its one copy.
@@ -1147,7 +1148,7 @@ def _stack_sparse_models(models, n_bucket: int) -> dict:
 
     def stacked(field):
         views = [np.asarray(getattr(m, field), np.int32) for m in padded]
-        return jnp.asarray(np.stack([views[k] for k in lanes]), jnp.int32)
+        return np.stack([views[k] for k in lanes])
 
     return {"h": stacked("h"), "nbr_idx": stacked("nbr_idx"),
             "nbr_w": stacked("nbr_w")}
@@ -1186,7 +1187,7 @@ class BatchedSparseBackend(_VmapBatchedBackend):
     name = "sparse"
 
     def stack(self, models, couplings=model_couplings):
-        return _stack_sparse_models(models, self.n_bucket)
+        return _on_device(_stack_sparse_models(models, self.n_bucket))
 
     def _field_one(self, prob, m):
         return local_fields_sparse(
@@ -1224,8 +1225,9 @@ def _stack_packed_models(models, n_bucket: int, j_bits: int,
     once on the host, from ``couplings(model)`` (the service passes the
     coalescing its weight-bit scan already made; padding rows carry no
     coupling, so the unpadded model's is the padded model's); the (B, ...)
-    arrays index those packings by lane and move to the device in one
-    transfer each.
+    host arrays index those packings by lane.  They stay on the host: the
+    caller places them, whole on one device (:func:`_on_device`) or each
+    device's rows on that device.
     """
     distinct, lanes = _distinct(models)
     planes = []   # per distinct model: (h, sign, mags, base) host arrays
@@ -1235,8 +1237,14 @@ def _stack_packed_models(models, n_bucket: int, j_bits: int,
         h = np.zeros(n_bucket, np.int32)
         h[:m.n] = m.h
         planes.append((h, sign, mags, base))
-    return {key: jnp.asarray(np.stack([planes[k][i] for k in lanes]))
+    return {key: np.stack([planes[k][i] for k in lanes])
             for i, key in enumerate(("h", "sign", "mags", "base"))}
+
+
+def _on_device(problem: dict) -> dict:
+    """A stacked problem's host arrays on the default device, one transfer
+    each."""
+    return {k: jnp.asarray(v) for k, v in problem.items()}
 
 
 class BatchedDenseBackend(_VmapBatchedBackend):
@@ -1273,10 +1281,10 @@ class BatchedDenseBackend(_VmapBatchedBackend):
 
     def stack(self, models, couplings=model_couplings):
         if self.field_mode == "popcount":
-            return _stack_packed_models(models, self.n_bucket, self.j_bits,
-                                        couplings)
+            return _on_device(_stack_packed_models(
+                models, self.n_bucket, self.j_bits, couplings))
         if self.j_mode == "tiled":
-            return _stack_sparse_models(models, self.n_bucket)
+            return _on_device(_stack_sparse_models(models, self.n_bucket))
         return _stack_dense_models(models, self.n_bucket, self.j_dtype)
 
     def _field_one(self, prob, m):
@@ -1350,8 +1358,8 @@ class BatchedPallasBackend(BatchedBackend):
 
     def stack(self, models, couplings=model_couplings):
         if self.field_mode == "popcount":
-            return _stack_packed_models(models, self.n_bucket, self.j_bits,
-                                        couplings)
+            return _on_device(_stack_packed_models(
+                models, self.n_bucket, self.j_bits, couplings))
         return _stack_dense_models(models, self.n_bucket, self.j_dtype)
 
     def _field_one(self, prob, m):

@@ -130,7 +130,7 @@ from repro.core.ssa import AnnealResult, SSAHyperParams
 from repro.core.ssqa import SSQAHyperParams
 from repro.ft.faults import FaultInjector
 from repro.problems import ProblemEncoding
-from repro.sharding import mesh_fingerprint
+from repro.sharding import mesh_fingerprint, spin_mesh
 
 from .registry import family_for, registered_algos
 from .spans import Spans
@@ -489,8 +489,11 @@ class AnnealService:
         (DESIGN.md §11): ``'problem'`` (default) stacks whole problems per
         device; ``'spin'`` shards the spin axis of every problem over
         ``mesh``'s model axis via shard_map collectives — the only way
-        instances above ``engine.MAX_UNSHARDED_SPINS`` are admitted;
-        ``'auto'`` resolves per shape bucket.  Spin-sharded groups require
+        instances above ``engine.MAX_UNSHARDED_SPINS`` are admitted.
+        Without a ``mesh`` (here or in ``config``), ``'spin'`` builds
+        :func:`repro.sharding.spin_mesh`, the 1-D ``model`` mesh over every
+        device JAX sees; ``'auto'`` resolves per shape bucket, and without
+        a mesh to 'problem'.  Spin-sharded groups require
         ``noise='xorshift'`` (shard-local lane seeding is what makes sharded
         runs bit-identical to single-device runs).  SA and PT-SSA groups
         always run problem-partitioned.
@@ -513,6 +516,8 @@ class AnnealService:
             raise ValueError(f"unknown storage_layout {storage_layout!r}")
         if partition not in ("problem", "spin", "auto"):
             raise ValueError(f"unknown partition {partition!r}")
+        if partition == "spin" and mesh is None:
+            mesh = spin_mesh()
         self.backend = backend
         self.noise = noise
         self.storage_layout = storage_layout
@@ -1071,11 +1076,15 @@ class AnnealService:
             )
             state = init_fn(stacked, ns0)
 
+        shards = None
+        if bk.name == "spinshard":
+            shards = (bk.n_dev, bk.gather_bytes(b_bucket, plateaus, chunk))
         state, chunk_traces, stops = self._chunk_loop(
             ctx.kind, nb, items, n_chunks, progress,
             lambda st, c, live: chunk_fn(stacked, st, live), state,
             lambda st: st.best_H, ctx, width=b_bucket,
             snap=lambda st: bk.finalize(st), masks=bk.skips_dead_lanes,
+            shards=shards,
         )
         with spans("finalize"):
             bh_dev, bm_dev = bk.finalize(state)  # unpacks bitplanes too
@@ -1306,7 +1315,8 @@ class AnnealService:
     # deadline watchdog, non-finite detector, fault hooks
     # ------------------------------------------------------------------
     def _chunk_loop(self, kind, nb, items, n_chunks, progress, step, state,
-                    best_of, ctx, *, width=None, snap=None, masks=False):
+                    best_of, ctx, *, width=None, snap=None, masks=False,
+                    shards=None):
         """Run up to n_chunks ``step(state, c, live)`` calls from the last
         checkpoint; report per-chunk bests; stop early when every request is
         done (target_cut reached or deadline expired).
@@ -1329,7 +1339,8 @@ class AnnealService:
         stop) and for ``_pad_group``'s padding lanes (from chunk 0).  A
         resume starts with every request lane live.  ``masks`` says the
         step skips the dead lanes, which ``stats["masked_lane_chunks"]``
-        then counts.
+        then counts.  ``shards`` is ``(devices, gather bytes)`` of one chunk
+        of a spin-sharded group, which the ``spin_*`` counters add up.
         """
         spans = self.spans
         traces = [[] for _ in items]
@@ -1351,6 +1362,14 @@ class AnnealService:
                     self.stats["live_lane_chunks"] += n_live
                     if masks:
                         self.stats["masked_lane_chunks"] += live.size - n_live
+                    if shards is not None:
+                        # One chunk program over the mesh axis; it gathers
+                        # n_shots x (cycles + eligible plateaus) x B x T x
+                        # N_bucket / 8 bytes of spin words (word-aligned
+                        # shards; BatchedSpinShardedBackend.gather_bytes).
+                        self.stats["spin_chunks"] += 1
+                        self.stats["spin_shard_chunks"] += shards[0]
+                        self.stats["spin_gather_bytes"] += shards[1]
                     state = step(state, c, live)
                 with spans("chunk.sync"):
                     best_H = np.asarray(best_of(state))  # the report
